@@ -21,7 +21,7 @@ from analyze import Finding, emit_github, emit_json
 
 MAX_LINE = 100
 ROOTS = ["spark_rapids_jni_tpu", "tests", "bench.py", "__graft_entry__.py",
-         "boot_cpu_mesh.py", "ci", "tools"]
+         "chip_smoke.py", "ci", "tools"]
 
 _URL_RE = re.compile(r"https?://\S+")
 

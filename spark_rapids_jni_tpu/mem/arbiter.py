@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import collections
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,7 +22,33 @@ from spark_rapids_jni_tpu.obs import flight as _flight
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _SRC = os.path.join(_NATIVE_DIR, "task_arbiter.cpp")
-_LIB = os.path.join(_NATIVE_DIR, "libtask_arbiter.so")
+_CXX = ("g++", "-std=c++17", "-O2", "-shared", "-fPIC")
+_LDLIBS = ("-lpthread",)
+
+
+def _lib_path(src: str = _SRC) -> str:
+    """The built library for ``src``, named by a hash of the source and the
+    compiler command: an .so built from any other source or flags (a stale
+    file copied along with the tree, say) is never the one loaded."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(_CXX + _LDLIBS).encode())
+    return os.path.join(os.path.dirname(src),
+                        f"libtask_arbiter-{h.hexdigest()[:16]}.so")
+
+
+def _build_lib(src: str = _SRC) -> str:
+    """Path of the library for ``src``, compiling it first if absent.  The
+    compiler writes a private temp file that is renamed into place, so
+    concurrent first users in other processes never load a partial file."""
+    lib = _lib_path(src)
+    if not os.path.exists(lib):
+        tmp = f"{lib}.{os.getpid()}.tmp"
+        subprocess.run([*_CXX, "-o", tmp, src, *_LDLIBS], check=True,
+                       capture_output=True)
+        os.replace(tmp, lib)
+    return lib
 
 # return codes (task_arbiter.cpp arbiter_code)
 OK = 0
@@ -76,19 +103,12 @@ def _ensure_lib() -> ctypes.CDLL:
     with _build_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
-            # analyze: ignore[blocking-under-lock] - one-shot native
-            # build at first import, serialized BY DESIGN: _build_lock
-            # exists precisely so concurrent first-callers wait for the
-            # single g++ run instead of racing the .so write; no task,
-            # arbiter, or serving thread exists yet to stall behind it
-            subprocess.run(
-                ["g++", "-std=c++17", "-O2", "-shared", "-fPIC", "-o", _LIB, _SRC,
-                 "-lpthread"],
-                check=True,
-                capture_output=True,
-            )
-        lib = ctypes.CDLL(_LIB)
+        # analyze: ignore[blocking-under-lock] - one-shot native
+        # build at first import, serialized BY DESIGN: _build_lock
+        # exists precisely so concurrent first-callers wait for the
+        # single g++ run instead of racing the .so write; no task,
+        # arbiter, or serving thread exists yet to stall behind it
+        lib = ctypes.CDLL(_build_lib())
         lib.arbiter_create.restype = ctypes.c_void_p
         lib.arbiter_create.argtypes = [ctypes.c_char_p]
         lib.arbiter_destroy.argtypes = [ctypes.c_void_p]

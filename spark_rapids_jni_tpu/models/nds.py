@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from spark_rapids_jni_tpu.ops.hashing import murmur3_raw_int64, xxhash64_raw_int64
-from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, axis_size, shard_map
+from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from spark_rapids_jni_tpu.parallel.shuffle import all_to_all_shuffle, partition_of
 
 
@@ -50,8 +50,13 @@ class QueryStepOut(NamedTuple):
     dropped: jnp.ndarray  # scalar: shuffle capacity overflows (global)
 
 
-def _bloom_positions(keys: jnp.ndarray, k: int, total_bits: int) -> jnp.ndarray:
-    """[n, k] bit positions via double hashing from two murmur seeds.
+def _bloom_positions(keys: jnp.ndarray, k: int, total_bits: int) -> list:
+    """k bit-position arrays, each [n], via double hashing from two murmur
+    seeds: position i is (h1 + (i+1) * h2) % total_bits.
+
+    Kept as k separate 1-D arrays, never one [n, k] matrix: on the TPU a
+    minor dimension of k pads to 128 lanes, which made the entry step need
+    ~1 KiB of HBM per row.
 
     (Not the Spark sketch's exact bit layout — ops/bloom_filter.py owns
     Spark-serialization-compatible filters; this one is internal to the
@@ -59,9 +64,7 @@ def _bloom_positions(keys: jnp.ndarray, k: int, total_bits: int) -> jnp.ndarray:
     """
     h1 = murmur3_raw_int64(keys, 0).astype(jnp.int64)
     h2 = murmur3_raw_int64(keys, 0x9747B28C).astype(jnp.int64)
-    ks = jnp.arange(1, k + 1, dtype=jnp.int64)
-    combined = h1[:, None] + ks[None, :] * h2[:, None]
-    return combined % total_bits
+    return [(h1 + i * h2) % total_bits for i in range(1, k + 1)]
 
 
 def local_query_step(keys: jnp.ndarray, values: jnp.ndarray, cfg: QueryStepConfig):
@@ -80,19 +83,19 @@ def local_query_step(keys: jnp.ndarray, values: jnp.ndarray, cfg: QueryStepConfi
         jnp.ones_like(values, dtype=jnp.int32), bucket, num_segments=cfg.n_buckets
     )
     pos = _bloom_positions(keys, cfg.bloom_hashes, cfg.bloom_bits)
-    bits = (
-        # analyze: ignore[governed-allocation] - bloom_bits u8 bitmap,
-        # same oracle path: sized by config, not by data, bounded small
-        jnp.zeros((cfg.bloom_bits,), jnp.uint8).at[pos.reshape(-1)].max(1)
-    )
-    probed = bits[pos].astype(jnp.int32).sum(axis=1) == cfg.bloom_hashes
+    # analyze: ignore[governed-allocation] - bloom_bits u8 bitmap,
+    # same oracle path: sized by config, not by data, bounded small
+    bits = jnp.zeros((cfg.bloom_bits,), jnp.uint8)
+    for p in pos:
+        bits = bits.at[p].max(1)
+    probed = sum(bits[p].astype(jnp.int32) for p in pos) == cfg.bloom_hashes
     return sums, counts, bits, probed.sum()
 
 
 def _sharded_step(keys, values, cfg: QueryStepConfig):
     """The body run per device under shard_map over (data, model)."""
-    dp = axis_size(DATA_AXIS)
-    mp = axis_size(MODEL_AXIS)
+    dp = jax.lax.axis_size(DATA_AXIS)
+    mp = jax.lax.axis_size(MODEL_AXIS)
     m_idx = jax.lax.axis_index(MODEL_AXIS)
     n_local = keys.shape[0]
 
@@ -103,21 +106,20 @@ def _sharded_step(keys, values, cfg: QueryStepConfig):
     bits_per_shard = cfg.bloom_bits // mp
     pos = _bloom_positions(keys, cfg.bloom_hashes, bits_per_shard * mp)
     lo = m_idx.astype(jnp.int64) * bits_per_shard
-    local_pos = pos.reshape(-1) - lo
-    in_range = (local_pos >= 0) & (local_pos < bits_per_shard)
-    local_bits = (
-        jnp.zeros((bits_per_shard,), jnp.uint8)
-        .at[jnp.where(in_range, local_pos, bits_per_shard)]
-        .max(1, mode="drop")
-    )
+    local_pos = [p - lo for p in pos]
+    in_range = [(lp >= 0) & (lp < bits_per_shard) for lp in local_pos]
+    local_bits = jnp.zeros((bits_per_shard,), jnp.uint8)
+    for lp, ok in zip(local_pos, in_range):
+        local_bits = local_bits.at[jnp.where(ok, lp, bits_per_shard)].max(
+            1, mode="drop")
     local_bits = jax.lax.pmax(local_bits, DATA_AXIS)
 
     # 2. bloom probe (tp reduce): each model shard counts the probe bits it
     #    owns and has set; a row passes iff the psum over shards reaches k.
-    probe_local_pos = pos - lo
-    probe_in_range = (probe_local_pos >= 0) & (probe_local_pos < bits_per_shard)
-    gathered = local_bits[jnp.clip(probe_local_pos, 0, bits_per_shard - 1)]
-    set_here = jnp.where(probe_in_range, gathered.astype(jnp.int32), 0).sum(axis=1)
+    set_here = sum(
+        jnp.where(ok, local_bits[jnp.clip(lp, 0, bits_per_shard - 1)]
+                  .astype(jnp.int32), 0)
+        for lp, ok in zip(local_pos, in_range))
     set_total = jax.lax.psum(set_here, MODEL_AXIS)
     probe_hits = jax.lax.psum((set_total == cfg.bloom_hashes).sum(), DATA_AXIS)
 
@@ -147,7 +149,7 @@ def _sharded_step(keys, values, cfg: QueryStepConfig):
 
 def make_distributed_query_step(mesh, cfg: QueryStepConfig):
     """jit-compiled full distributed step over ``mesh`` (axes data, model)."""
-    step = shard_map(
+    step = jax.shard_map(
         functools.partial(_sharded_step, cfg=cfg),
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS)),

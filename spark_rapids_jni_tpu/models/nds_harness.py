@@ -96,7 +96,20 @@ def _q97_tables_from_parquet(input_dir: str, n_splits: int):
     return cat("store"), cat("catalog")
 
 
-def main(argv=None) -> int:
+def _plan_split(before: dict) -> dict:
+    """Plan-cache time split since the ``before`` snapshot: trace, compile
+    and execute seconds and the compilations that happened in between."""
+    from spark_rapids_jni_tpu.plans import plan_cache
+
+    after = plan_cache.stats()
+    out = {k: round(after[k] - before[k], 3)
+           for k in ("trace_s", "compile_s", "execute_s")}
+    out["compiles"] = int(after["misses"] - before["misses"])
+    return out
+
+
+def run(argv=None) -> dict:
+    """Run the harness and return its report (what :func:`main` prints)."""
     ap = argparse.ArgumentParser(
         description="NDS q5+q97 (+q3) end-to-end harness")
     ap.add_argument("--sf", type=float, default=0.05)
@@ -153,8 +166,11 @@ def main(argv=None) -> int:
         out["input"] = args.input
         out["splits_per_file"] = args.splits
 
+    from spark_rapids_jni_tpu.plans import plan_cache
+
     try:
         budget.reset_peak()
+        span = plan_cache.stats()
         if args.stream_chunk_rows > 0:
             import tempfile
 
@@ -190,6 +206,7 @@ def main(argv=None) -> int:
                 "verified": q5_ok,
                 "streamed": q5_stats,
                 "peak_reserved_bytes": budget.reset_peak(),
+                "plan": _plan_split(span),
             }
         else:
             data = generate_q5_data(sf=args.sf, seed=args.seed)
@@ -207,8 +224,10 @@ def main(argv=None) -> int:
                 "result_rows": len(q5_rows),
                 "verified": q5_ok,
                 "peak_reserved_bytes": budget.reset_peak(),
+                "plan": _plan_split(span),
             }
 
+        span = plan_cache.stats()
         if args.stream_chunk_rows > 0:
             if args.input:
                 # footer-planned parquet scan feeding the disk shuffle:
@@ -233,6 +252,7 @@ def main(argv=None) -> int:
                 "verified": q97_ok,
                 "streamed": stats,
                 "peak_reserved_bytes": budget.reset_peak(),
+                "plan": _plan_split(span),
             }
         else:
             if args.input:
@@ -259,8 +279,10 @@ def main(argv=None) -> int:
                            int(q97.both)],
                 "verified": q97_ok,
                 "peak_reserved_bytes": budget.reset_peak(),
+                "plan": _plan_split(span),
             }
 
+        span = plan_cache.stats()
         q3_data = generate_q3_data(sf=args.sf, seed=args.seed)
         n3 = len(q3_data.ss_item_sk)
         t0 = time.perf_counter()
@@ -274,15 +296,23 @@ def main(argv=None) -> int:
             "result_rows": len(q3_rows),
             "verified": q3_ok,
             "peak_reserved_bytes": budget.reset_peak(),
+            "plan": _plan_split(span),
         }
         out["total_wall_s"] = round(q5_dt + q97_dt + q3_dt, 3)
     finally:
         MemoryGovernor.shutdown()
+    return out
 
+
+def main(argv=None) -> int:
+    out = run(argv)
     print(json.dumps(out))
     failed = any(q.get("verified") is False for q in out["queries"].values())
     return 1 if failed else 0
 
 
 if __name__ == "__main__":
+    from spark_rapids_jni_tpu import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -44,7 +44,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from spark_rapids_jni_tpu.models.tpcds import Q3Data
-from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS, shard_map
+from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 from spark_rapids_jni_tpu.plans import ir
 from spark_rapids_jni_tpu.plans.ir import Bin, Cast, band_all, col, lit
 
@@ -381,7 +381,7 @@ def _q3_columns_step_cached(mesh, geo_items: tuple):
             return _dec_partials(ss_item, ss_date, price, item_brand,
                                  item_manufact, date_year, date_moy, **geo)
 
-        step = shard_map(
+        step = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(DATA_AXIS),) * 3 + (P(),) * 4,
             out_specs=_DecPartials(P(), P(), P()),

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS, axis_size, shard_map
+from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 from spark_rapids_jni_tpu.parallel.shuffle import all_to_all_shuffle, partition_of
 from spark_rapids_jni_tpu.plans import ir as ir_mod
 
@@ -127,7 +127,7 @@ def q97_local(store: tuple, catalog: tuple) -> Q97Out:
 
 def _sharded_q97(s_cust, s_item, c_cust, c_item, capacity: int,
                  s_valid=None, c_valid=None):
-    dp = axis_size(DATA_AXIS)
+    dp = jax.lax.axis_size(DATA_AXIS)
     sk = _composite_key(s_cust, s_item)
     ck = _composite_key(c_cust, c_item)
 
@@ -201,7 +201,7 @@ def make_distributed_q97(mesh, capacity: int, with_validity: bool = False):
     else:
         body = functools.partial(_sharded_q97, capacity=capacity)
         in_specs = tuple(P(DATA_AXIS) for _ in range(4))
-    step = shard_map(
+    step = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -281,7 +281,7 @@ def _sharded_q97_columns(s_cust, s_item, c_cust, c_item, s_rv, c_rv,
     from spark_rapids_jni_tpu.columnar.dtypes import INT64 as _I64
     from spark_rapids_jni_tpu.parallel.table_shuffle import shuffle_table
 
-    dp = axis_size(DATA_AXIS)
+    dp = jax.lax.axis_size(DATA_AXIS)
     skh, skl = _pair_key(s_cust.data, s_cust.is_valid(),
                          s_item.data, s_item.is_valid(), side=1)
     ckh, ckl = _pair_key(c_cust.data, c_cust.is_valid(),
@@ -327,7 +327,7 @@ def make_distributed_q97_columns(mesh, capacity: int):
         return _sharded_q97_columns(s_cust, s_item, c_cust, c_item,
                                     s_rv, c_rv, capacity)
 
-    step = shard_map(
+    step = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(P(DATA_AXIS) for _ in range(6)),

@@ -72,26 +72,19 @@ def q97_spill_shuffle(tmpdir: str, n_buckets: int) -> ExternalTableShuffle:
 
 def generate_q97_chunks(sf: float, seed: int, chunk_rows: int
                         ) -> Iterator[Tuple[str, np.ndarray, np.ndarray]]:
-    """Stream the q97 fact pair as ``(side, cust, item)`` chunks.
+    """Stream the q97 fact pair as ``(side, cust, item)`` chunks of at most
+    ``chunk_rows`` rows.
 
-    Same marginal distribution as tpcds.generate_q97_tables (chunk c draws
-    from a per-chunk seeded rng, so any prefix is reproducible without
-    materializing the whole table — the streaming analog of dsdgen's
-    parallel generation, which also seeds per partition)."""
-    n = max(1000, int(2_800_000 * sf))
-    n_cust = max(2, n // 14)
-    for side_idx, side in enumerate(("store", "catalog")):
-        done = 0
-        chunk = 0
-        while done < n:
-            m = min(chunk_rows, n - done)
-            rng = np.random.RandomState(
-                (seed + 1_000_003 * side_idx + chunk) % (2**31 - 1))
-            yield (side,
-                   rng.randint(1, n_cust, m).astype(np.int32),
-                   rng.randint(1, 18_000, m).astype(np.int32))
-            done += m
-            chunk += 1
+    The chunks concatenate to tpcds.generate_q97_tables(sf, seed) for any
+    ``chunk_rows``: they are slices of its seeded generation blocks
+    (tpcds.q97_blocks), the streaming analog of dsdgen's parallel
+    generation, which also seeds per partition."""
+    from spark_rapids_jni_tpu.models.tpcds import q97_blocks
+
+    for side, cust, item in q97_blocks(sf, seed):
+        for start in range(0, len(cust), chunk_rows):
+            yield (side, cust[start:start + chunk_rows],
+                   item[start:start + chunk_rows])
 
 
 # ------------------------------------------------------------ streamed q5 --

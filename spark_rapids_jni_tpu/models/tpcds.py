@@ -20,7 +20,8 @@ from typing import Dict
 import numpy as np
 
 __all__ = ["Q3Data", "Q5Data", "Q5Dims", "q5_dims", "generate_q3_data",
-           "generate_q5_data", "generate_q97_tables", "write_q97_parquet",
+           "generate_q5_data", "generate_q97_tables", "q97_blocks",
+           "write_q97_parquet",
            "CHANNELS"]
 
 # (channel label, fact prefix, dim id prefix) for q5's three channel unions
@@ -240,16 +241,44 @@ def generate_q3_data(sf: float = 0.01, seed: int = 0,
     )
 
 
+#: rows per seeded generation block of the q97 fact pair: the table is the
+#: same whatever chunk size a streamed reader asks for
+Q97_BLOCK_ROWS = 1 << 20
+
+
+def q97_rows(sf: float) -> int:
+    """Rows per q97 fact table at ``sf`` (SF1 store_sales is ~2.9M rows)."""
+    return max(1000, int(2_800_000 * sf))
+
+
+def q97_blocks(sf: float, seed: int):
+    """The q97 fact pair as ``(side, cust, item)`` int32 blocks of at most
+    :data:`Q97_BLOCK_ROWS` rows, store side first.  Block b of a side draws
+    from its own seeded rng, so a streamed reader holds one block at a
+    time and any prefix is reproducible."""
+    n = q97_rows(sf)
+    n_cust = max(2, n // 14)
+    for side_idx, side in enumerate(("store", "catalog")):
+        for block, start in enumerate(range(0, n, Q97_BLOCK_ROWS)):
+            m = min(Q97_BLOCK_ROWS, n - start)
+            rng = np.random.RandomState(
+                (seed + 1_000_003 * side_idx + block) % (2**31 - 1))
+            yield (side,
+                   rng.randint(1, n_cust, m).astype(np.int32),
+                   rng.randint(1, 18_000, m).astype(np.int32))
+
+
 def generate_q97_tables(sf: float, seed: int):
-    """The q97 fact pair: (customer_sk, item_sk) int32 arrays per channel,
-    ~SF-proportional (SF1 store_sales is ~2.9M rows)."""
-    rng = np.random.RandomState(seed)
-    n = max(1000, int(2_800_000 * sf))
-    store = (rng.randint(1, max(2, n // 14), n).astype(np.int32),
-             rng.randint(1, 18_000, n).astype(np.int32))
-    catalog = (rng.randint(1, max(2, n // 14), n).astype(np.int32),
-               rng.randint(1, 18_000, n).astype(np.int32))
-    return store, catalog
+    """The q97 fact pair: ``(customer_sk, item_sk)`` int32 arrays per
+    channel, the concatenation of :func:`q97_blocks` (so the in-memory and
+    the streamed harness modes see the same table)."""
+    parts = {"store": ([], []), "catalog": ([], [])}
+    for side, cust, item in q97_blocks(sf, seed):
+        parts[side][0].append(cust)
+        parts[side][1].append(item)
+    return tuple((np.concatenate(parts[side][0]),
+                  np.concatenate(parts[side][1]))
+                 for side in ("store", "catalog"))
 
 
 def write_q97_parquet(outdir: str, sf: float = 0.05, seed: int = 42,

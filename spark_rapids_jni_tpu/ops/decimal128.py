@@ -61,7 +61,7 @@ def _multiply_kernel(a_hi, a_lo, b_hi, b_lo, *, a_cs, b_cs, prod_cs, interim):
         d_hi, d_lo = i256.to_i128(div)
         rounded = i256.divide_and_round(product, d_hi, d_lo)
         take = fdp > 0
-        product = jnp.where(take[..., None], rounded, product)
+        product = jnp.where(take[None], rounded, product)
         mult_cs = mult_cs + jnp.where(take, fdp, 0)
 
     exponent = jnp.int32(prod_cs) - mult_cs
@@ -78,7 +78,7 @@ def _multiply_kernel(a_hi, a_lo, b_hi, b_lo, *, a_cs, b_cs, prod_cs, interim):
     scaled_down = i256.divide_and_round(product, dv_hi, dv_lo)
 
     up = exponent < 0
-    final = jnp.where(up[..., None], scaled_up, scaled_down)
+    final = jnp.where(up[None], scaled_up, scaled_down)
     overflow = jnp.where(
         up,
         up_overflow | i256.is_greater_than_decimal_38(scaled_up),
@@ -239,7 +239,7 @@ def _remainder_kernel(a_hi, a_lo, b_hi, b_lo, *, a_cs, b_cs, rem_cs):
     rem = i256.add(abs_n, i256.negate(less_n))
 
     overflow = div_zero | i256.is_greater_than_decimal_38(rem)
-    rem = jnp.where(n_neg[..., None], i256.negate(rem), rem)
+    rem = jnp.where(n_neg[None], i256.negate(rem), rem)
     r_hi, r_lo = i256.to_i128(rem)
     r_hi = jnp.where(div_zero, jnp.int64(0), r_hi)
     r_lo = jnp.where(div_zero, jnp.uint64(0), r_lo)

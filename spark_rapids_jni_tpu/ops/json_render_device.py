@@ -342,7 +342,7 @@ def _float_gather(b, kind, start, end, NF: int, WS: int):
 def _float_render(bits):
     """Ryu digits + Java formatting of parsed float bits, with the
     quoted-Infinity quirk (ftos_converter.cuh:1154)."""
-    from spark_rapids_jni_tpu.ops.float_to_string import _d2d, _emit
+    from spark_rapids_jni_tpu.ops.float_to_string import _d2d, _emit_fast
 
     u = bits.astype(jnp.uint64)
     mant = u & jnp.uint64((1 << 52) - 1)
@@ -357,16 +357,20 @@ def _float_render(bits):
         jnp.where(is_inf, jnp.where(negative, _I32(3), _I32(2)),
                   jnp.where(is_zero,
                             jnp.where(negative, _I32(1), _I32(0)), _I32(-1))))
-    padded, lens = _emit(output, e10, negative, special_id, is_float=False)
+    # the strength-reduced renderer (byte-identical to the _emit oracle,
+    # which compiles far slower on the TPU)
+    padded, lens = _emit_fast(output, e10, negative, special_id, False)
     lens = lens.astype(_I64)
 
     # quoted-Infinity: shift right by one and wrap in quotes
     out_len = jnp.where(is_inf, lens + 2, lens)
     lane_w = jnp.arange(_FLOAT_W, dtype=_I64)[None, :]
-    srcpos = jnp.clip(lane_w - is_inf[:, None], 0, padded.shape[1] - 1)
-    gathered = _take_rows(
-        jnp.pad(padded, ((0, 0), (0, max(_FLOAT_W - padded.shape[1], 0)))),
-        srcpos)
+    wide = jnp.pad(padded, ((0, 0), (0, max(_FLOAT_W - padded.shape[1], 0))))
+    wide = wide[:, :_FLOAT_W]
+    # a shift by 0 or 1 lane is a select between two static slices, not a
+    # per-row gather; lanes outside the text are masked below either way
+    shifted = jnp.concatenate([wide[:, :1], wide[:, :-1]], axis=1)
+    gathered = jnp.where(is_inf[:, None], shifted, wide)
     in_text = (lane_w >= is_inf[:, None]) & \
         (lane_w < (lens + is_inf)[:, None])
     ftext = jnp.where(in_text, gathered, _U8(0))

@@ -10,7 +10,6 @@ from spark_rapids_jni_tpu.parallel.mesh import (
     data_sharding,
     model_sharding,
     replicated,
-    shard_map,
 )
 from spark_rapids_jni_tpu.parallel.shuffle import (
     ShuffleResult,
@@ -32,7 +31,6 @@ __all__ = [
     "data_sharding",
     "model_sharding",
     "replicated",
-    "shard_map",
     "PaddedStrings",
     "ShuffleResult",
     "ShuffledTable",

@@ -25,30 +25,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-def shard_map(body, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` compat wrapper.
-
-    Newer jax exposes shard_map at the top level with a ``check_vma``
-    kwarg; 0.4.x only has ``jax.experimental.shard_map.shard_map`` with
-    the same semantics under ``check_rep``.  Every shard_map in this
-    package goes through here so the framework runs on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_size(name: str):
-    """``jax.lax.axis_size`` compat (0.4.x spells it psum(1, name))."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def make_mesh(
     shape: Optional[Tuple[int, int]] = None,
     *,

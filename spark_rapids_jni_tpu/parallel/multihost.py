@@ -16,9 +16,11 @@ psum-style reductions cross DCN.  This is the standing-in for "NCCL/MPI
 backend that scales to multi-host": there is no transport code to write —
 placement + sharding annotations are the backend.
 
-On TPU pods the coordinator/process topology comes from the environment and
-``initialize()`` needs no arguments; explicit arguments support CPU/GPU
-multi-process clusters and tests.
+A cluster runtime that exports a coordinator (``JAX_COORDINATOR_ADDRESS``,
+Slurm, MPI) lets ``initialize()`` run with no arguments; explicit arguments
+support everything else, tests included.  With neither, the process stays
+single-process: JAX's own auto-detection would query the cloud metadata
+server, which a single TPU host need not have.
 """
 
 from __future__ import annotations
@@ -48,10 +50,10 @@ _CLUSTER_ENV_MARKERS = (
 def _cluster_env_present() -> bool:
     """True when the environment names a genuinely multi-process cluster.
 
-    TPU_WORKER_HOSTNAMES needs a value check, not a presence check: TPU
-    runtimes (including single-chip tunnels) set it to the one local host,
-    and a one-entry list is exactly the single-process case this module
-    must treat as benign.
+    TPU_WORKER_HOSTNAMES needs a value check, not a presence check: a
+    single-host TPU runtime sets it to the one local host, and a one-entry
+    list is exactly the single-process case this module must treat as
+    benign.
     """
     if any(m in os.environ for m in _CLUSTER_ENV_MARKERS):
         return True
@@ -66,10 +68,9 @@ def initialize(
 ) -> None:
     """Join the JAX process group (idempotent).
 
-    With no arguments, relies on JAX's cluster auto-detection (Cloud TPU
-    pod runtime, GKE, Slurm, ...); when no cluster environment is detected
-    — the plain single-process case — the auto-detect attempt fails and
-    this degrades to a no-op, so single-host code paths need no changes.
+    With no arguments, joins only when the environment names a cluster
+    (:data:`_CLUSTER_ENV_MARKERS`) and is a no-op otherwise, so single-host
+    code paths need no changes and never reach for a metadata server.
     Must run before the backend is first touched.
     """
     global _initialized
@@ -77,6 +78,9 @@ def initialize(
         return
     explicit = any(a is not None for a in
                    (coordinator_address, num_processes, process_id))
+    if not explicit and not any(m in os.environ
+                                for m in _CLUSTER_ENV_MARKERS):
+        return
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_jni_tpu.obs.seam import COLLECTIVE, instrument
-from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS, axis_size
+from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 
 
 class ShuffleResult(NamedTuple):
@@ -114,7 +114,7 @@ def all_to_all_shuffle(
     The seam range covers the dispatch (trace) boundary; on-chip timing comes
     from the profiler's optional XPlane capture.
     """
-    ndev = axis_size(axis)
+    ndev = jax.lax.axis_size(axis)
     if row_valid is not None:
         # invalid rows ride the out-of-range bucket: excluded from ranking,
         # capacity, sending, and the dropped count

@@ -124,11 +124,13 @@ class PlanCache:
         with self._lock:
             del self._building[key]
             self._stats["misses"] += 1
-            if not entry.aot:
+            if entry.aot_error:
                 # the build fell back from AOT lower+compile to plain jit
                 # (entry.aot_error says why): surfaced as a gauge so a
                 # swallowed trace failure is visible in telemetry, not
-                # silently deferred to the first launch
+                # silently deferred to the first launch.  Entries that
+                # never compile (row_conversion's host-built permutation
+                # plans) carry no error and are not fallbacks.
                 self._stats["aot_fallbacks"] += 1
                 self._last_aot_error = entry.aot_error
             # builder-reported phase split when available (AOT lower/

@@ -229,14 +229,14 @@ def _emit_union(node: ir.Union, ctx: _Ctx) -> _Rows:
 
 @emitter(ir.Exchange)
 def _emit_exchange(node: ir.Exchange, ctx: _Ctx) -> _Rows:
-    from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS, axis_size
+    from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
     from spark_rapids_jni_tpu.parallel.shuffle import (
         all_to_all_shuffle,
         partition_of,
     )
 
     rows = _emit(node.child, ctx)
-    dp = axis_size(DATA_AXIS)
+    dp = jax.lax.axis_size(DATA_AXIS)
     part = partition_of(_eval(node.key, rows.cols), dp)
     ex = all_to_all_shuffle(
         {f: rows.cols[f] for f in node.fields}, part, node.capacity,
@@ -468,15 +468,12 @@ def compile_plan(plan: ir.Plan, mesh, signature: Tuple) -> CompiledPlan:
         else:
             from jax.sharding import PartitionSpec as P
 
-            from spark_rapids_jni_tpu.parallel.mesh import (
-                DATA_AXIS,
-                shard_map,
-            )
+            from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 
             in_specs = tuple(
                 P(DATA_AXIS) if kind == "scan" else P()
                 for kind, _t, _f in layout)
-            step = jax.jit(shard_map(
+            step = jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=in_specs,
                 out_specs=tuple(P() for _ in out_names),
                 check_vma=False,

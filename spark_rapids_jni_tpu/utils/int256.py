@@ -4,8 +4,10 @@ The reference's DECIMAL128 math (decimal_utils.cu `chunked256`, multiply at
 decimal_utils.cu:126, long division at :148, half-up rounding at :192) runs on
 native 64/128-bit scalars per CUDA thread.  TPUs have neither int128 nor a
 per-row scalar unit; here a 256-bit value is a little-endian tensor of eight
-32-bit limbs (``uint32[..., 8]``) so limb products fit exactly in uint64 lanes
-and every operation is elementwise over the leading (row) axes, safe under jit.
+32-bit limbs, limb-major (``uint32[8, ...]``), so limb products fit exactly in
+uint64 lanes and every operation is elementwise over the row axes, safe under
+jit.  Limbs lead because a minor dimension of 8 pads to 128 lanes on the TPU:
+row-major limbs made DECIMAL128 multiply at 2^22 rows need 18.4 GB of HBM.
 
 Sign convention: two's complement over the full 256 bits (limb 7's top bit).
 """
@@ -35,7 +37,7 @@ POW10 = np.stack([const256(10**k) for k in range(77)])  # (77, 8) uint32
 
 
 def from_i128(hi, lo):
-    """Sign-extend (hi int64, lo uint64) into limbs[..., 8]."""
+    """Sign-extend (hi int64, lo uint64) into limbs[8, ...]."""
     hi = hi.astype(jnp.int64)
     lo = lo.astype(jnp.uint64)
     sign = jnp.where(hi < 0, _U32(0xFFFFFFFF), _U32(0))
@@ -49,11 +51,11 @@ def from_i128(hi, lo):
         sign,
         sign,
     ]
-    return jnp.stack(limbs, axis=-1)
+    return jnp.stack(limbs, axis=0)
 
 
 def from_i64(x):
-    """Sign-extend int64 into limbs[..., 8]."""
+    """Sign-extend int64 into limbs[8, ...]."""
     x = x.astype(jnp.int64)
     hi = jnp.where(x < 0, jnp.int64(-1), jnp.int64(0))
     return from_i128(hi, x.astype(jnp.uint64))
@@ -62,19 +64,19 @@ def from_i64(x):
 def to_i128(limbs):
     """Truncate to the low 128 bits as (hi int64, lo uint64)."""
     l = limbs.astype(jnp.uint64)
-    lo = l[..., 0] | (l[..., 1] << _U64(32))
-    hi = (l[..., 2] | (l[..., 3] << _U64(32))).astype(jnp.int64)
+    lo = l[0] | (l[1] << _U64(32))
+    hi = (l[2] | (l[3] << _U64(32))).astype(jnp.int64)
     return hi, lo
 
 
 def to_i64(limbs):
     """Truncate to the low 64 bits as signed int64 (reference as_64_bits)."""
     l = limbs.astype(jnp.uint64)
-    return (l[..., 0] | (l[..., 1] << _U64(32))).astype(jnp.int64)
+    return (l[0] | (l[1] << _U64(32))).astype(jnp.int64)
 
 
 def is_negative(limbs):
-    return (limbs[..., 7] >> _U32(31)) != _U32(0)
+    return (limbs[7] >> _U32(31)) != _U32(0)
 
 
 def add(a, b):
@@ -82,10 +84,10 @@ def add(a, b):
     out = []
     carry = _U64(0)
     for i in range(NLIMBS):
-        s = a[..., i].astype(_U64) + b[..., i].astype(_U64) + carry
+        s = a[i].astype(_U64) + b[i].astype(_U64) + carry
         out.append((s & _M32).astype(_U32))
         carry = s >> _U64(32)
-    return jnp.stack(out, axis=-1)
+    return jnp.stack(out, axis=0)
 
 
 def add_small(a, d):
@@ -97,21 +99,21 @@ def negate(a):
     out = []
     carry = _U64(1)
     for i in range(NLIMBS):
-        s = (~a[..., i]).astype(_U64) + carry
+        s = (~a[i]).astype(_U64) + carry
         out.append((s & _M32).astype(_U32))
         carry = s >> _U64(32)
-    return jnp.stack(out, axis=-1)
+    return jnp.stack(out, axis=0)
 
 
 def abs256(a):
-    return jnp.where(is_negative(a)[..., None], negate(a), a)
+    return jnp.where(is_negative(a)[None], negate(a), a)
 
 
 def multiply(a, b):
     """Schoolbook 8x8 32-bit-limb multiply keeping the low 256 bits
     (reference multiply, decimal_utils.cu:126)."""
-    au = [a[..., i].astype(_U64) for i in range(NLIMBS)]
-    bu = [b[..., i].astype(_U64) for i in range(NLIMBS)]
+    au = [a[i].astype(_U64) for i in range(NLIMBS)]
+    bu = [b[i].astype(_U64) for i in range(NLIMBS)]
     r = [jnp.zeros_like(au[0]) for _ in range(NLIMBS)]
     for b_idx in range(NLIMBS):
         carry = _U64(0)
@@ -120,16 +122,16 @@ def multiply(a, b):
             m = au[a_idx] * bu[b_idx] + r[r_idx] + carry
             r[r_idx] = m & _M32
             carry = m >> _U64(32)
-    return jnp.stack([x.astype(_U32) for x in r], axis=-1)
+    return jnp.stack([x.astype(_U32) for x in r], axis=0)
 
 
 def lt_unsigned(a, b):
     """Unsigned a < b, lexicographic from the high limb down."""
-    lt = jnp.zeros(a.shape[:-1], dtype=jnp.bool_)
-    eq = jnp.ones(a.shape[:-1], dtype=jnp.bool_)
+    lt = jnp.zeros(a.shape[1:], dtype=jnp.bool_)
+    eq = jnp.ones(a.shape[1:], dtype=jnp.bool_)
     for i in range(NLIMBS - 1, -1, -1):
-        lt = lt | (eq & (a[..., i] < b[..., i]))
-        eq = eq & (a[..., i] == b[..., i])
+        lt = lt | (eq & (a[i] < b[i]))
+        eq = eq & (a[i] == b[i])
     return lt
 
 
@@ -138,13 +140,13 @@ def gte_unsigned(a, b):
 
 
 def eq256(a, b):
-    return jnp.all(a == b, axis=-1)
+    return jnp.all(a == b, axis=0)
 
 
 def _bcast(table_row, like):
-    """Broadcast a host (8,) limb constant against limbs[..., 8]."""
-    c = jnp.asarray(table_row)
-    return jnp.broadcast_to(c, like.shape[:-1] + (NLIMBS,))
+    """Broadcast a host (8,) limb constant against limbs[8, ...]."""
+    c = jnp.asarray(table_row).reshape((NLIMBS,) + (1,) * (like.ndim - 1))
+    return jnp.broadcast_to(c, like.shape)
 
 
 def pow_ten(k, like):
@@ -152,8 +154,8 @@ def pow_ten(k, like):
     (clipped to [0, 76]) or a python int."""
     if isinstance(k, int):
         return _bcast(POW10[k], like)
-    table = jnp.asarray(POW10)
-    return table[jnp.clip(k, 0, 76)]
+    table = jnp.asarray(POW10.T)  # (8, 77)
+    return table[:, jnp.clip(k, 0, 76)]
 
 
 def precision10(a):
@@ -161,16 +163,14 @@ def precision10(a):
     decimal_utils.cu:520: NOT digit count — exact powers of ten return their
     exponent).  Equals the number of k in [0, 76] with 10**k < |a|."""
     mag = abs256(a)
-    table = jnp.asarray(POW10)  # (77, 8)
-    # lt_unsigned(pow10[k], mag) for all k at once: broadcast rows axis.
-    p = jnp.broadcast_to(table, mag.shape[:-1] + (77, NLIMBS))
-    lt = jnp.zeros(p.shape[:-1], dtype=jnp.bool_)
-    eq = jnp.ones(p.shape[:-1], dtype=jnp.bool_)
-    m = mag[..., None, :]
+    # lt_unsigned(pow10[k], mag) for all k at once, k leading: [77, ...]
+    p = jnp.asarray(POW10).reshape((77, NLIMBS) + (1,) * (mag.ndim - 1))
+    lt = jnp.zeros((77,) + mag.shape[1:], dtype=jnp.bool_)
+    eq = jnp.ones((77,) + mag.shape[1:], dtype=jnp.bool_)
     for i in range(NLIMBS - 1, -1, -1):
-        lt = lt | (eq & (p[..., i] < m[..., i]))
-        eq = eq & (p[..., i] == m[..., i])
-    return jnp.sum(lt, axis=-1).astype(jnp.int32)
+        lt = lt | (eq & (p[:, i] < mag[i][None]))
+        eq = eq & (p[:, i] == mag[i][None])
+    return jnp.sum(lt, axis=0).astype(jnp.int32)
 
 
 def is_greater_than_decimal_38(a):
@@ -200,7 +200,7 @@ def divide_unsigned(n, d_hi, d_lo):
     q_limbs = []
 
     for block in range(NLIMBS - 1, -1, -1):
-        nb = n[..., block].astype(_U64)
+        nb = n[block].astype(_U64)
 
         def body(i, state, nb=nb):
             r_hi, r_lo, q_block = state
@@ -223,7 +223,7 @@ def divide_unsigned(n, d_hi, d_lo):
         q_limbs.append((q_block & _M32).astype(_U32))
 
     q_limbs.reverse()
-    return jnp.stack(q_limbs, axis=-1), r_hi, r_lo
+    return jnp.stack(q_limbs, axis=0), r_hi, r_lo
 
 
 def divide(n, d_hi, d_lo):
@@ -240,7 +240,7 @@ def divide(n, d_hi, d_lo):
     ad_hi = jnp.where(d_neg, nd_hi, d_hi.astype(_U64))
     ad_lo = jnp.where(d_neg, nd_lo, d_lo)
     q, r_hi, r_lo = divide_unsigned(abs_n, ad_hi, ad_lo)
-    q = jnp.where((d_neg != n_neg)[..., None], negate(q), q)
+    q = jnp.where((d_neg != n_neg)[None], negate(q), q)
     # negate remainder where n negative
     nr_lo = (~r_lo) + _U64(1)
     nr_hi = (~r_hi) + (nr_lo == _U64(0)).astype(_U64)

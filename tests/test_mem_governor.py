@@ -4,6 +4,7 @@ a budget-capped fake resource, failures are injected, and exact thread-state
 transitions are asserted.  No accelerator needed — the arbiter is host-native.
 """
 
+import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -531,3 +532,28 @@ def test_pool_wait_counts_as_blocked_for_deadlock(gov):
     # the acquire cannot have succeeded: 90 of 100 was held by a pool-blocked
     # thread, so the watchdog must have escalated task 2
     assert outcome.get("escalated") is True
+
+
+@pytest.mark.skipif(__import__("shutil").which("g++") is None,
+                    reason="no g++ toolchain")
+def test_stale_arbiter_library_is_rebuilt(tmp_path):
+    """A library built from other source (or copied along with the tree)
+    is never loaded: the build is keyed on a hash of the source and the
+    compiler command, so a changed source gets a fresh build."""
+    import ctypes
+    import shutil
+
+    from spark_rapids_jni_tpu.mem import arbiter
+
+    src = tmp_path / "task_arbiter.cpp"
+    shutil.copy(arbiter._SRC, src)
+    # a stale build lying around under the old fixed name, newer than src
+    stale = tmp_path / "libtask_arbiter.so"
+    stale.write_bytes(b"not a shared object")
+    os.utime(stale, (time.time() + 3600,) * 2)
+    lib = arbiter._build_lib(str(src))
+    assert lib != str(stale) and os.path.exists(lib)
+    ctypes.CDLL(lib).arbiter_create  # a real build, loadable
+    src.write_text(src.read_text() + "\n// changed\n")
+    assert arbiter._lib_path(str(src)) != lib  # the edit forces a rebuild
+    assert arbiter._lib_path(arbiter._SRC) == arbiter._lib_path(arbiter._SRC)

@@ -56,7 +56,7 @@ def test_four_process_group_runs_distributed_q97():
 def _run_group_once(nproc: int):
     from conftest import scrubbed_cpu_env
 
-    env = scrubbed_cpu_env(2)  # boot_cpu_mesh must not re-exec the workers
+    env = scrubbed_cpu_env(2)
 
     coord = f"127.0.0.1:{_free_port()}"
     worker = os.path.join(_HERE, "multihost_worker.py")

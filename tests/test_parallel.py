@@ -10,7 +10,6 @@ from spark_rapids_jni_tpu.parallel import (
     all_to_all_shuffle,
     bucket_by_partition,
     make_mesh,
-    shard_map,
 )
 from spark_rapids_jni_tpu.models import (
     QueryStepConfig,
@@ -59,7 +58,7 @@ def test_all_to_all_shuffle_routes_rows(ndev):
         return ok[None], n_recv[None], res.dropped[None]
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P("data"), P("data")),

@@ -29,7 +29,6 @@ from spark_rapids_jni_tpu.parallel import (
     DATA_AXIS,
     make_mesh,
     materialize_strings,
-    shard_map,
     shuffle_table,
 )
 
@@ -59,7 +58,7 @@ def _shuffle_fn(mesh, capacity, width):
         return ex.columns, ex.valid, jax.lax.psum(ex.dropped, DATA_AXIS)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=tuple(P(DATA_AXIS) for _ in range(6)),
@@ -248,7 +247,7 @@ def test_jcudf_row_bytes_ride_the_exchange():
         ex = all_to_all_shuffle({"r": rows_rect}, part, n, axis=DATA_AXIS)
         return ex.columns["r"], ex.valid
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(DATA_AXIS), P(DATA_AXIS)), check_vma=False))
     part = (keys_np % NDEV).astype(np.int32)
