@@ -45,6 +45,7 @@ from spark_rapids_jni_tpu.mem.governor import (
     OutOfBudget,
 )
 from spark_rapids_jni_tpu.obs import flight as _flight
+from spark_rapids_jni_tpu.obs import trace as _trace
 
 __all__ = [
     "task_context",
@@ -119,7 +120,9 @@ def reservation(budget: BudgetedResource, nbytes: int):
     ``acquire`` drives the arbiter's pre_alloc/post_alloc protocol: it may
     block (another task holds the budget), raise RetryOOM/SplitAndRetryOOM
     (escalation decided this thread must retry or split), or raise
-    OutOfBudget (non-retryable; request exceeds the whole budget).
+    OutOfBudget (non-retryable; request exceeds the whole budget).  The
+    acquire, blocked wait included, is the ``admit`` span of the thread's
+    current trace context (none without one).
 
     The acquire crosses the ALLOC seam — the allocation-interception
     point of the reference's chaos/profiling stack (faultinj.cu hooks the
@@ -135,7 +138,8 @@ def reservation(budget: BudgetedResource, nbytes: int):
     # to the admission path (incl. the up-to-500 RetryOOM retry loop)
     if _seam._profiler_range is None and _seam._injector is None:
         t0 = 0
-        budget.acquire(nbytes)
+        with _trace.maybe_span(_trace.SPAN_ADMIT):
+            budget.acquire(nbytes)
         try:
             t0 = time.monotonic_ns()
             yield
@@ -165,7 +169,8 @@ def reservation(budget: BudgetedResource, nbytes: int):
         with _seam.seam(
                 _seam.ALLOC,
                 f"reserve:{'cpu' if budget.is_cpu else 'dev'}:{nbytes}"):
-            budget.acquire(nbytes)
+            with _trace.maybe_span(_trace.SPAN_ADMIT):
+                budget.acquire(nbytes)
             acquired = True
     except BaseException:
         # the seam __exit__ (profiler range close) runs AFTER a

@@ -494,7 +494,8 @@ def run_distributed_q97(
 
     ``manage_task=False`` joins a task context the caller already registered
     (the Spark shape: one dedicated thread registered per task runs many
-    ops); the default registers/ends ``task_id`` itself.
+    ops); the default registers/ends ``task_id`` itself, under a ``task``
+    root span when the thread has no trace context (obs/trace.py).
     """
     from spark_rapids_jni_tpu.mem.governed import (
         default_device_budget,
@@ -516,9 +517,13 @@ def run_distributed_q97(
 
     import contextlib
 
+    from spark_rapids_jni_tpu.obs import trace as _trace
+
     ctx = (task_context(budget.gov, task_id) if manage_task
            else contextlib.nullcontext())
-    with ctx:
+    root = (_trace.task_span(task_id, extra="plan:q97") if manage_task
+            else contextlib.nullcontext())
+    with root, ctx:
         return run_with_split_retry(
             budget, batch,
             nbytes_of=lambda b: q97_working_set_bytes(b, dp),

@@ -78,9 +78,9 @@ def _set_profiler(fn) -> None:
 def serialize_category(category: str) -> None:
     """Install (idempotently) a crossing lock for ``category``.
 
-    Reentrant: a launch crossing (``seam(COLLECTIVE, "launch:...")``)
-    re-enters on the same thread when the step traces through an
-    ``@instrument(COLLECTIVE, ...)``-wrapped collective at compile time.
+    Reentrant: a crossing of the category nested inside another on the
+    same thread (an ``@instrument``-wrapped call reached while a launch
+    crossing traces its step) must not deadlock on itself.
     The read-modify-write is guarded: two engines constructed
     concurrently must end up sharing ONE lock per category, or the
     serialization this exists for is void.

@@ -13,6 +13,16 @@ across the supervisor pipe, so one request's
 breakdown reconstructs LIVE from the telemetry plane (serve/telemetry.py)
 — not just post-hoc from anomaly dumps.
 
+The same vocabulary narrates a governed task outside the serving path:
+``run_distributed_q97`` and ``run_governed_plan`` open a ``task`` root
+(:func:`task_span`) when the thread has no context yet, and the governor
+and the plan runtime hang ``admit``, ``plan_pad``, ``plan_upload``,
+``plan_run`` and ``plan_download`` children under whatever context is
+current.  Every scoped span (:func:`span`, :func:`task_span`) also enters
+``jax.profiler.TraceAnnotation(<kind>, rid=<rid>)`` for its life, so under
+a profiler session it lands on the host plane of the XPlane, on the same
+clock as the device ops; without one the annotation is a no-op.
+
 Design constraints, in order:
 
 - **the hot path is two deque appends per span** — open and close are
@@ -44,13 +54,16 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from spark_rapids_jni_tpu.obs import flight as _flight
 
 __all__ = [
     "SPAN_QUEUE", "SPAN_DISPATCH", "SPAN_TRANSPORT", "SPAN_COMPUTE",
-    "SPAN_SCATTER", "SPAN_CACHE", "SPAN_KINDS",
+    "SPAN_SCATTER", "SPAN_CACHE", "SPAN_TASK", "SPAN_ADMIT", "SPAN_PLAN_PAD",
+    "SPAN_PLAN_UPLOAD", "SPAN_PLAN_RUN", "SPAN_PLAN_DOWNLOAD", "SPAN_KINDS",
     "TraceContext", "new_root", "child_of", "to_wire", "from_wire",
-    "open_span", "close_span", "span", "maybe_span",
+    "open_span", "close_span", "span", "maybe_span", "task_span",
     "push_current", "pop_current", "current",
     "waterfall", "chain_complete", "format_waterfall",
 ]
@@ -65,8 +78,17 @@ SPAN_CACHE = "cache_hit"      # result served from the result cache
 #                               (plans/rcache.py round 15): the request
 #                               skipped dispatch/compute entirely, so a
 #                               hit's waterfall is queue -> cache_hit
+# a governed task's phases (the direct governed path and, under a compute
+# span, a served request's): the root, then its children in run order
+SPAN_TASK = "task"                # root of one governed task (task_span)
+SPAN_ADMIT = "admit"              # budget.acquire, any blocked wait included
+SPAN_PLAN_PAD = "plan_pad"        # pad_tables onto the pow2 lattice
+SPAN_PLAN_UPLOAD = "plan_upload"  # device_put enqueue + host staging copy
+SPAN_PLAN_RUN = "plan_run"        # launch through block_until_ready
+SPAN_PLAN_DOWNLOAD = "plan_download"  # outputs to numpy + the dropped check
 SPAN_KINDS = (SPAN_QUEUE, SPAN_DISPATCH, SPAN_TRANSPORT, SPAN_COMPUTE,
-              SPAN_SCATTER, SPAN_CACHE)
+              SPAN_SCATTER, SPAN_CACHE, SPAN_TASK, SPAN_ADMIT, SPAN_PLAN_PAD,
+              SPAN_PLAN_UPLOAD, SPAN_PLAN_RUN, SPAN_PLAN_DOWNLOAD)
 
 # span ids are (pid | counter) packed so concurrently-opened spans across
 # executor processes never collide in a merged timeline; 20 pid bits
@@ -155,7 +177,11 @@ def open_span(parent: Optional[TraceContext], kind: str, *,
     :func:`close_span`."""
     if parent is None:
         return None
-    ctx = child_of(parent)
+    return _open(child_of(parent), kind, task_id, extra)
+
+
+def _open(ctx: TraceContext, kind: str, task_id: int,
+          extra: str) -> SpanHandle:
     h = SpanHandle(ctx, kind, task_id, extra, time.monotonic_ns())
     _flight.record(_flight.EV_SPAN_OPEN, task_id,
                    detail=_detail(ctx, kind, extra))
@@ -183,6 +209,14 @@ def span(parent: Optional[TraceContext], kind: str, *, task_id: int = -1,
     if h is None:
         yield None
         return
+    with _scoped(h) as ctx:
+        yield ctx
+
+
+@contextlib.contextmanager
+def _scoped(h: SpanHandle):  # resource: release span
+    """The life of an opened span on this thread: current context and
+    profiler annotation inside, closed on every exit."""
     # close_span owns the whole window from here: push/pop stay paired
     # inside it (push_current is a bare thread-local append — it either
     # appends or leaves the stack untouched), and no fault between open
@@ -190,7 +224,8 @@ def span(parent: Optional[TraceContext], kind: str, *, task_id: int = -1,
     try:
         push_current(h.ctx)
         try:
-            yield h.ctx
+            with TraceAnnotation(h.kind, rid=h.ctx.rid):
+                yield h.ctx
         finally:
             pop_current()
     finally:
@@ -198,16 +233,32 @@ def span(parent: Optional[TraceContext], kind: str, *, task_id: int = -1,
 
 
 @contextlib.contextmanager
-def maybe_span(kind: str, *, extra: str = ""):
-    """A child span under the thread's current context, or a no-op when
-    none is set — how deep layers (serve/shuffle.py fetches) narrate
-    without threading a context through every signature."""
-    cur = current()
-    if cur is None:
+def task_span(task_id: int = -1, *, extra: str = ""):
+    """The ``task`` root of one governed task, when the thread has no
+    current context (a served request's compute span already roots its
+    children, and a served request with spans off must stay span-free:
+    its runners pass ``manage_task=False`` and never reach here).  Each
+    root takes a fresh process-unique rid: task ids repeat across runs."""
+    if current() is not None:
         yield None
         return
-    with span(cur, kind, extra=extra) as ctx:
+    root = TraceContext(_new_span_id(), _new_span_id(), 0)
+    with _scoped(_open(root, SPAN_TASK, task_id, extra)) as ctx:
         yield ctx
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def maybe_span(kind: str, *, extra: str = ""):
+    """A child span under the thread's current context, or a no-op when
+    none is set — how deep layers (serve/shuffle.py fetches, the plan
+    runtime's phases) narrate without threading a context through every
+    signature.  The no-op costs one thread-local lookup."""
+    cur = current()
+    if cur is None:
+        return _NO_SPAN
+    return span(cur, kind, extra=extra)
 
 
 # thread-local current-context stack (handler threads set it around the

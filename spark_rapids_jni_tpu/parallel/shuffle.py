@@ -24,7 +24,6 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_jni_tpu.obs.seam import COLLECTIVE, instrument
 from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 
 
@@ -94,7 +93,6 @@ def bucket_by_partition(part: jnp.ndarray, n_parts: int, capacity: int):
     return slot, in_cap, counts
 
 
-@instrument(COLLECTIVE, "all_to_all_shuffle")
 def all_to_all_shuffle(
     columns: Dict[str, jnp.ndarray],
     part: jnp.ndarray,
@@ -111,40 +109,46 @@ def all_to_all_shuffle(
     shard multiple) rely on this so pads can't evict real rows or trigger
     spurious capacity retries.
 
-    The seam range covers the dispatch (trace) boundary; on-chip timing comes
-    from the profiler's optional XPlane capture.
+    The ops of its three phases carry stable names in the HLO metadata
+    (``jax.named_scope``): ``exchange_bucket``, ``exchange_scatter`` (the
+    send buffers) and ``exchange_all_to_all``, so a device trace can
+    attribute time to them.
     """
     ndev = jax.lax.axis_size(axis)
-    if row_valid is not None:
-        # invalid rows ride the out-of-range bucket: excluded from ranking,
-        # capacity, sending, and the dropped count
-        part = jnp.where(row_valid, part, ndev)
-    slot, in_cap, _counts = bucket_by_partition(part, ndev, capacity)
-    sendable = in_cap if row_valid is None else in_cap & row_valid
-    if row_valid is None:
-        dropped = jnp.sum(~in_cap).astype(jnp.int32)
-    else:
-        dropped = jnp.sum(row_valid & ~in_cap).astype(jnp.int32)
+    with jax.named_scope("exchange_bucket"):
+        if row_valid is not None:
+            # invalid rows ride the out-of-range bucket: excluded from
+            # ranking, capacity, sending, and the dropped count
+            part = jnp.where(row_valid, part, ndev)
+        slot, in_cap, _counts = bucket_by_partition(part, ndev, capacity)
+        sendable = in_cap if row_valid is None else in_cap & row_valid
+        if row_valid is None:
+            dropped = jnp.sum(~in_cap).astype(jnp.int32)
+        else:
+            dropped = jnp.sum(row_valid & ~in_cap).astype(jnp.int32)
 
-    send_valid = (
-        jnp.zeros((ndev * capacity,), jnp.bool_)
-        .at[jnp.where(sendable, slot, ndev * capacity)]
-        .set(True, mode="drop")
-        .reshape(ndev, capacity)
-    )
-
-    recv_cols = {}
-    for name, data in columns.items():
-        send = (
-            jnp.zeros((ndev * capacity,) + data.shape[1:], data.dtype)
-            .at[jnp.where(sendable, slot, ndev * capacity)]
-            .set(data, mode="drop")
-            .reshape((ndev, capacity) + data.shape[1:])
+    with jax.named_scope("exchange_scatter"):
+        dest = jnp.where(sendable, slot, ndev * capacity)
+        send_valid = (
+            jnp.zeros((ndev * capacity,), jnp.bool_)
+            .at[dest].set(True, mode="drop")
+            .reshape(ndev, capacity)
         )
-        recv = jax.lax.all_to_all(send, axis, split_axis=0, concat_axis=0, tiled=False)
-        recv_cols[name] = recv.reshape((ndev * capacity,) + data.shape[1:])
+        sends = {
+            name: jnp.zeros((ndev * capacity,) + data.shape[1:], data.dtype)
+            .at[dest].set(data, mode="drop")
+            .reshape((ndev, capacity) + data.shape[1:])
+            for name, data in columns.items()
+        }
 
-    recv_valid = jax.lax.all_to_all(
-        send_valid, axis, split_axis=0, concat_axis=0, tiled=False
-    ).reshape(ndev * capacity)
+    with jax.named_scope("exchange_all_to_all"):
+        recv_cols = {
+            name: jax.lax.all_to_all(
+                send, axis, split_axis=0, concat_axis=0, tiled=False
+            ).reshape((ndev * capacity,) + send.shape[2:])
+            for name, send in sends.items()
+        }
+        recv_valid = jax.lax.all_to_all(
+            send_valid, axis, split_axis=0, concat_axis=0, tiled=False
+        ).reshape(ndev * capacity)
     return ShuffleResult(recv_cols, recv_valid, dropped)

@@ -344,8 +344,10 @@ def _emit_presence_count(node: ir.PresenceCount,
     from spark_rapids_jni_tpu.models.q97 import _count_runs
 
     rows = _emit(node.child, ctx)
-    so, co, b = _count_runs(rows.cols[node.key],
-                            rows.cols[node.tag] == 1, rows.mask)
+    # a stable name for the sort-merge count's ops in the HLO metadata
+    with jax.named_scope("presence_count"):
+        so, co, b = _count_runs(rows.cols[node.key],
+                                rows.cols[node.tag] == 1, rows.mask)
     return dict(zip(node.names, (so, co, b)))
 
 

@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from spark_rapids_jni_tpu.obs import flight as _flight
+from spark_rapids_jni_tpu.obs import trace as _trace
 from spark_rapids_jni_tpu.plans import ir
 from spark_rapids_jni_tpu.plans.cache import plan_cache
 from spark_rapids_jni_tpu.plans.compiler import (
@@ -244,7 +245,10 @@ def plan_working_set_bytes(plan: ir.Plan, tables: Tables, dp: int) -> int:
 
 
 def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
-    """ONE fused launch: pad, compile (cached), upload, run, download.
+    """ONE fused launch: pad, compile (cached), upload, run, download —
+    each but the compile lookup a child span of the thread's current
+    trace context (``plan_pad``, ``plan_upload``, ``plan_run``,
+    ``plan_download``; no-ops without one).
 
     Raises :class:`mem.governed.ShuffleCapacityExceeded` when an
     Exchange overflowed (``dropped > 0``) — the caller grows the
@@ -268,11 +272,16 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
         dp = mesh.shape[DATA_AXIS]
         shardings = (NamedSharding(mesh, P(DATA_AXIS)),
                      NamedSharding(mesh, P()))
-    padded = pad_tables(plan, tables, dp)
+    with _trace.maybe_span(_trace.SPAN_PLAN_PAD):
+        padded = pad_tables(plan, tables, dp)
     compiled = cached_compile(plan, mesh, padded)
     sig = ir.plan_signature(plan)
     scans = {s.table for s in ir.scan_tables(plan)}
-    with seam(TRANSFER, f"plan_upload:{plan.name}"):
+    # device_put is asynchronous: the seam range and the span time the
+    # enqueue and the host staging copy; a transfer still in flight when
+    # the launch waits shows as device idle inside plan_run
+    with seam(TRANSFER, f"plan_upload:{plan.name}"), _trace.maybe_span(
+            _trace.SPAN_PLAN_UPLOAD):
         flat = []
         for _kind, table, field in _layout_of(compiled):
             arr = padded[table][field]
@@ -281,17 +290,19 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
             else:
                 flat.append(jax.device_put(
                     arr, shardings[0] if table in scans else shardings[1]))
-    t0 = time.perf_counter()
-    with seam(COLLECTIVE, f"launch:plan:{sig}"):
-        out = compiled.fn(*flat)
-        jax.block_until_ready(out)
-    plan_cache.record_execute(time.perf_counter() - t0)
-    outputs = {name: np.asarray(v)
-               for name, v in zip(compiled.out_names, out)}
-    if int(outputs.get("dropped", 0)) > 0:
-        raise ShuffleCapacityExceeded(
-            f"{int(outputs['dropped'])} rows overflowed the plan's "
-            f"exchange capacity")
+    with _trace.maybe_span(_trace.SPAN_PLAN_RUN):
+        t0 = time.perf_counter()
+        with seam(COLLECTIVE, f"launch:plan:{sig}"):
+            out = compiled.fn(*flat)
+            jax.block_until_ready(out)
+        plan_cache.record_execute(time.perf_counter() - t0)
+    with _trace.maybe_span(_trace.SPAN_PLAN_DOWNLOAD):
+        outputs = {name: np.asarray(v)
+                   for name, v in zip(compiled.out_names, out)}
+        if int(outputs.get("dropped", 0)) > 0:
+            raise ShuffleCapacityExceeded(
+                f"{int(outputs['dropped'])} rows overflowed the plan's "
+                f"exchange capacity")
     return outputs
 
 
@@ -375,7 +386,9 @@ def run_governed_plan(
     re-runs the fused program on the same batch, SplitAndRetryOOM halves
     every scan table and re-executes the fused program per half (NOT a
     disband into per-op launches), and partial outputs combine by
-    addition.  One flight-recorder task spans the plan.
+    addition.  One flight-recorder task spans the plan; with
+    ``manage_task`` and no current trace context, so does one ``task``
+    root span, the parent of every piece's child spans.
     """
     from spark_rapids_jni_tpu import config
     from spark_rapids_jni_tpu.mem.governed import (
@@ -409,7 +422,6 @@ def run_governed_plan(
     # upload below moves anything to the device.
     ckey = cdeps = None
     if config.get("serve_result_cache"):
-        from spark_rapids_jni_tpu.obs import trace as _trace
         from spark_rapids_jni_tpu.plans.rcache import (
             plan_result_key,
             result_cache,
@@ -455,7 +467,9 @@ def run_governed_plan(
 
     ctx = (task_context(budget.gov, task_id) if manage_task
            else contextlib.nullcontext())
-    with ctx:
+    root = (_trace.task_span(task_id, extra=f"plan:{plan.name}")
+            if manage_task else contextlib.nullcontext())
+    with root, ctx:
         out = run_with_split_retry(
             budget, tables,
             nbytes_of=nbytes_of or (
