@@ -14,7 +14,6 @@ from spark_rapids_jni_tpu.parallel.mesh import (
 from spark_rapids_jni_tpu.parallel.shuffle import (
     ShuffleResult,
     all_to_all_shuffle,
-    bucket_by_partition,
 )
 from spark_rapids_jni_tpu.parallel.table_shuffle import (
     PaddedStrings,
@@ -35,7 +34,6 @@ __all__ = [
     "ShuffleResult",
     "ShuffledTable",
     "all_to_all_shuffle",
-    "bucket_by_partition",
     "initialize_multihost",
     "is_multihost",
     "make_pod_mesh",

@@ -5,8 +5,16 @@ its TPU-native replacement (SURVEY.md §2.3 planning note): rows move between
 devices with a single dense `all_to_all` over ICI/DCN instead of point-to-point
 RDMA.  XLA requires static shapes, so the exchange uses fixed-capacity buckets:
 
-    local rows --bucket by hash % ndev--> [ndev, capacity] padded send buffer
-              --all_to_all--> [ndev, capacity] receive buffer + slot-valid mask
+    local rows --stable sort by destination (hash % ndev), columns as
+               payload--> ndev contiguous runs
+               --one length-capacity slice per run--> [ndev, capacity]
+               padded send buffer
+               --all_to_all--> [ndev, capacity] receive buffer + slot-valid mask
+
+Slot ``p * capacity + r`` of the send buffer holds the r-th row bound for
+device p, in local row order.  The runs' starts come from a binary search over
+the sorted destinations, so the buffers are built by copies: no scatter, no
+per-row counting.
 
 Capacity defaults to the local row count (no row can ever be dropped); callers
 with bounded skew can pass a smaller capacity and check `dropped` (a per-shard
@@ -71,28 +79,6 @@ def quantized_rows(n: int, mult: int) -> int:
     return mult * next_pow2(max(1, -(-int(n) // mult)))
 
 
-def bucket_by_partition(part: jnp.ndarray, n_parts: int, capacity: int):
-    """Assign each local row a slot in a [n_parts, capacity] send layout.
-
-    Returns (slot index [n], in_capacity mask [n], per-bucket counts [n_parts]).
-    Rows overflowing a bucket get mask False.
-    """
-    n = part.shape[0]
-    # rank of each row within its partition = number of earlier rows with same part
-    # computed stably via sort: order rows by partition, rank = position - start.
-    order = jnp.argsort(part, stable=True)
-    sorted_part = part[order]
-    counts = jnp.bincount(part, length=n_parts).astype(jnp.int32)
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)[:-1]]
-    )
-    rank_sorted = jnp.arange(n, dtype=jnp.int32) - starts[sorted_part]
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted)
-    in_cap = rank < capacity
-    slot = part.astype(jnp.int32) * capacity + jnp.minimum(rank, capacity - 1)
-    return slot, in_cap, counts
-
-
 def all_to_all_shuffle(
     columns: Dict[str, jnp.ndarray],
     part: jnp.ndarray,
@@ -110,36 +96,55 @@ def all_to_all_shuffle(
     spurious capacity retries.
 
     The ops of its three phases carry stable names in the HLO metadata
-    (``jax.named_scope``): ``exchange_bucket``, ``exchange_scatter`` (the
-    send buffers) and ``exchange_all_to_all``, so a device trace can
+    (``jax.named_scope``): ``exchange_bucket`` (the sort by destination and
+    the runs' bounds), ``exchange_scatter`` (the send buffers, sliced from
+    the sorted runs) and ``exchange_all_to_all``, so a device trace can
     attribute time to them.
     """
     ndev = jax.lax.axis_size(axis)
+    n = part.shape[0]
     with jax.named_scope("exchange_bucket"):
+        part = part.astype(jnp.int32)
         if row_valid is not None:
-            # invalid rows ride the out-of-range bucket: excluded from
-            # ranking, capacity, sending, and the dropped count
+            # invalid rows sort after every real destination: never sent,
+            # never in a run, never counted in ``dropped``
             part = jnp.where(row_valid, part, ndev)
-        slot, in_cap, _counts = bucket_by_partition(part, ndev, capacity)
-        sendable = in_cap if row_valid is None else in_cap & row_valid
-        if row_valid is None:
-            dropped = jnp.sum(~in_cap).astype(jnp.int32)
-        else:
-            dropped = jnp.sum(row_valid & ~in_cap).astype(jnp.int32)
+        # 1-D columns ride the sort as payload; columns with trailing dims
+        # are gathered by the sorted permutation, which rides in their place
+        flat = [k for k, v in columns.items() if v.ndim == 1]
+        wide = len(flat) < len(columns)
+        payload = [columns[k] for k in flat]
+        if wide:
+            payload.append(jnp.arange(n, dtype=jnp.int32))
+        sorted_part, *sorted_cols = jax.lax.sort(
+            (part, *payload), num_keys=1, is_stable=True)
+        starts = jnp.searchsorted(
+            sorted_part, jnp.arange(ndev + 1, dtype=jnp.int32)
+        ).astype(jnp.int32)
+        counts = jnp.diff(starts)
+        dropped = jnp.sum(jnp.maximum(counts - capacity, 0)).astype(jnp.int32)
 
     with jax.named_scope("exchange_scatter"):
-        dest = jnp.where(sendable, slot, ndev * capacity)
-        send_valid = (
-            jnp.zeros((ndev * capacity,), jnp.bool_)
-            .at[dest].set(True, mode="drop")
-            .reshape(ndev, capacity)
-        )
-        sends = {
-            name: jnp.zeros((ndev * capacity,) + data.shape[1:], data.dtype)
-            .at[dest].set(data, mode="drop")
-            .reshape((ndev, capacity) + data.shape[1:])
-            for name, data in columns.items()
-        }
+        in_run = (jnp.arange(capacity, dtype=jnp.int32)[None, :]
+                  < jnp.minimum(counts, capacity)[:, None])
+
+        def zero_past_runs(x):
+            m = in_run.reshape(in_run.shape + (1,) * (x.ndim - 2))
+            return jnp.where(m, x, jnp.zeros((), x.dtype))
+
+        def runs(stream):
+            # [ndev, capacity]: each destination's run of the sorted stream,
+            # padded so that no slice is clamped
+            stream = jnp.pad(stream, (0, capacity))
+            return zero_past_runs(jnp.stack([
+                jax.lax.dynamic_slice_in_dim(stream, starts[p], capacity)
+                for p in range(ndev)]))
+
+        by_name = dict(zip(flat, sorted_cols))
+        perm = runs(sorted_cols[-1]) if wide else None
+        sends = {k: runs(by_name[k]) if k in by_name
+                 else zero_past_runs(v[perm])
+                 for k, v in columns.items()}
 
     with jax.named_scope("exchange_all_to_all"):
         recv_cols = {
@@ -149,6 +154,6 @@ def all_to_all_shuffle(
             for name, send in sends.items()
         }
         recv_valid = jax.lax.all_to_all(
-            send_valid, axis, split_axis=0, concat_axis=0, tiled=False
+            in_run, axis, split_axis=0, concat_axis=0, tiled=False
         ).reshape(ndev * capacity)
     return ShuffleResult(recv_cols, recv_valid, dropped)

@@ -8,7 +8,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from spark_rapids_jni_tpu.parallel import (
     all_to_all_shuffle,
-    bucket_by_partition,
     make_mesh,
 )
 from spark_rapids_jni_tpu.models import (
@@ -16,25 +15,6 @@ from spark_rapids_jni_tpu.models import (
     make_distributed_query_step,
     make_example_batch,
 )
-
-
-def test_bucket_by_partition_ranks():
-    part = jnp.asarray(np.array([2, 0, 2, 1, 2, 0], dtype=np.int32))
-    slot, in_cap, counts = bucket_by_partition(part, 3, capacity=4)
-    assert list(np.asarray(counts)) == [2, 1, 3]
-    assert all(np.asarray(in_cap))
-    # slots must be unique and land in the right bucket
-    slots = list(np.asarray(slot))
-    assert len(set(slots)) == 6
-    for s, p in zip(slots, np.asarray(part)):
-        assert s // 4 == p
-
-
-@pytest.mark.slow
-def test_bucket_by_partition_overflow():
-    part = jnp.zeros(5, dtype=jnp.int32)
-    slot, in_cap, counts = bucket_by_partition(part, 2, capacity=3)
-    assert int(np.asarray(in_cap).sum()) == 3
 
 
 @pytest.mark.parametrize("ndev", [2, 4, 8])
