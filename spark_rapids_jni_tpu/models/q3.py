@@ -6,15 +6,19 @@
       and i_manufact_id = M and d_moy = 11
     group by d_year, i_brand_id, i_brand
     order by d_year, sum_agg desc, i_brand_id
+    limit 100
 
 Third query pattern in the models family (q97 = shuffle join-count, q5 =
 broadcast rollup): a selective dimension FILTER pushed through two dense
 dimension joins into one grouped money aggregation.  TPU shape: both
 dimensions are dense surrogate-keyed, so each join is a replicated-table
-gather; the group key (d_year, i_brand_id) lives in a small dense product
-space, so the aggregation is one masked segment-sum into a
-[n_years * n_brands] grid and the distributed form psums that grid over
-the data axis — no row exchange, same as q5's partials.
+gather.  The item side of the group key is dictionary-coded on the host:
+a dense code over the item table's distinct (i_brand_id, i_brand) pairs
+(the spec's i_brand_id is a sparse composite up to ~10^7, a few thousand
+distinct), gathered per row.  So the aggregation is one masked segment-sum
+into a [n_years * n_codes] grid, the distributed form psums that grid over
+the data axis — no row exchange, same as q5's partials — and the result
+decodes each code back to the item rows' own id and name.
 
 Money stays unscaled int64 cents (decimal scale 2) end to end; brand
 STRINGS materialize only in the host-formatted result rows.
@@ -33,7 +37,7 @@ plan IR).
 
 from __future__ import annotations
 
-from typing import List, NamedTuple
+from typing import List, NamedTuple, Optional
 
 import functools
 
@@ -48,8 +52,9 @@ from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 from spark_rapids_jni_tpu.plans import ir
 from spark_rapids_jni_tpu.plans.ir import Bin, Cast, band_all, col, lit
 
-__all__ = ["Q3Row", "q3_local", "q3_local_unfused", "q3_plan",
+__all__ = ["Q3Row", "Q3Grid", "q3_local", "q3_local_unfused", "q3_plan",
            "make_distributed_q3", "run_distributed_q3",
+           "run_distributed_q3_grid",
            "run_distributed_q3_columns", "q3_columns_host_oracle",
            "q3_working_set_bytes"]
 
@@ -61,16 +66,43 @@ class Q3Row(NamedTuple):
     sum_agg: int  # cents
 
 
+#: the query's LIMIT
+ROWS_LIMIT = 100
+
+
 class _Partials(NamedTuple):
-    sums: jnp.ndarray  # [n_years * n_brands] int64 cents
-    counts: jnp.ndarray  # [n_years * n_brands] int32
+    sums: jnp.ndarray  # [n_years * n_codes] int64 cents
+    counts: jnp.ndarray  # [n_years * n_codes] int32
+
+
+class _Codes(NamedTuple):
+    """The dictionary code of the group's item columns."""
+
+    item: np.ndarray  # [n_items] int32: each item row's code, 1-based
+    brand_id: np.ndarray  # [n_codes] i_brand_id of code c+1
+    brand: np.ndarray  # [n_codes] i_brand of code c+1
+
+
+def _brand_codes(data: Q3Data) -> _Codes:
+    """Dense codes over the item table's distinct (i_brand_id, i_brand)
+    pairs, in (id, name) order, each decoded from a representative item
+    row."""
+    ids = np.asarray(data.item_brand_id)
+    names = np.asarray(data.item_brand)
+    distinct, name_idx = np.unique(names, return_inverse=True)
+    key = ids.astype(np.int64) * len(distinct) + name_idx.reshape(-1)
+    _, first, inverse = np.unique(key, return_index=True, return_inverse=True)
+    return _Codes(inverse.astype(np.int32).reshape(-1) + 1, ids[first],
+                  names[first])
 
 
 def _partials(ss_item, ss_item_v, ss_date, ss_date_v, price,
               item_brand, item_manufact, date_year, date_moy,
               *, n_brands: int, year0: int, n_years: int,
               date_sk0: int, manufact_id: int, moy: int) -> _Partials:
-    """Device body over [rows] facts; dims are replicated dense tables."""
+    """Device body over [rows] facts; dims are replicated dense tables
+    (``item_brand`` holds each item's 1-based code, ``n_brands`` counts
+    the codes)."""
     i_idx = jnp.clip(ss_item - 1, 0, item_brand.shape[0] - 1)
     d_idx = jnp.clip(ss_date - date_sk0, 0, date_year.shape[0] - 1)
     ok = (
@@ -94,38 +126,56 @@ def _partials(ss_item, ss_item_v, ss_date, ss_date_v, price,
     return _Partials(sums, counts)
 
 
-def _assemble_rows(counts: np.ndarray, sum_of, year0: int, n_brands: int,
-                   render_brands) -> List[Q3Row]:
+def _assemble_rows(counts: np.ndarray, sum_of, year0: int, codes: _Codes,
+                   render_brands,
+                   limit: Optional[int] = ROWS_LIMIT) -> List[Q3Row]:
     """Shared result assembly: drop empty groups, decode the group grid
-    (year = year0 + g//n_brands, brand = g%n_brands + 1), attach brand
-    names via ``render_brands(zero_based_idx_array)``, order by
-    (d_year, sum desc, brand_id) — ONE owner of the grid layout and sort
-    rule for both the int64 and the decimal-columns variants."""
-    groups = np.nonzero(counts)[0]
-    names = render_brands((groups % n_brands).astype(np.int32))
-    rows = [
-        Q3Row(year0 + int(g) // n_brands, int(g) % n_brands + 1,
-              name, sum_of(int(g)))
-        for g, name in zip(groups, names)
-    ]
-    rows.sort(key=lambda r: (r.d_year, -r.sum_agg, r.brand_id))
-    return rows
+    (year = year0 + g // n_codes, code = g % n_codes + 1), order by
+    (d_year, sum desc, brand_id) with i_brand (the rest of the code
+    order) breaking ties, keep the first ``limit`` (all with None), and
+    attach their names via ``render_brands(zero_based_code_array)`` — ONE
+    owner of the grid layout, sort rule and limit for every q3 path."""
+    n_codes = len(codes.brand_id)
+    groups = [int(g) for g in np.nonzero(counts)[0]]
+    sums = {g: sum_of(g) for g in groups}
+    groups.sort(key=lambda g: (g // n_codes, -sums[g], g % n_codes))
+    groups = groups[:limit]
+    names = render_brands(np.asarray([g % n_codes for g in groups],
+                                     np.int32))
+    return [Q3Row(year0 + g // n_codes, int(codes.brand_id[g % n_codes]),
+                  str(name), sums[g])
+            for g, name in zip(groups, names)]
 
 
-def _format(parts: _Partials, data: Q3Data, year0: int) -> List[Q3Row]:
-    """Host: int64-partials formatting (host-list brand lookup)."""
-    sums = np.asarray(parts.sums)
-    return _assemble_rows(
-        np.asarray(parts.counts), lambda g: int(sums[g]), year0,
-        len(data.brand_names),
-        lambda idx: [data.brand_names[i] for i in idx])
+def _host_names(codes: _Codes):
+    return lambda idx: [codes.brand[i] for i in idx]
 
 
-def _geometry(data: Q3Data):
+class Q3Grid(NamedTuple):
+    """The group grid as the plan downloaded it: slot (d_year - year0) *
+    n_codes + code - 1 holds the group's sum, in cents and in the dtype
+    the plan summed in, and its row count."""
+
+    year0: int
+    codes: _Codes
+    sums: np.ndarray  # [n_years * n_codes]
+    counts: np.ndarray  # [n_years * n_codes]
+
+    def rows(self, limit: Optional[int] = ROWS_LIMIT) -> List[Q3Row]:
+        """The non-empty groups in the query's order: its result, or every
+        group with ``limit`` None (names looked up on the host)."""
+        sums = np.asarray(self.sums)
+        return _assemble_rows(np.asarray(self.counts),
+                              lambda g: int(sums[g]), self.year0, self.codes,
+                              _host_names(self.codes), limit)
+
+
+def _geometry(data: Q3Data, codes: Optional[_Codes] = None):
+    codes = _brand_codes(data) if codes is None else codes
     year0 = int(data.date_year.min())
     n_years = int(data.date_year.max()) - year0 + 1
     return dict(
-        n_brands=len(data.brand_names), year0=year0, n_years=n_years,
+        n_brands=len(codes.brand_id), year0=year0, n_years=n_years,
         date_sk0=int(data.date_sk[0]), manufact_id=data.manufact_id,
         moy=data.moy,
     )
@@ -147,7 +197,9 @@ def q3_plan(*, n_brands: int, year0: int, n_years: int, date_sk0: int,
             manufact_id: int, moy: int) -> ir.Plan:
     """The whole q3 device pipeline as ONE plan: scan -> item gather ->
     date gather -> manufact/moy filter -> grouped segment-sum into the
-    dense [n_years * n_brands] grid.  Geometry scalars normalize through
+    dense [n_years * n_brands] grid (``n_brands`` counts the item table's
+    brand codes; the item dim's ``brand`` field holds each item's 1-based
+    code).  Geometry scalars normalize through
     ``plans.ir.lit`` so equal geometry always builds an EQUAL plan (one
     cache entry on the process-global plan cache).  Memoized per
     geometry: the per-request hot path must not rebuild (and re-hash)
@@ -191,12 +243,13 @@ def _q3_tables(facts: dict, dims: dict) -> dict:
     }
 
 
-def _dims(data: Q3Data) -> dict:
+def _dims(data: Q3Data, codes: Optional[_Codes] = None) -> dict:
     # raw numpy: q3_local's jnp ops take them directly, and
     # run_distributed_q3 device_puts them with a replicated sharding
     # (no device->host->device round-trip)
+    codes = _brand_codes(data) if codes is None else codes
     return dict(
-        item_brand=data.item_brand_id,
+        item_brand=codes.item,
         item_manufact=data.item_manufact_id,
         date_year=data.date_year,
         date_moy=data.date_moy,
@@ -206,11 +259,12 @@ def _dims(data: Q3Data) -> dict:
 def q3_local_unfused(data: Q3Data) -> List[Q3Row]:
     """Per-op eager q3 (the pre-plan shape): one device dispatch per op.
     The plan path's bit-parity oracle."""
-    geo = _geometry(data)
+    codes = _brand_codes(data)
+    geo = _geometry(data, codes)
     parts = _partials(
         *(jnp.asarray(v) for v in _facts(data).values()),
-        **{k: jnp.asarray(v) for k, v in _dims(data).items()}, **geo)
-    return _format(parts, data, geo["year0"])
+        **{k: jnp.asarray(v) for k, v in _dims(data, codes).items()}, **geo)
+    return Q3Grid(geo["year0"], codes, parts.sums, parts.counts).rows()
 
 
 def q3_local(data: Q3Data) -> List[Q3Row]:
@@ -219,11 +273,13 @@ def q3_local(data: Q3Data) -> List[Q3Row]:
     bucket lattice), then host formatting."""
     from spark_rapids_jni_tpu.plans.runtime import execute_plan
 
-    geo = _geometry(data)
+    codes = _brand_codes(data)
+    geo = _geometry(data, codes)
     plan = q3_plan(**geo)
-    outputs = execute_plan(None, plan, _q3_tables(_facts(data), _dims(data)))
-    return _format(_Partials(outputs["sums"], outputs["counts"]),
-                   data, geo["year0"])
+    outputs = execute_plan(None, plan, _q3_tables(_facts(data),
+                                                  _dims(data, codes)))
+    return Q3Grid(geo["year0"], codes, outputs["sums"],
+                  outputs["counts"]).rows()
 
 
 def make_distributed_q3(mesh, data: Q3Data):
@@ -237,9 +293,10 @@ def make_distributed_q3(mesh, data: Q3Data):
     lengths and dtypes, never a padded dataset copy."""
     from spark_rapids_jni_tpu.plans.runtime import compiled_plan_for
 
-    plan = q3_plan(**_geometry(data))
+    codes = _brand_codes(data)
+    plan = q3_plan(**_geometry(data, codes))
     return compiled_plan_for(plan, mesh, _q3_tables(_facts(data),
-                                                    _dims(data)))
+                                                    _dims(data, codes)))
 
 
 def _pad_facts(facts: dict, dp: int) -> dict:
@@ -283,23 +340,32 @@ def _split_facts(facts: dict):
             {k: v[n // 2:] for k, v in facts.items()}]
 
 
-def run_distributed_q3(mesh, data: Q3Data, *, budget=None, task_id: int = 0,
-                       manage_task: bool = True) -> List[Q3Row]:
-    """Governed distributed q3 through the compiled plan: ONE admission
-    for the fused working set, RetryOOM re-runs the fused program,
-    SplitAndRetryOOM halves fact rows and re-executes the fused program
-    per half (exact: sums/counts are additive), one flight-recorder task
-    spans the plan."""
+def run_distributed_q3_grid(mesh, data: Q3Data, *, budget=None,
+                            task_id: int = 0,
+                            manage_task: bool = True) -> Q3Grid:
+    """Governed distributed q3 through the compiled plan, up to the
+    downloaded grid: ONE admission for the fused working set, RetryOOM
+    re-runs the fused program, SplitAndRetryOOM halves fact rows and
+    re-executes the fused program per half (exact: sums/counts are
+    additive), one flight-recorder task spans the plan."""
     from spark_rapids_jni_tpu.plans.runtime import run_governed_plan
 
-    geo = _geometry(data)
+    codes = _brand_codes(data)
+    geo = _geometry(data, codes)
     plan = q3_plan(**geo)
     outputs = run_governed_plan(
-        mesh, plan, _q3_tables(_facts(data), _dims(data)),
+        mesh, plan, _q3_tables(_facts(data), _dims(data, codes)),
         budget=budget, task_id=task_id, manage_task=manage_task,
     )
-    return _format(_Partials(outputs["sums"], outputs["counts"]),
-                   data, geo["year0"])
+    return Q3Grid(geo["year0"], codes, outputs["sums"], outputs["counts"])
+
+
+def run_distributed_q3(mesh, data: Q3Data, *, budget=None, task_id: int = 0,
+                       manage_task: bool = True) -> List[Q3Row]:
+    """Governed distributed q3: :func:`run_distributed_q3_grid`, then the
+    query's order and limit."""
+    return run_distributed_q3_grid(mesh, data, budget=budget, task_id=task_id,
+                                   manage_task=manage_task).rows()
 
 
 # ----------------------------------------------------------- columns variant
@@ -399,10 +465,13 @@ def _price_limbs(price: np.ndarray):
 
 def q3_columns_host_oracle(data: Q3Data) -> List[Q3Row]:
     """Arbitrary-precision host oracle (python ints — exact at magnitudes
-    where the int64 oracle in q3_local would overflow)."""
-    geo = _geometry(data)
+    where the int64 oracle in q3_local would overflow), on the program's
+    grid of brand codes."""
+    codes = _brand_codes(data)
+    geo = _geometry(data, codes)
+    n_codes = geo["n_brands"]
     sums: dict = {}
-    counts: dict = {}
+    counts = np.zeros(geo["n_years"] * n_codes, np.int64)
     for i in range(len(data.ss_item_sk)):
         if not (data.ss_item_sk_valid[i] and data.ss_sold_date_sk_valid[i]):
             continue
@@ -415,13 +484,12 @@ def q3_columns_host_oracle(data: Q3Data) -> List[Q3Row]:
             continue
         if int(data.date_moy[dsk]) != geo["moy"]:
             continue
-        key = (int(data.date_year[dsk]), int(data.item_brand_id[isk - 1]))
-        sums[key] = sums.get(key, 0) + int(data.ss_ext_sales_price[i])
-        counts[key] = counts.get(key, 0) + 1
-    rows = [Q3Row(y, b, data.brand_names[b - 1], s)
-            for (y, b), s in sums.items()]
-    rows.sort(key=lambda r: (r.d_year, -r.sum_agg, r.brand_id))
-    return rows
+        g = ((int(data.date_year[dsk]) - geo["year0"]) * n_codes
+             + int(codes.item[isk - 1]) - 1)
+        sums[g] = sums.get(g, 0) + int(data.ss_ext_sales_price[i])
+        counts[g] += 1
+    return _assemble_rows(counts, sums.__getitem__, geo["year0"], codes,
+                          _host_names(codes))
 
 
 def run_distributed_q3_columns(mesh, data: Q3Data, *, budget=None,
@@ -454,15 +522,18 @@ def run_distributed_q3_columns(mesh, data: Q3Data, *, budget=None,
 
     from jax.sharding import NamedSharding
 
-    geo = _geometry(data)
+    codes = _brand_codes(data)
+    geo = _geometry(data, codes)
     dp = mesh.shape[DATA_AXIS]
     step = _q3_columns_step_cached(mesh, tuple(sorted(geo.items())))
     sharding = NamedSharding(mesh, P(DATA_AXIS))
     rep = NamedSharding(mesh, P())
     # analyze: ignore[governed-allocation] - shared replicated dim tables,
     # as in run_distributed_q3 above
-    dims = {k: jax.device_put(v, rep) for k, v in _dims(data).items()}
-    brands = strings_column(data.brand_names)  # the STRING dimension
+    dims = {k: jax.device_put(v, rep)
+            for k, v in _dims(data, codes).items()}
+    # the STRING dimension: each code's i_brand
+    brands = strings_column([str(b) for b in codes.brand])
 
     hi0, lo0 = _price_limbs(data.ss_ext_sales_price)
     facts = dict(
@@ -527,5 +598,5 @@ def run_distributed_q3_columns(mesh, data: Q3Data, *, budget=None,
         return strings_from_padded(
             padded[sel], lens[sel]).to_list()[:n_sel]
 
-    return _assemble_rows(counts, lambda g: sums[g], geo["year0"],
-                          len(data.brand_names), render_brands)
+    return _assemble_rows(counts, lambda g: sums[g], geo["year0"], codes,
+                          render_brands)

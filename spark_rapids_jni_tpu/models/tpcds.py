@@ -15,7 +15,7 @@ query plans exercise.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -181,9 +181,11 @@ def generate_q5_data(sf: float = 0.01, seed: int = 0,
 class Q3Data:
     """q3 table set: store_sales fact + item and date_dim dimensions.
 
-    item: dense surrogate keys 1..n_items, a brand string per item (many
-    items share a brand), and a manufacturer id (the query's filter).
-    date_dim: dense keys with (d_year, d_moy) attributes.
+    item: dense surrogate keys 1..n_items, each row's ``i_brand_id`` and
+    ``i_brand`` (many items share a brand; the ids may be sparse, as the
+    spec's composite category/class/brand ids are), and a manufacturer id
+    (the query's filter).  date_dim: dense keys with (d_year, d_moy)
+    attributes.
     """
 
     ss_item_sk: np.ndarray
@@ -194,15 +196,18 @@ class Q3Data:
 
     item_sk: np.ndarray  # [n_items] dense 1..n
     item_brand_id: np.ndarray  # [n_items] int32
+    item_brand: np.ndarray  # [n_items] str: each row's i_brand
     item_manufact_id: np.ndarray  # [n_items] int32
-    brand_names: list  # [n_brands] strings; brand_id b -> brand_names[b-1]
 
-    date_sk: np.ndarray  # [n_dates] dense keys (from _D0)
+    date_sk: np.ndarray  # [n_dates] dense keys (from date_sk[0])
     date_year: np.ndarray
     date_moy: np.ndarray
 
     manufact_id: int  # the query's i_manufact_id literal
     moy: int  # the query's d_moy literal
+    #: the generator's dense brand table (brand_id b -> brand_names[b-1]);
+    #: the query paths read ``item_brand`` and never this
+    brand_names: Optional[list] = None
 
 
 def generate_q3_data(sf: float = 0.01, seed: int = 0,
@@ -235,9 +240,11 @@ def generate_q3_data(sf: float = 0.01, seed: int = 0,
         ss_sold_date_sk=d_sk, ss_sold_date_sk_valid=d_v,
         ss_ext_sales_price=_money(rng, n_sales),
         item_sk=item_sk, item_brand_id=item_brand_id,
-        item_manufact_id=item_manufact_id, brand_names=brand_names,
+        item_brand=np.asarray(brand_names)[item_brand_id - 1],
+        item_manufact_id=item_manufact_id,
         date_sk=date_sk, date_year=date_year, date_moy=date_moy,
         manufact_id=int(rng.randint(1, n_manufact + 1)), moy=11,
+        brand_names=brand_names,
     )
 
 

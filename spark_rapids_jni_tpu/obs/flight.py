@@ -66,7 +66,7 @@ __all__ = [
     "EV_RCACHE_EVICT", "EV_RCACHE_INVALIDATE",
     "EV_PLAN_REWRITE", "EV_ADAPT_EXCHANGE",
     "EV_HEDGE_LAUNCH", "EV_HEDGE_WIN", "EV_HEDGE_LOSE",
-    "EV_ATTRIB",
+    "EV_ATTRIB", "EV_SEGMENT_AGG",
     "EVENT_KINDS", "EVENT_PAIRS", "KIND_IDS", "DUMP_SCHEMA",
     "FlightRecorder", "record", "anomaly", "snapshot", "snapshot_since",
     "task_stats", "task_stat", "ring_stats",
@@ -238,6 +238,11 @@ EV_HEDGE_LOSE = "hedge_lose"            # the primary finished first (or
 # ``flags:<a+b>`` (``split``/``cache``/``hedge``) last; value=comp ns.
 # Tenant and handler names must not contain ':'.
 EV_ATTRIB = "attrib"
+# the plan runtime's aggregate counter (plans/runtime.execute_plan): one
+# event per plan run with SegmentAgg sinks, recorded in its plan_download
+# (detail=plan:<name>:scattered:<rows the scatters ran over>:kept:<rows
+# their masks kept>, value=kept)
+EV_SEGMENT_AGG = "segment_agg"
 
 # Paired kinds: a layer that emits the left side of a pair must also emit
 # the right side (module-granular balance, enforced by the analyze gate's
@@ -283,6 +288,8 @@ EVENT_KINDS = (
     EV_HEDGE_LAUNCH, EV_HEDGE_WIN, EV_HEDGE_LOSE,
     # round 21: appended for the same reason
     EV_ATTRIB,
+    # appended for the same reason
+    EV_SEGMENT_AGG,
 )
 KIND_IDS = {k: i for i, k in enumerate(EVENT_KINDS)}
 

@@ -53,6 +53,12 @@ DTYPES = {
 #: the implicit per-scan row-validity input the executor appends
 VALID_FIELD = "__valid__"
 
+#: the implicit outputs of a plan with SegmentAgg sinks: the rows their
+#: masks kept, and the rows their scatters ran over (int32, each summed
+#: over the sinks and the chips)
+AGG_KEPT = "__agg_kept__"
+AGG_ROWS = "__agg_rows__"
+
 
 # ---------------------------------------------------------------- expressions
 
@@ -113,13 +119,16 @@ def _eval(expr, env: Dict[str, object]):
 
 
 class _Ctx:
-    """One trace: bound input arrays + exchange-drop accumulation."""
+    """One trace: bound input arrays + exchange-drop and aggregate-row
+    accumulation."""
 
     def __init__(self, inputs, rowvalid, mesh):
         self.inputs = inputs      # table -> field -> traced array
         self.rowvalid = rowvalid  # scan table -> traced bool array
         self.mesh = mesh
         self.dropped: List[object] = []
+        self.agg_kept: List[object] = []  # rows each SegmentAgg kept
+        self.agg_rows = 0  # static rows the SegmentAgg scatters run over
 
 
 class _Rows:
@@ -158,7 +167,8 @@ def _emit_scan(node: ir.Scan, ctx: _Ctx) -> _Rows:
 @emitter(ir.Filter)
 def _emit_filter(node: ir.Filter, ctx: _Ctx) -> _Rows:
     rows = _emit(node.child, ctx)
-    return _Rows(rows.cols, rows.mask & _eval(node.pred, rows.cols))
+    with jax.named_scope("filter"):
+        return _Rows(rows.cols, rows.mask & _eval(node.pred, rows.cols))
 
 
 @emitter(ir.Project)
@@ -174,13 +184,14 @@ def _emit_project(node: ir.Project, ctx: _Ctx) -> _Rows:
 def _emit_gather_join(node: ir.GatherJoin, ctx: _Ctx) -> _Rows:
     rows = _emit(node.child, ctx)
     dim = ctx.inputs[node.dim.table]
-    key = _eval(node.key, rows.cols)
-    base = _eval(node.base, rows.cols)
-    n_dim = dim[node.fields[0][0]].shape[0]
-    idx = jnp.clip(key - base, 0, n_dim - 1)
-    cols = dict(rows.cols)
-    for dfield, out in node.fields:
-        cols[out] = dim[dfield][idx]
+    with jax.named_scope("gather_join"):
+        key = _eval(node.key, rows.cols)
+        base = _eval(node.base, rows.cols)
+        n_dim = dim[node.fields[0][0]].shape[0]
+        idx = jnp.clip(key - base, 0, n_dim - 1)
+        cols = dict(rows.cols)
+        for dfield, out in node.fields:
+            cols[out] = dim[dfield][idx]
     return _Rows(cols, rows.mask)
 
 
@@ -202,16 +213,20 @@ def _emit_semi_join_window(node: ir.SemiJoinWindow, ctx: _Ctx) -> _Rows:
 @emitter(ir.SegmentAgg)
 def _emit_segment_agg(node: ir.SegmentAgg, ctx: _Ctx) -> Dict[str, object]:
     rows = _emit(node.child, ctx)
-    key = _eval(node.key, rows.cols)
-    n = node.num_segments
-    # masked rows scatter into the drop bucket — the _masked_segment
-    # shape, bit-identical for integer sums
-    bucket = jnp.where(rows.mask, key, n)
-    out = {}
-    for name, value_expr, dtype in node.aggs:
-        vals = jnp.where(rows.mask, _eval(value_expr, rows.cols), 0).astype(
-            DTYPES[dtype])
-        out[name] = jax.ops.segment_sum(vals, bucket, num_segments=n + 1)[:-1]
+    with jax.named_scope("segment_agg"):
+        key = _eval(node.key, rows.cols)
+        n = node.num_segments
+        # masked rows scatter into the drop bucket — the _masked_segment
+        # shape, bit-identical for integer sums
+        bucket = jnp.where(rows.mask, key, n)
+        out = {}
+        for name, value_expr, dtype in node.aggs:
+            vals = jnp.where(rows.mask, _eval(value_expr, rows.cols),
+                             0).astype(DTYPES[dtype])
+            out[name] = jax.ops.segment_sum(vals, bucket,
+                                            num_segments=n + 1)[:-1]
+        ctx.agg_kept.append(jnp.sum(rows.mask, dtype=jnp.int32))
+        ctx.agg_rows += int(rows.mask.shape[0])
     return out
 
 
@@ -425,6 +440,8 @@ def compile_plan(plan: ir.Plan, mesh, signature: Tuple) -> CompiledPlan:
     if len(signature) != len(layout):
         raise ValueError("signature does not match the plan's arg layout")
     out_names = output_names(plan)
+    if any(isinstance(s, ir.SegmentAgg) for s in plan.sinks):
+        out_names += (AGG_KEPT, AGG_ROWS)
     local = mesh is None
     if local and ir.has_exchange(plan):
         raise ValueError(
@@ -455,6 +472,9 @@ def compile_plan(plan: ir.Plan, mesh, signature: Tuple) -> CompiledPlan:
             outputs.update(_emit(sink, ctx))
         if ctx.dropped:
             outputs["dropped"] = sum(ctx.dropped[1:], ctx.dropped[0])
+        if ctx.agg_kept:
+            outputs[AGG_KEPT] = sum(ctx.agg_kept[1:], ctx.agg_kept[0])
+            outputs[AGG_ROWS] = jnp.int32(ctx.agg_rows)
         if not local:
             from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 

@@ -33,6 +33,8 @@ from spark_rapids_jni_tpu.obs import trace as _trace
 from spark_rapids_jni_tpu.plans import ir
 from spark_rapids_jni_tpu.plans.cache import plan_cache
 from spark_rapids_jni_tpu.plans.compiler import (
+    AGG_KEPT,
+    AGG_ROWS,
     VALID_FIELD,
     cached_compile,
 )
@@ -248,7 +250,9 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
     """ONE fused launch: pad, compile (cached), upload, run, download —
     each but the compile lookup a child span of the thread's current
     trace context (``plan_pad``, ``plan_upload``, ``plan_run``,
-    ``plan_download``; no-ops without one).
+    ``plan_download``; no-ops without one).  A plan with SegmentAgg sinks
+    also records one ``segment_agg`` flight event: the rows its scatters
+    ran over and the rows their masks kept.
 
     Raises :class:`mem.governed.ShuffleCapacityExceeded` when an
     Exchange overflowed (``dropped > 0``) — the caller grows the
@@ -299,6 +303,13 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
     with _trace.maybe_span(_trace.SPAN_PLAN_DOWNLOAD):
         outputs = {name: np.asarray(v)
                    for name, v in zip(compiled.out_names, out)}
+        kept = outputs.pop(AGG_KEPT, None)
+        if kept is not None:
+            _flight.record(
+                _flight.EV_SEGMENT_AGG,
+                detail=f"plan:{plan.name}:scattered:"
+                       f"{int(outputs.pop(AGG_ROWS))}:kept:{int(kept)}",
+                value=int(kept))
         if int(outputs.get("dropped", 0)) > 0:
             raise ShuffleCapacityExceeded(
                 f"{int(outputs['dropped'])} rows overflowed the plan's "

@@ -165,6 +165,8 @@ def test_event_kind_vocabulary_is_stable():
         "hedge_launch", "hedge_win", "hedge_lose")
     # round 21: the per-tenant attribution kind is strictly appended after
     assert flight.EVENT_KINDS[47:48] == ("attrib",)
+    # the plan runtime's aggregate counter is strictly appended after
+    assert flight.EVENT_KINDS[48:49] == ("segment_agg",)
     assert len(set(flight.EVENT_KINDS)) == len(flight.EVENT_KINDS)
 
 
