@@ -240,8 +240,8 @@ EV_HEDGE_LOSE = "hedge_lose"            # the primary finished first (or
 EV_ATTRIB = "attrib"
 # the plan runtime's aggregate counter (plans/runtime.execute_plan): one
 # event per plan run with SegmentAgg sinks, recorded in its plan_download
-# (detail=plan:<name>:scattered:<rows the scatters ran over>:kept:<rows
-# their masks kept>, value=kept)
+# (detail=plan:<name>:path:<sorted|scatter|mixed>:scattered:<rows the
+# aggregation ran over>:kept:<rows their masks kept>, value=kept)
 EV_SEGMENT_AGG = "segment_agg"
 
 # Paired kinds: a layer that emits the left side of a pair must also emit

@@ -54,7 +54,7 @@ DTYPES = {
 VALID_FIELD = "__valid__"
 
 #: the implicit outputs of a plan with SegmentAgg sinks: the rows their
-#: masks kept, and the rows their scatters ran over (int32, each summed
+#: masks kept, and the rows the aggregation ran over (int32, each summed
 #: over the sinks and the chips)
 AGG_KEPT = "__agg_kept__"
 AGG_ROWS = "__agg_rows__"
@@ -128,7 +128,7 @@ class _Ctx:
         self.mesh = mesh
         self.dropped: List[object] = []
         self.agg_kept: List[object] = []  # rows each SegmentAgg kept
-        self.agg_rows = 0  # static rows the SegmentAgg scatters run over
+        self.agg_rows = 0  # static rows the aggregation ran over
 
 
 class _Rows:
@@ -210,19 +210,100 @@ def _emit_semi_join_window(node: ir.SemiJoinWindow, ctx: _Ctx) -> _Rows:
     return _Rows(rows.cols, rows.mask & valid & hit & in_win)
 
 
+def _rowwise(x, mask):
+    """``x`` (a row column or a scalar) as one value per row of ``mask``."""
+    return jnp.broadcast_to(jnp.asarray(x), mask.shape)
+
+
+def _sums_by_sort(dtype: str) -> bool:
+    """Integer aggs sum by sort and prefix differences, exact mod 2^k;
+    float (and bool) aggs keep the scatter, whose rounding they own."""
+    return bool(jnp.issubdtype(DTYPES[dtype], jnp.integer))
+
+
+def agg_path(plan: ir.Plan) -> str:
+    """How the plan's SegmentAgg sinks sum: ``sorted`` (every agg an
+    integer), ``scatter`` (none) or ``mixed``."""
+    paths = {"sorted" if _sums_by_sort(dtype) else "scatter"
+             for sink in plan.sinks if isinstance(sink, ir.SegmentAgg)
+             for _name, _expr, dtype in sink.aggs}
+    return paths.pop() if len(paths) == 1 else "mixed"
+
+
+def _lower_bounds(sorted_keys, queries, hi):
+    """``searchsorted(sorted_keys, queries)`` (side left) for queries whose
+    answers are at most ``hi``: a branch-free binary search over
+    ``[0, hi]``, one gather of every query a step and ``bit_length(hi)``
+    steps, so few where few rows are kept.  Its loop body is this
+    function's own ops: no jitted helper's trace, shared with other
+    programs, carries this caller's frames."""
+    size = sorted_keys.shape[0]
+    steps = 32 - jax.lax.clz(hi)
+    top = jax.lax.shift_left(jnp.int32(1), jnp.maximum(steps - 1, 0))
+
+    def step(i, pos):
+        # at least cand keys lie below q iff key[cand - 1] < q
+        cand = pos + jax.lax.shift_right_logical(top, i)
+        below = sorted_keys[jnp.minimum(cand, size) - 1] < queries
+        return jax.lax.select((cand <= hi) & below, cand, pos)
+
+    return jax.lax.fori_loop(0, steps, step,
+                             jnp.zeros(queries.shape, jnp.int32))
+
+
+def _sorted_segment_sums(bucket, n: int, aggs, cols) -> Dict[str, object]:
+    """Integer segment sums over ``bucket`` by ONE sort that carries every
+    value column as payload: each segment is a run of the sorted stream,
+    found by binary search, and its sum is the difference of the wrapping
+    prefix sums at the run's ends — bit-identical to ``segment_sum``,
+    since integer addition mod 2^k is a group.  A scalar value needs no
+    payload: its sum is the run length times the value, in its dtype."""
+    payload, scalars = {}, {}
+    for name, value_expr, dtype in aggs:
+        v = _eval(value_expr, cols)
+        if jnp.ndim(v) == 0:
+            scalars[name] = jnp.asarray(v).astype(DTYPES[dtype])
+        else:
+            # a masked row's value rides to bucket n, past every bound
+            payload[name] = jnp.asarray(v).astype(DTYPES[dtype])
+    sorted_bucket, *sorted_vals = jax.lax.sort(
+        (bucket, *payload.values()), num_keys=1, is_stable=False)
+    # every bound lies at or below the count of keys below n
+    bounds = _lower_bounds(
+        sorted_bucket, jnp.arange(n + 1, dtype=sorted_bucket.dtype),
+        jnp.sum(bucket < n, dtype=jnp.int32))
+    out = {}
+    for name, v in zip(payload, sorted_vals):
+        prefix = jnp.concatenate([jnp.zeros((1,), v.dtype),
+                                  jax.lax.cumsum(v)])
+        out[name] = prefix[bounds[1:]] - prefix[bounds[:-1]]
+    counts = bounds[1:] - bounds[:-1]
+    for name, c in scalars.items():
+        out[name] = counts.astype(c.dtype) * c
+    return out
+
+
 @emitter(ir.SegmentAgg)
 def _emit_segment_agg(node: ir.SegmentAgg, ctx: _Ctx) -> Dict[str, object]:
     rows = _emit(node.child, ctx)
     with jax.named_scope("segment_agg"):
-        key = _eval(node.key, rows.cols)
+        key = _rowwise(_eval(node.key, rows.cols), rows.mask)
         n = node.num_segments
-        # masked rows scatter into the drop bucket — the _masked_segment
-        # shape, bit-identical for integer sums
-        bucket = jnp.where(rows.mask, key, n)
+        # masked rows go to the drop bucket n, which no output reads.
+        # lax.select, not jnp.where: jnp.where's jitted trace is cached by
+        # shape across programs, with the frames of its first caller
+        bucket = jax.lax.select(rows.mask, key, jnp.full_like(key, n))
+        by_sort = [a for a in node.aggs if _sums_by_sort(a[2])]
+        sums = _sorted_segment_sums(bucket, n, by_sort, rows.cols) \
+            if by_sort else {}
         out = {}
         for name, value_expr, dtype in node.aggs:
-            vals = jnp.where(rows.mask, _eval(value_expr, rows.cols),
-                             0).astype(DTYPES[dtype])
+            if name in sums:
+                out[name] = sums[name]
+                continue
+            vals = _rowwise(jnp.asarray(_eval(value_expr, rows.cols))
+                            .astype(DTYPES[dtype]), rows.mask)
+            vals = jax.lax.select(rows.mask, vals, jnp.zeros_like(vals))
             out[name] = jax.ops.segment_sum(vals, bucket,
                                             num_segments=n + 1)[:-1]
         ctx.agg_kept.append(jnp.sum(rows.mask, dtype=jnp.int32))

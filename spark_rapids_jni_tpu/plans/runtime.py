@@ -36,6 +36,7 @@ from spark_rapids_jni_tpu.plans.compiler import (
     AGG_KEPT,
     AGG_ROWS,
     VALID_FIELD,
+    agg_path,
     cached_compile,
 )
 
@@ -251,8 +252,8 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
     each but the compile lookup a child span of the thread's current
     trace context (``plan_pad``, ``plan_upload``, ``plan_run``,
     ``plan_download``; no-ops without one).  A plan with SegmentAgg sinks
-    also records one ``segment_agg`` flight event: the rows its scatters
-    ran over and the rows their masks kept.
+    also records one ``segment_agg`` flight event: the path its sums took,
+    the rows the aggregation ran over and the rows their masks kept.
 
     Raises :class:`mem.governed.ShuffleCapacityExceeded` when an
     Exchange overflowed (``dropped > 0``) — the caller grows the
@@ -307,7 +308,7 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
         if kept is not None:
             _flight.record(
                 _flight.EV_SEGMENT_AGG,
-                detail=f"plan:{plan.name}:scattered:"
+                detail=f"plan:{plan.name}:path:{agg_path(plan)}:scattered:"
                        f"{int(outputs.pop(AGG_ROWS))}:kept:{int(kept)}",
                 value=int(kept))
         if int(outputs.get("dropped", 0)) > 0:

@@ -198,7 +198,7 @@ def test_segment_agg_counter_counts_the_kept_rows(chips):
         & (data.item_manufact_id[i] == 2) & (data.date_moy[d] == 11)))
     n = quantized_rows(len(data.ss_item_sk), chips)
     assert [e["detail"] for e in events] == [
-        f"plan:q3:scattered:{n}:kept:{kept}"]
+        f"plan:q3:path:sorted:scattered:{n}:kept:{kept}"]
     assert events[0]["value"] == kept > 0
 
 
@@ -226,3 +226,48 @@ def test_q3_plan_scopes_name_the_join_filter_and_aggregate_ops():
     names = re.findall(r'op_name="([^"]*)"', cp.fn.as_text())
     for scope in ("gather_join", "filter", "segment_agg"):
         assert any(scope in n.split("/") for n in names), scope
+
+
+def _scope_opcodes(hlo: str, scope: str) -> set:
+    """The HLO opcodes of the instructions whose ``op_name`` holds
+    ``scope`` as a path component."""
+    ops = set()
+    for line in hlo.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = re.search(r"=\s*(?:\([^)]*\)|\S+)\s+([a-z][a-z0-9-]*)\(", line)
+        if name and op and scope in name.group(1).split("/"):
+            ops.add(op.group(1))
+    return ops
+
+
+@pytest.mark.parametrize("sums", ["int64", "float32"])
+def test_q3_plan_sums_integers_by_sort_and_floats_by_scatter(sums):
+    """The q3 plan's integer aggs take one sort and no scatter; a float32
+    sum keeps its scatter beside the sorted count."""
+    import dataclasses
+
+    from spark_rapids_jni_tpu.models.q3 import (
+        _dims,
+        _facts,
+        _geometry,
+        _q3_tables,
+        q3_plan,
+    )
+    from spark_rapids_jni_tpu.plans.compiler import agg_path, compile_plan
+    from spark_rapids_jni_tpu.plans.runtime import input_signature_raw
+
+    data = spec_data(3)
+    plan = q3_plan(**_geometry(data))
+    (sink,) = plan.sinks
+    plan = dataclasses.replace(plan, sinks=(dataclasses.replace(
+        sink, aggs=tuple((n, e, sums if d == "int64" else d)
+                         for n, e, d in sink.aggs)),))
+    tables = _q3_tables(_facts(data), _dims(data))
+    cp = compile_plan(plan, _one_device_mesh(),
+                      input_signature_raw(plan, tables, 1))
+    ops = _scope_opcodes(cp.fn.as_text(), "segment_agg")
+    assert "sort" in ops
+    if sums == "int64":
+        assert agg_path(plan) == "sorted" and "scatter" not in ops
+    else:
+        assert agg_path(plan) == "mixed" and "scatter" in ops
