@@ -598,23 +598,17 @@ def mesh_query_step(mesh, rows_per_chip: int = 1 << 22):
 
 
 def mesh_q97(mesh, sf: float = 10.0):
-    """make_distributed_q97 at ``sf`` vs the numpy q97 oracle (the oracle
-    the one-chip smoke holds its SF 10 counts to)."""
-    import jax
-    import numpy as np
-
-    from spark_rapids_jni_tpu.models import make_distributed_q97
+    """run_distributed_q97 (the compiled q97 plan) at ``sf`` vs the numpy
+    q97 oracle (the oracle the one-chip smoke holds its SF 10 counts to)."""
+    from spark_rapids_jni_tpu.models import run_distributed_q97
     from spark_rapids_jni_tpu.models.q97 import default_q97_capacity
     from spark_rapids_jni_tpu.models.tpcds import generate_q97_tables
 
     dp = mesh.shape["data"]
     store, catalog = generate_q97_tables(sf, SEED)
     n = len(store[0])
-    assert n % dp == 0, (n, dp)
-    cap = default_q97_capacity(2 * n, dp)
-    q = make_distributed_q97(mesh, capacity=cap)(
-        *_spread([store[0], store[1], catalog[0], catalog[1]], mesh))
-    jax.block_until_ready(q)
+    cap = default_q97_capacity(n + len(catalog[0]), dp)
+    q = run_distributed_q97(mesh, store, catalog, capacity=cap)
     got = [int(q.store_only), int(q.catalog_only), int(q.both)]
     want = list(_q97_numpy_oracle(store, catalog))
     assert int(q.dropped) == 0 and got == want, (got, want, int(q.dropped))

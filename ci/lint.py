@@ -20,7 +20,7 @@ from typing import List
 from analyze import Finding, emit_github, emit_json
 
 MAX_LINE = 100
-ROOTS = ["spark_rapids_jni_tpu", "tests", "bench.py", "__graft_entry__.py",
+ROOTS = ["spark_rapids_jni_tpu", "tests", "__graft_entry__.py",
          "chip_smoke.py", "ci", "tools"]
 
 _URL_RE = re.compile(r"https?://\S+")

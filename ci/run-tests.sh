@@ -150,11 +150,6 @@ env JAX_PLATFORMS=cpu \
     --requests 96 --workers 2 --queue-size 64 \
     --seed "${TENANT_SEED:-13}"
 
-# perf-trajectory report (round 14, ADVISORY — bench numbers on shared
-# CI boxes are weather, so regressions print loudly but never gate):
-# diff the two newest BENCH_r*.json snapshots stage by stage
-python tools/bench_report.py || true
-
 # the sharded query path on a virtual 8-device CPU mesh (the chip run is
 # `python chip_smoke.py --chips 4` through the chip tool)
 env JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \

@@ -7,8 +7,8 @@ when the caller sets it (JAX then reads it itself and nothing here touches
 it), otherwise ``<repo>/.jax_cache``, which ``.gitignore`` lists.
 
 Entry points call :func:`enable` before their first compile: ``chip_smoke.py``,
-``bench.py`` and the ``nds_harness`` CLI.  The CPU test suite never does, so
-its cache stays off (``tests/conftest.py``).
+the benchmark (``python3 -m benchmark.run``) and the ``nds_harness`` CLI.  The
+CPU test suite never does, so its cache stays off (``tests/conftest.py``).
 """
 
 from __future__ import annotations

@@ -11,7 +11,7 @@ override support for tests.
 Usage::
 
     from spark_rapids_jni_tpu import config
-    rows = config.get("bench_rows")
+    rows = config.get("json_fuzz_rows")
     with config.override(json_fuzz_rows=10000):
         ...
     config.describe()   # -> human-readable flag table
@@ -128,10 +128,6 @@ def describe() -> str:
 # framework flags (every env knob the package reads, in one place)
 # --------------------------------------------------------------------------
 
-register("bench_rows", 1 << 24,
-         "Row count for bench.py workloads.", env="BENCH_ROWS")
-register("bench_iters", 20,
-         "Timed iterations per bench.py workload.", env="BENCH_ITERS")
 register("json_fuzz_rows", 300,
          "Row count for the get_json_object fuzz-vs-oracle test.",
          env="SRT_JSON_FUZZ_ROWS")

@@ -17,7 +17,7 @@ admission point is :meth:`BudgetedResource.acquire` *before* the jitted
 launch.  Everything else — blocking, BUFN escalation, watchdog, metrics —
 is byte-identical state-machine behavior (native/task_arbiter.cpp).
 
-Usage shape (what models/ and bench.py go through)::
+Usage shape (what models/ and the plan runtime go through)::
 
     gov = MemoryGovernor.instance()
     budget = default_device_budget(gov)

@@ -22,9 +22,7 @@ from spark_rapids_jni_tpu.models.q97 import (
     Q97Batch,
     Q97Out,
     combine_q97_outs,
-    make_distributed_q97,
     make_distributed_q97_columns,
-    q97_local,
     run_distributed_q97,
     run_q97_piece,
     split_q97_batch,
@@ -57,10 +55,8 @@ __all__ = [
     "run_distributed_q5",
     "local_query_step",
     "make_distributed_query_step",
-    "make_distributed_q97",
     "make_example_batch",
     "combine_q97_outs",
-    "q97_local",
     "run_distributed_q97",
     "run_q97_piece",
     "split_q97_batch",

@@ -11,16 +11,20 @@ i.e. a full outer join on a composite key reduced to presence counts:
       (SELECT customer_sk, item_sk FROM catalog_sales GROUP BY 1,2) cs
       USING (customer_sk, item_sk)
 
-Distributed plan (the Spark plan's TPU-native shape):
+The query runs as ONE compiled plan (:func:`q97_plan`, executed by
+plans/runtime): both fact scans project the packed int64 composite key,
+union with a source tag, exchange by key hash over the data axis (one
+tagged all_to_all co-locates every distinct key on its owner shard), and
+the ``PresenceCount`` sink sorts each shard's rows and counts equal-key
+runs by which sources appear (:func:`_count_runs`, a static-shape
+sort-merge "join": no dynamic hash table).  :func:`run_distributed_q97`
+drives it under the memory governor; :func:`q97_host_oracle` is the
+host-set reference.
 
-1. hash the composite key per row (Spark murmur3 row hashing, ops/hashing);
-2. all_to_all shuffle BOTH tables by ``hash % ndev`` over the data axis —
-   co-locating every distinct key on one owner shard (the exchange Spark
-   does with its UCX shuffle, here one ICI collective);
-3. per shard: sort the union of (key, source-tag) pairs and count
-   equal-key runs by which sources appear — a static-shape sort-merge
-   "join" (XLA-friendly: no dynamic hash table);
-4. psum the three counters over the mesh.
+The nullable variant (:func:`make_distributed_q97_columns`) takes Column
+keys with null validity, which the plan IR has no form for yet: it keys
+on a two-limb (k_hi, k_lo) pair through the columnar table shuffle and
+counts with the same :func:`_count_runs`.
 
 Shuffled row counts are data-dependent; capacity is a static bound with
 overflow reported (parallel/shuffle.py) — the caller retries with a larger
@@ -31,7 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +43,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
-from spark_rapids_jni_tpu.parallel.shuffle import all_to_all_shuffle, partition_of
+from spark_rapids_jni_tpu.parallel.shuffle import partition_of
 from spark_rapids_jni_tpu.plans import ir as ir_mod
 
 
@@ -50,35 +54,29 @@ class Q97Out(NamedTuple):
     dropped: jnp.ndarray  # shuffle capacity overflows (0 == exact result)
 
 
-def _composite_key(customer_sk: jnp.ndarray, item_sk: jnp.ndarray) -> jnp.ndarray:
-    """One int64 key per (customer, item) pair.
-
-    Both sks are positive 32-bit surrogate keys in TPC-DS, so packing is
-    exact (no collisions), unlike hashing the pair.
-    """
-    return (customer_sk.astype(jnp.int64) << 32) | (
-        item_sk.astype(jnp.int64) & 0xFFFFFFFF
-    )
-
-
-def _count_runs(keys: jnp.ndarray, is_store: jnp.ndarray, valid: jnp.ndarray):
+def _count_runs(keys: Union[jnp.ndarray, Tuple[jnp.ndarray, ...]],
+                is_store: jnp.ndarray, valid: jnp.ndarray):
     """Sort-merge presence counting over one shard's co-located rows.
 
-    For every distinct valid key: did it appear with a store tag, a catalog
-    tag, or both?  Returns (store_only, catalog_only, both) scalars.
+    ``keys`` is one int64 key array or a tuple of int64 key limbs (most
+    significant first); a key is the tuple of its limbs.  For every
+    distinct valid key: did it appear with a store tag, a catalog tag, or
+    both?  Returns (store_only, catalog_only, both) scalars.
     """
+    limbs = keys if isinstance(keys, tuple) else (keys,)
     # order by key; invalid rows sort last via the max sentinel
     sentinel = jnp.int64(0x7FFFFFFFFFFFFFFF)
-    k = jnp.where(valid, keys, sentinel)
-    order = jnp.argsort(k)
-    ks = k[order]
+    ks = [jnp.where(valid, k, sentinel) for k in limbs]
+    order = (jnp.argsort(ks[0]) if len(ks) == 1
+             else jnp.lexsort(tuple(reversed(ks))))
+    ks = [k[order] for k in ks]
     store_s = jnp.where(valid, is_store, False)[order]
     cat_s = jnp.where(valid, ~is_store, False)[order]
 
-    # run starts: first element or key change
-    n = ks.shape[0]
-    prev = jnp.concatenate([ks[:1] - 1, ks[:-1]])
-    run_start = ks != prev
+    # run starts: first element or a change in any limb
+    n = ks[0].shape[0]
+    run_start = functools.reduce(jnp.logical_or, [
+        k != jnp.concatenate([k[:1] - 1, k[:-1]]) for k in ks])
     run_id = jnp.cumsum(run_start.astype(jnp.int32)) - 1
 
     # per-run presence via segment max (bounded by n runs)
@@ -89,7 +87,7 @@ def _count_runs(keys: jnp.ndarray, is_store: jnp.ndarray, valid: jnp.ndarray):
         cat_s.astype(jnp.int32), run_id, num_segments=n
     )
     run_valid = jax.ops.segment_max(
-        (ks != sentinel).astype(jnp.int32), run_id, num_segments=n
+        (ks[0] != sentinel).astype(jnp.int32), run_id, num_segments=n
     )
     has_store = has_store * run_valid
     has_cat = has_cat * run_valid
@@ -106,57 +104,6 @@ def q97_host_oracle(store, catalog):
     s = set(zip(store[0].tolist(), store[1].tolist()))
     c = set(zip(catalog[0].tolist(), catalog[1].tolist()))
     return len(s - c), len(c - s), len(s & c)
-
-
-def q97_local(store: tuple, catalog: tuple) -> Q97Out:
-    """Single-chip q97 core over (customer_sk, item_sk) int arrays."""
-    sk = _composite_key(*store)
-    ck = _composite_key(*catalog)
-    keys = jnp.concatenate([sk, ck])
-    is_store = jnp.concatenate(
-        # analyze: ignore[governed-allocation] - the single-chip unfused
-        # oracle the parity tests pin the plan path against: tag/validity
-        # masks are O(input) bools beside already-resident key arrays, and
-        # callers (tests, dryrun) run it whole, never under the retry ladder
-        [jnp.ones(sk.shape, bool), jnp.zeros(ck.shape, bool)]
-    )
-    # analyze: ignore[governed-allocation] - same oracle-path mask
-    so, co, b = _count_runs(keys, is_store, jnp.ones(keys.shape, bool))
-    return Q97Out(so, co, b, jnp.int32(0))
-
-
-def _sharded_q97(s_cust, s_item, c_cust, c_item, capacity: int,
-                 s_valid=None, c_valid=None):
-    dp = jax.lax.axis_size(DATA_AXIS)
-    sk = _composite_key(s_cust, s_item)
-    ck = _composite_key(c_cust, c_item)
-
-    # co-locate keys from BOTH tables with ONE tagged all_to_all: same bytes
-    # moved, half the collective launches on the query hot path
-    keys = jnp.concatenate([sk, ck])
-    tag = jnp.concatenate(
-        [jnp.ones(sk.shape, jnp.int8), jnp.zeros(ck.shape, jnp.int8)]
-    )
-    row_valid = None
-    if s_valid is not None or c_valid is not None:
-        sv = jnp.ones(sk.shape, bool) if s_valid is None else s_valid
-        cv = jnp.ones(ck.shape, bool) if c_valid is None else c_valid
-        row_valid = jnp.concatenate([sv, cv])
-    part = partition_of(keys, dp)
-    ex = all_to_all_shuffle(
-        {"k": keys, "tag": tag}, part, capacity, axis=DATA_AXIS,
-        row_valid=row_valid,
-    )
-    so, co, b = _count_runs(
-        ex.columns["k"], ex.columns["tag"] == 1, ex.valid
-    )
-    axes = (DATA_AXIS,)
-    return Q97Out(
-        jax.lax.psum(so, axes),
-        jax.lax.psum(co, axes),
-        jax.lax.psum(b, axes),
-        jax.lax.psum(ex.dropped, axes),
-    )
 
 
 @functools.lru_cache(maxsize=64)
@@ -182,43 +129,11 @@ def q97_plan(capacity: int) -> ir_mod.Plan:
                                                     tag="tag"),))
 
 
-def make_distributed_q97(mesh, capacity: int, with_validity: bool = False):
-    """jit-compiled distributed q97 over ``mesh``'s data axis.
-
-    Inputs: four [rows] int arrays sharded over DATA_AXIS (store customer/
-    item, catalog customer/item); with ``with_validity``, two more bool
-    arrays (store row-valid, catalog row-valid) marking padding rows that
-    must not count.  ``capacity`` bounds per-destination shuffle buckets
-    over the COMBINED row stream (both tables ride one tagged all_to_all);
-    Q97Out.dropped > 0 means retry with a larger one.
-    """
-    if with_validity:
-        def body(s_cust, s_item, c_cust, c_item, s_valid, c_valid):
-            return _sharded_q97(s_cust, s_item, c_cust, c_item, capacity,
-                                s_valid=s_valid, c_valid=c_valid)
-
-        in_specs = tuple(P(DATA_AXIS) for _ in range(6))
-    else:
-        body = functools.partial(_sharded_q97, capacity=capacity)
-        in_specs = tuple(P(DATA_AXIS) for _ in range(4))
-    step = jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=Q97Out(P(), P(), P(), P()),
-        check_vma=False,
-    )
-    return jax.jit(step)
-
-
 # ------------------------------------------------------- nullable columns --
 # q97 over real Column inputs with per-column null validity.  SQL semantics:
 # NULL keys group *within* a side (DISTINCT treats NULLs as one group) but
 # never join *across* sides (NULL = NULL is unknown), so a side's null-key
 # groups count as that side's "only" rows.
-
-_PAIR_SENTINEL = jnp.int64(0x7FFFFFFFFFFFFFFF)
-
 
 def _pair_key(cust, cust_valid, item, item_valid, side: int):
     """(k_hi, k_lo) 2-limb group key over nullable (cust, item) int32 pairs.
@@ -237,35 +152,6 @@ def _pair_key(cust, cust_valid, item, item_valid, side: int):
     marker = jnp.int64((2 | (side & 1)) << 33)
     k_lo = i_ext | jnp.where(null_any, marker, jnp.int64(0))
     return c_ext, k_lo
-
-
-def _count_runs_pair(k_hi, k_lo, is_store, valid):
-    """_count_runs generalized to a 2-limb key (lexsorted)."""
-    kh = jnp.where(valid, k_hi, _PAIR_SENTINEL)
-    kl = jnp.where(valid, k_lo, _PAIR_SENTINEL)
-    order = jnp.lexsort((kl, kh))
-    khs = kh[order]
-    kls = kl[order]
-    store_s = jnp.where(valid, is_store, False)[order]
-    cat_s = jnp.where(valid, ~is_store, False)[order]
-
-    n = khs.shape[0]
-    prev_hi = jnp.concatenate([khs[:1] - 1, khs[:-1]])
-    prev_lo = jnp.concatenate([kls[:1] - 1, kls[:-1]])
-    run_start = (khs != prev_hi) | (kls != prev_lo)
-    run_id = jnp.cumsum(run_start.astype(jnp.int32)) - 1
-
-    has_store = jax.ops.segment_max(store_s.astype(jnp.int32), run_id, num_segments=n)
-    has_cat = jax.ops.segment_max(cat_s.astype(jnp.int32), run_id, num_segments=n)
-    run_valid = jax.ops.segment_max(
-        (khs != _PAIR_SENTINEL).astype(jnp.int32), run_id, num_segments=n
-    )
-    has_store = has_store * run_valid
-    has_cat = has_cat * run_valid
-    both = jnp.sum((has_store & has_cat).astype(jnp.int32))
-    store_only = jnp.sum((has_store & (1 - has_cat)).astype(jnp.int32))
-    cat_only = jnp.sum((has_cat & (1 - has_store)).astype(jnp.int32))
-    return store_only, cat_only, both
 
 
 def _sharded_q97_columns(s_cust, s_item, c_cust, c_item, s_rv, c_rv,
@@ -303,8 +189,8 @@ def _sharded_q97_columns(s_cust, s_item, c_cust, c_item, s_rv, c_rv,
         },
         part, capacity, axis=DATA_AXIS, row_valid=row_valid,
     )
-    so, co, b = _count_runs_pair(
-        ex.columns["kh"].data, ex.columns["kl"].data,
+    so, co, b = _count_runs(
+        (ex.columns["kh"].data, ex.columns["kl"].data),
         ex.columns["tag"].data == 1, ex.valid,
     )
     axes = (DATA_AXIS,)
@@ -435,7 +321,7 @@ def default_q97_capacity(total_rows: int, dp: int) -> int:
     return next_pow2(raw)
 
 
-def run_q97_piece(mesh, piece: Q97Batch, *, sharding=None) -> Q97Out:
+def run_q97_piece(mesh, piece: Q97Batch) -> Q97Out:
     """One FUSED launch of one q97 (sub-)batch through the compiled plan.
 
     The single-attempt core shared by :func:`run_distributed_q97` (which
@@ -446,12 +332,10 @@ def run_q97_piece(mesh, piece: Q97Batch, *, sharding=None) -> Q97Out:
     compiled variants are cached on (plan structure, dtype signature,
     pow2 batch bucket).  Raises :class:`ShuffleCapacityExceeded` when
     rows overflowed the piece's static exchange capacity (the caller
-    grows and re-runs).  ``sharding`` is accepted for API compatibility;
-    the plan runtime derives placements from the plan itself.
+    grows and re-runs).
     """
     from spark_rapids_jni_tpu.plans.runtime import execute_plan
 
-    del sharding
     out = execute_plan(mesh, q97_plan(piece.capacity), {
         "store": {"cust": piece.s_cust, "item": piece.s_item},
         "catalog": {"cust": piece.c_cust, "item": piece.c_item},

@@ -46,7 +46,6 @@ from spark_rapids_jni_tpu import config
 from spark_rapids_jni_tpu.columnar.buckets import length_buckets, map_buckets
 from spark_rapids_jni_tpu.columnar.column import Column, StringColumn
 from spark_rapids_jni_tpu.columnar.dtypes import DType, FLOAT64, Kind
-from spark_rapids_jni_tpu.obs.phases import PhaseTimes
 from spark_rapids_jni_tpu.ops.cast_string import CastException
 from spark_rapids_jni_tpu.utils.softfloat import (
     f64_bits_to_f32_bits,
@@ -57,8 +56,6 @@ from spark_rapids_jni_tpu.utils.softfloat import (
 
 MAX_SAFE_DIGITS = 19
 MAX_HOLDING = ((1 << 64) - 1 - 9) // 10  # 1844674407370955160
-
-PHASES = PhaseTimes("bucket", "parse", "assemble")
 
 # binary64 values of 10^k for k in [-360, 359].  Non-negative k: float(10**k)
 # is correctly rounded (exact integer -> nearest double), overflowing to inf
@@ -555,53 +552,49 @@ def _scan_np(col: StringColumn):
     _scan_rect_np over a zero-filled rectangle clamped to the bucket's true
     max length (host rectangles have no jit shape cache to feed, so nothing
     forces the width itself up to a power of two)."""
-    with PHASES.phase("bucket"):
-        chars = np.asarray(col.chars)
-        offsets = np.asarray(col.offsets)
-        lens_all = (offsets[1:] - offsets[:-1]).astype(np.int32)
-        n = lens_all.shape[0]
-        buckets = length_buckets(lens_all, min_width=4)
-        # bucketing only pays when it prunes padded work (long outliers);
-        # a flat length profile runs as ONE rectangle, skipping the
-        # per-field scatter-backs entirely
-        w_max = int(lens_all.max(initial=0))
-        bucketed_work = sum(w * nv for w, _, nv in buckets)
-        mono = bool(n and n * w_max <= bucketed_work)
-        if mono:
-            buckets = [(w_max, np.arange(n, dtype=np.int64), n)]
+    chars = np.asarray(col.chars)
+    offsets = np.asarray(col.offsets)
+    lens_all = (offsets[1:] - offsets[:-1]).astype(np.int32)
+    n = lens_all.shape[0]
+    buckets = length_buckets(lens_all, min_width=4)
+    # bucketing only pays when it prunes padded work (long outliers);
+    # a flat length profile runs as ONE rectangle, skipping the
+    # per-field scatter-backs entirely
+    w_max = int(lens_all.max(initial=0))
+    bucketed_work = sum(w * nv for w, _, nv in buckets)
+    mono = bool(n and n * w_max <= bucketed_work)
+    if mono:
+        buckets = [(w_max, np.arange(n, dtype=np.int64), n)]
     outs = {k: np.zeros(n, dt) for k, dt in _SCAN_FIELDS_NP.items()}
     for _, rows_np, n_valid in buckets:
-        with PHASES.phase("bucket"):
-            rows_np = rows_np[:n_valid]
-            lens = lens_all[rows_np]
-            width = max(int(lens.max(initial=0)), 1)
-            in_row = np.arange(width, dtype=np.int32)[None, :] < lens[:, None]
-            if mono:
-                # all rows in offset order: the chars buffer between
-                # offsets[0] and offsets[-1] IS the row-major concatenation
-                # of every row's bytes, so one boolean scatter fills the
-                # rectangle — no (n, W) int32 index matrix, no gather, no
-                # zeroing multiply
-                padded = np.zeros((n_valid, width), np.uint8)
-                padded[in_row] = chars[int(offsets[0]):int(offsets[-1])]
-            else:
-                starts = offsets[rows_np].astype(np.int32)
-                idx = starts[:, None] + np.arange(
-                    width, dtype=np.int32)[None, :]
-                pad_chars = np.concatenate(
-                    [chars, np.zeros((width,), np.uint8)]
-                )
-                padded = pad_chars[idx]
-                padded *= in_row
-        with PHASES.phase("parse"):
-            fields = _scan_rect_np(padded, lens)
-        with PHASES.phase("bucket"):
-            if n_valid == n:
-                for k, dt in _SCAN_FIELDS_NP.items():
-                    outs[k] = fields[k].astype(dt)
-            else:
-                for k, dt in _SCAN_FIELDS_NP.items():
-                    outs[k][rows_np] = fields[k].astype(dt)
+        rows_np = rows_np[:n_valid]
+        lens = lens_all[rows_np]
+        width = max(int(lens.max(initial=0)), 1)
+        in_row = np.arange(width, dtype=np.int32)[None, :] < lens[:, None]
+        if mono:
+            # all rows in offset order: the chars buffer between
+            # offsets[0] and offsets[-1] IS the row-major concatenation
+            # of every row's bytes, so one boolean scatter fills the
+            # rectangle — no (n, W) int32 index matrix, no gather, no
+            # zeroing multiply
+            padded = np.zeros((n_valid, width), np.uint8)
+            padded[in_row] = chars[int(offsets[0]):int(offsets[-1])]
+        else:
+            starts = offsets[rows_np].astype(np.int32)
+            idx = starts[:, None] + np.arange(
+                width, dtype=np.int32)[None, :]
+            pad_chars = np.concatenate(
+                [chars, np.zeros((width,), np.uint8)]
+            )
+            padded = pad_chars[idx]
+            padded *= in_row
+        fields = _scan_rect_np(padded, lens)
+        if n_valid == n:
+            for k, dt in _SCAN_FIELDS_NP.items():
+                outs[k] = fields[k].astype(dt)
+        else:
+            for k, dt in _SCAN_FIELDS_NP.items():
+                outs[k][rows_np] = fields[k].astype(dt)
     return outs
 
 
@@ -829,9 +822,8 @@ def _string_to_float_host(col: StringColumn, ansi_mode: bool, dtype: DType):
     """XLA:CPU arm: bucketed numpy scan + the hardware-binary64 assembly
     twin, no device round-trips (round 20)."""
     f = _scan_np(col)
-    with PHASES.phase("assemble"):
-        out_np = np.float32 if dtype.kind == Kind.FLOAT32 else np.float64
-        out, valid, except_ = _assemble(f, out_np)
+    out_np = np.float32 if dtype.kind == Kind.FLOAT32 else np.float64
+    out, valid, except_ = _assemble(f, out_np)
 
     in_valid = np.asarray(col.is_valid())
     except_ = except_ & in_valid
@@ -864,10 +856,8 @@ def string_to_float(
         raise TypeError("string_to_float produces FLOAT32 or FLOAT64")
     if not _device_parse_enabled():
         return _string_to_float_host(col, ansi_mode, dtype)
-    with PHASES.phase("parse"):
-        f = _scan(col)
-    with PHASES.phase("assemble"):
-        bits, valid, except_ = _assemble_device(f)
+    f = _scan(col)
+    bits, valid, except_ = _assemble_device(f)
 
     in_valid = col.is_valid()
     except_ = except_ & in_valid

@@ -58,7 +58,6 @@ from spark_rapids_jni_tpu.columnar.column import (
     strings_from_padded,
 )
 from spark_rapids_jni_tpu.columnar.dtypes import Kind
-from spark_rapids_jni_tpu.obs.phases import PhaseTimes
 from spark_rapids_jni_tpu.utils.floatbits import f32_to_bits
 from spark_rapids_jni_tpu.utils import ryu_tables as rt
 
@@ -73,12 +72,6 @@ _POW10_U64 = jnp.asarray(np.array([10**k for k in range(20)], dtype=np.uint64))
 
 _POW10_NP = np.array([10**k for k in range(20)], dtype=np.uint64)
 _POW5_NP = np.array([5**k for k in range(24)], dtype=np.uint64)
-
-# pipeline phase timers (obs/phases.py): bucket = classification + class
-# split, ryu = digit computation (shortest-search or strip), emit =
-# character emission + column assembly.  bench.py snapshots these into
-# the stage's phases_s.
-PHASES = PhaseTimes("bucket", "ryu", "emit")
 
 # value classes (class_buckets ids): specials render from a 5-row table,
 # simple integers take the strip loop, the residue pays full Ryu.
@@ -1219,15 +1212,13 @@ def _render_device(bits, negative, special_id, cls, is_float):
     the compiled-shape universe bounded, exactly like length buckets)."""
 
     def kernel(cid, b_bits, b_neg, b_sid):
-        with PHASES.phase("ryu"):
-            if cid == CLS_SIMPLE:
-                output, e10 = _simple_digits_jit(b_bits, is_float)
-            elif cid == CLS_RYU:
-                output, e10 = (_f2d_jit if is_float else _d2d_jit)(b_bits)
-            else:  # specials never reach the digit path; emit masks them
-                output, e10 = b_bits, b_sid * 0
-        with PHASES.phase("emit"):
-            return _emit_fast_jit(output, e10, b_neg, b_sid, is_float)
+        if cid == CLS_SIMPLE:
+            output, e10 = _simple_digits_jit(b_bits, is_float)
+        elif cid == CLS_RYU:
+            output, e10 = (_f2d_jit if is_float else _d2d_jit)(b_bits)
+        else:  # specials never reach the digit path; emit masks them
+            output, e10 = b_bits, b_sid * 0
+        return _emit_fast_jit(output, e10, b_neg, b_sid, is_float)
 
     padded, lens = map_classes(
         cls, 3, kernel,
@@ -1253,15 +1244,13 @@ def _render_host(bits, negative, special_id, cls, is_float):
             b_bits = bits[rows_np]
             b_neg = negative[rows_np]
             b_sid = special_id[rows_np]
-        with PHASES.phase("ryu"):
-            if cid == CLS_SIMPLE:
-                output, e10 = _simple_digits_np(b_bits, is_float)
-            elif cid == CLS_RYU:
-                output, e10 = (_f2d_np if is_float else _d2d_np)(b_bits)
-            else:
-                output, e10 = b_bits, b_sid * 0
-        with PHASES.phase("emit"):
-            p, l = _emit_np(output, e10, b_neg, b_sid, is_float)
+        if cid == CLS_SIMPLE:
+            output, e10 = _simple_digits_np(b_bits, is_float)
+        elif cid == CLS_RYU:
+            output, e10 = (_f2d_np if is_float else _d2d_np)(b_bits)
+        else:
+            output, e10 = b_bits, b_sid * 0
+        p, l = _emit_np(output, e10, b_neg, b_sid, is_float)
         if whole:
             return p, l
         padded[rows_np] = p
@@ -1335,60 +1324,54 @@ def _float_to_string_device(col: Column) -> StringColumn:
 
     if not config.get("float_bucketed"):
         # monolithic oracle: every row pays full Ryu + per-position emission
-        with PHASES.phase("ryu"):
-            output, e10 = (_f2d if is_float else _d2d)(bits)
-        with PHASES.phase("emit"):
-            padded, lens = _emit(output, e10, negative, special_id, is_float)
+        output, e10 = (_f2d if is_float else _d2d)(bits)
+        padded, lens = _emit(output, e10, negative, special_id, is_float)
         return strings_from_padded(padded, lens, col.validity)
 
-    with PHASES.phase("bucket"):
-        cls = _classify_np(
-            np.asarray(bits), np.asarray(special_id), is_float
-        )
+    cls = _classify_np(
+        np.asarray(bits), np.asarray(special_id), is_float
+    )
     padded, lens = _render_device(bits, negative, special_id, cls, is_float)
-    with PHASES.phase("emit"):
-        return strings_from_padded(padded, lens, col.validity)
+    return strings_from_padded(padded, lens, col.validity)
 
 
 def _float_to_string_host(col: Column) -> StringColumn:
     """Host-twin arm (XLA:CPU): classify + render entirely in numpy."""
     is_float = col.dtype.kind == Kind.FLOAT32
-    with PHASES.phase("bucket"):
-        if is_float:
-            bits32 = np.asarray(col.data).view(np.int32)
-            bits = bits32.astype(np.uint64) & np.uint64(0xFFFFFFFF)
-            negative = bits32 < 0
-            mant = bits & np.uint64((1 << 23) - 1)
-            expo = (bits >> np.uint64(23)) & np.uint64(0xFF)
-            is_nan = (expo == 0xFF) & (mant != 0)
-            is_inf = (expo == 0xFF) & (mant == 0)
-            is_zero = (expo == 0) & (mant == 0)
-        else:
-            data = np.asarray(col.data)  # int64 IEEE bit patterns
-            bits = data.view(np.uint64)
-            negative = data < 0
-            mant = bits & np.uint64((1 << 52) - 1)
-            expo = (bits >> np.uint64(52)) & np.uint64(0x7FF)
-            is_nan = (expo == 0x7FF) & (mant != 0)
-            is_inf = (expo == 0x7FF) & (mant == 0)
-            is_zero = (expo == 0) & (mant == 0)
-        special_id = np.where(
-            is_nan,
-            np.int32(4),
+    if is_float:
+        bits32 = np.asarray(col.data).view(np.int32)
+        bits = bits32.astype(np.uint64) & np.uint64(0xFFFFFFFF)
+        negative = bits32 < 0
+        mant = bits & np.uint64((1 << 23) - 1)
+        expo = (bits >> np.uint64(23)) & np.uint64(0xFF)
+        is_nan = (expo == 0xFF) & (mant != 0)
+        is_inf = (expo == 0xFF) & (mant == 0)
+        is_zero = (expo == 0) & (mant == 0)
+    else:
+        data = np.asarray(col.data)  # int64 IEEE bit patterns
+        bits = data.view(np.uint64)
+        negative = data < 0
+        mant = bits & np.uint64((1 << 52) - 1)
+        expo = (bits >> np.uint64(52)) & np.uint64(0x7FF)
+        is_nan = (expo == 0x7FF) & (mant != 0)
+        is_inf = (expo == 0x7FF) & (mant == 0)
+        is_zero = (expo == 0) & (mant == 0)
+    special_id = np.where(
+        is_nan,
+        np.int32(4),
+        np.where(
+            is_inf,
+            np.where(negative, np.int32(3), np.int32(2)),
             np.where(
-                is_inf,
-                np.where(negative, np.int32(3), np.int32(2)),
-                np.where(
-                    is_zero,
-                    np.where(negative, np.int32(1), np.int32(0)),
-                    np.int32(-1),
-                ),
+                is_zero,
+                np.where(negative, np.int32(1), np.int32(0)),
+                np.int32(-1),
             ),
-        )
-        cls = _classify_np(bits, special_id, is_float)
+        ),
+    )
+    cls = _classify_np(bits, special_id, is_float)
     padded, lens = _render_host(bits, negative, special_id, cls, is_float)
-    with PHASES.phase("emit"):
-        return _strings_from_padded_np(padded, lens, col.validity)
+    return _strings_from_padded_np(padded, lens, col.validity)
 
 
 def float_to_string(col: Column) -> StringColumn:

@@ -48,10 +48,8 @@ depth before returning (the reference's loop structure does the same).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import threading
-import time
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -74,8 +72,6 @@ __all__ = [
     "get_json_object",
     "get_json_object_multiple_paths",
     "parse_path",
-    "phase_times",
-    "reset_phase_times",
     "truncation_count",
     "WILDCARD",
     "INDEX",
@@ -135,50 +131,19 @@ _HEX_UP = np.frombuffer(b"0123456789ABCDEF", np.uint8)
 
 
 # ---------------------------------------------------------------------------
-# observability: phase wall-clock attribution + step-cap truncation counter
+# observability: step-cap truncation counter
 # ---------------------------------------------------------------------------
 
-_PHASE_TIMES: Dict[str, float] = {"tokenize": 0.0, "evaluate": 0.0,
-                                  "render": 0.0}
 _COUNTERS: Dict[str, int] = {"step_cap_truncated": 0}
 # the serve worker pool runs the get_json_object handler from several
-# threads at once; the read-modify-write accumulator updates must not race
+# threads at once; the read-modify-write counter updates must not race
 _OBS_LOCK = threading.Lock()
-
-
-def reset_phase_times() -> None:
-    """Zero the per-phase wall-clock accumulators (bench sub-timings)."""
-    with _OBS_LOCK:
-        for k in _PHASE_TIMES:
-            _PHASE_TIMES[k] = 0.0
-
-
-def phase_times() -> Dict[str, float]:
-    """Seconds spent per pipeline phase since the last reset.
-
-    Host pipeline: exact wall clock per phase.  Device pipeline: phases
-    are issued asynchronously, so time lands on the phase whose sync
-    point materialized the work (still attributable, just coarser).
-    """
-    with _OBS_LOCK:
-        return dict(_PHASE_TIMES)
 
 
 def truncation_count() -> int:
     """Process-lifetime count of rows nulled by the machine step cap."""
     with _OBS_LOCK:
         return _COUNTERS["step_cap_truncated"]
-
-
-@contextlib.contextmanager
-def _phase(key: str):
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        with _OBS_LOCK:
-            _PHASE_TIMES[key] += dt
 
 
 def _note_truncation(k: int) -> None:
@@ -1265,119 +1230,111 @@ def _get_json_object_device(col: StringColumn, parts: Sequence[tuple]
     valid_out = [jnp.zeros((n,), bool) for _ in range(P)]
     for group in groups:
         # ---- phase 1 (no sync): tokenize + scans + float-geometry scalars
-        # tokenize and evaluate are sibling phases (never nested), so the
-        # bench's phases_s sub-timings partition the stage total on both
-        # pipelines; issue time is exact, async device work lands on the
-        # phase whose sync point pulls it (the evaluate-phase geom pull)
         ph1 = []
         for b in group:
-            with _phase("tokenize"):
-                ts = jt.tokenize(b.bytes, b.lengths)
-                nr = b.n_rows
-                kind = ts.kind.astype(jnp.int32)
-                start, end = ts.start, ts.end
-                ntok = ts.n_tokens.astype(jnp.int32)
-                T = kind.shape[1]
+            ts = jt.tokenize(b.bytes, b.lengths)
+            nr = b.n_rows
+            kind = ts.kind.astype(jnp.int32)
+            start, end = ts.start, ts.end
+            ntok = ts.n_tokens.astype(jnp.int32)
+            T = kind.shape[1]
 
-                # reuse the tokenizer's automaton product (state AFTER
-                # each byte -> state BEFORE each byte)
-                st_before = jnp.pad(
-                    ts.str_state, ((0, 0), (1, 0)))[:, : b.bytes.shape[1]]
-                bi = jrd.byte_info_device(b.bytes, b.lengths, st_before)
-            with _phase("evaluate"):
-                len_raw, len_esc, has_uni, neg0 = jrd.token_tables_device(
-                    bi, kind, start, end)
-                nm_uniq = jrd.name_matches_device(
-                    bi, kind, start, len_raw, has_uni, end, uniq_names)
-                zeros_nt = jnp.zeros((nr, T), bool)
+            # reuse the tokenizer's automaton product (state AFTER
+            # each byte -> state BEFORE each byte)
+            st_before = jnp.pad(
+                ts.str_state, ((0, 0), (1, 0)))[:, : b.bytes.shape[1]]
+            bi = jrd.byte_info_device(b.bytes, b.lengths, st_before)
+            len_raw, len_esc, has_uni, neg0 = jrd.token_tables_device(
+                bi, kind, start, end)
+            nm_uniq = jrd.name_matches_device(
+                bi, kind, start, len_raw, has_uni, end, uniq_names)
+            zeros_nt = jnp.zeros((nr, T), bool)
 
-                F = min(jt.MAX_DEPTH + MAX_PATH_DEPTH + 6, T + 3)
-                G = min(MAX_PATH_DEPTH + 2, F)
-                per_path = []
-                trunc_dev = jnp.int32(0)
-                for (ptype_j, parg_j, P1), (_pt, _pa, names) in zip(
-                        path_consts, parts):
-                    nm = [zeros_nt if nm_ is None else nm_uniq[name_slot[nm_]]
-                          for nm_ in names]
-                    nm_stack = jnp.concatenate(
-                        [jnp.stack(nm) if nm else jnp.zeros((0, nr, T), bool),
-                         jnp.zeros((P1 - len(nm), nr, T), bool)])
-                    err_s, done, dirty_root, (segs, cg, cd, cn) = _run_scan(
-                        kind, ts.match, ntok, ts.ok, nm_stack, ptype_j,
-                        parg_j, T, F, G)
-                    trunc_dev = trunc_dev + jnp.sum(
-                        ~done & ~err_s, dtype=jnp.int32)
-                    err = err_s | ~done | (dirty_root <= 0)
-                    err = err | ~in_valid[b.rows]
-                    err = err | ~b.valid_mask()  # pow2-padding tail rows
-                    per_path.append(dict(err=err, segs=(segs, cg, cd, cn)))
+            F = min(jt.MAX_DEPTH + MAX_PATH_DEPTH + 6, T + 3)
+            G = min(MAX_PATH_DEPTH + 2, F)
+            per_path = []
+            trunc_dev = jnp.int32(0)
+            for (ptype_j, parg_j, P1), (_pt, _pa, names) in zip(
+                    path_consts, parts):
+                nm = [zeros_nt if nm_ is None else nm_uniq[name_slot[nm_]]
+                      for nm_ in names]
+                nm_stack = jnp.concatenate(
+                    [jnp.stack(nm) if nm else jnp.zeros((0, nr, T), bool),
+                     jnp.zeros((P1 - len(nm), nr, T), bool)])
+                err_s, done, dirty_root, (segs, cg, cd, cn) = _run_scan(
+                    kind, ts.match, ntok, ts.ok, nm_stack, ptype_j,
+                    parg_j, T, F, G)
+                trunc_dev = trunc_dev + jnp.sum(
+                    ~done & ~err_s, dtype=jnp.int32)
+                err = err_s | ~done | (dirty_root <= 0)
+                err = err | ~in_valid[b.rows]
+                err = err | ~b.valid_mask()  # pow2-padding tail rows
+                per_path.append(dict(err=err, segs=(segs, cg, cd, cn)))
 
-                fmask = kind == jt.VALUE_NUMBER_FLOAT
-                if fmask.size:
-                    nf_dev = jnp.sum(fmask, dtype=jnp.int32)
-                    ws_dev = jnp.max(
-                        jnp.where(fmask, end - start, 0)).astype(jnp.int32)
-                else:
-                    nf_dev = ws_dev = jnp.int32(0)
-                ph1.append(dict(
-                    b=b, bi=bi, kind=kind, start=start, end=end,
-                    paths=per_path, len_raw=len_raw, len_esc=len_esc,
-                    neg0=neg0, nf=nf_dev, ws=ws_dev, trunc=trunc_dev))
+            fmask = kind == jt.VALUE_NUMBER_FLOAT
+            if fmask.size:
+                nf_dev = jnp.sum(fmask, dtype=jnp.int32)
+                ws_dev = jnp.max(
+                    jnp.where(fmask, end - start, 0)).astype(jnp.int32)
+            else:
+                nf_dev = ws_dev = jnp.int32(0)
+            ph1.append(dict(
+                b=b, bi=bi, kind=kind, start=start, end=end,
+                paths=per_path, len_raw=len_raw, len_esc=len_esc,
+                neg0=neg0, nf=nf_dev, ws=ws_dev, trunc=trunc_dev))
 
-        with _phase("evaluate"):
-            # one batched sync: every bucket's (nf, ws, trunc) in one pull
-            geom = np.asarray(jnp.stack(
-                [jnp.stack([p["nf"], p["ws"], p["trunc"]]) for p in ph1]))
-            _note_truncation(int(geom[:, 2].sum()))
+        # one batched sync: every bucket's (nf, ws, trunc) in one pull
+        geom = np.asarray(jnp.stack(
+            [jnp.stack([p["nf"], p["ws"], p["trunc"]]) for p in ph1]))
+        _note_truncation(int(geom[:, 2].sum()))
 
         # ---- phase 2 (no sync): float slots + measure + out-width scalar
-        with _phase("render"):
-            for p, (nf_total, ws, _tr) in zip(ph1, geom):
-                b, kind = p["b"], p["kind"]
-                nr = b.n_rows
-                if nf_total:
-                    NF = next_pow2(int(nf_total))
-                    WS = next_pow2(max(int(ws), 1))
-                    ftext, flen, fidx = jrd.float_texts_device(
-                        b.bytes, kind, p["start"], p["end"], NF, WS)
-                else:
-                    ftext = jnp.zeros((0, 1), jnp.uint8)
-                    flen = jnp.zeros((0,), jnp.int64)
-                    fidx = jnp.full((nr, kind.shape[1]), -1, jnp.int64)
-                p["floats"] = (ftext, flen, fidx)
+        for p, (nf_total, ws, _tr) in zip(ph1, geom):
+            b, kind = p["b"], p["kind"]
+            nr = b.n_rows
+            if nf_total:
+                NF = next_pow2(int(nf_total))
+                WS = next_pow2(max(int(ws), 1))
+                ftext, flen, fidx = jrd.float_texts_device(
+                    b.bytes, kind, p["start"], p["end"], NF, WS)
+            else:
+                ftext = jnp.zeros((0, 1), jnp.uint8)
+                flen = jnp.zeros((0,), jnp.int64)
+                fidx = jnp.full((nr, kind.shape[1]), -1, jnp.int64)
+            p["floats"] = (ftext, flen, fidx)
 
-                for pp in p["paths"]:
-                    segs, cg, cd, cn = pp["segs"]
-                    stype, sarg, segcum, out_len = jrd.resolve_and_measure(
-                        segs, cg, cd, cn, pp["err"], kind, p["len_raw"],
-                        p["len_esc"], fidx, flen)
-                    pp.update(stype=stype, sarg=sarg, segcum=segcum,
-                              out_len=out_len,
-                              wmax=jnp.max(out_len).astype(jnp.int32))
+            for pp in p["paths"]:
+                segs, cg, cd, cn = pp["segs"]
+                stype, sarg, segcum, out_len = jrd.resolve_and_measure(
+                    segs, cg, cd, cn, pp["err"], kind, p["len_raw"],
+                    p["len_esc"], fidx, flen)
+                pp.update(stype=stype, sarg=sarg, segcum=segcum,
+                          out_len=out_len,
+                          wmax=jnp.max(out_len).astype(jnp.int32))
 
-            # second batched sync: all (bucket, path) output widths at once
-            wmaxes = np.asarray(jnp.stack(
-                [pp["wmax"] for p in ph1 for pp in p["paths"]]))
+        # second batched sync: all (bucket, path) output widths at once
+        wmaxes = np.asarray(jnp.stack(
+            [pp["wmax"] for p in ph1 for pp in p["paths"]]))
 
-            # ---- phase 3: render (width now static per bucket and path)
-            wi = 0
-            for p in ph1:
-                b = p["b"]
-                nv = b.n_valid
-                tgt = jnp.where(b.valid_mask(), b.rows, jnp.int32(n))
-                for pi, pp in enumerate(p["paths"]):
-                    W = next_pow2(max(int(wmaxes[wi]), 1))
-                    wi += 1
-                    padded = jrd.render_device(
-                        p["bi"], pp["stype"], pp["sarg"], pp["segcum"],
-                        pp["out_len"], pp["err"], p["kind"], p["start"],
-                        p["end"], (p["len_raw"], p["len_esc"], p["neg0"]),
-                        p["floats"], W)
-                    valid_out[pi] = valid_out[pi].at[tgt].set(
-                        ~pp["err"], mode="drop")
-                    results[pi].append(
-                        (b.rows[:nv], padded[:nv],
-                         pp["out_len"][:nv].astype(jnp.int32), nv))
+        # ---- phase 3: render (width now static per bucket and path)
+        wi = 0
+        for p in ph1:
+            b = p["b"]
+            nv = b.n_valid
+            tgt = jnp.where(b.valid_mask(), b.rows, jnp.int32(n))
+            for pi, pp in enumerate(p["paths"]):
+                W = next_pow2(max(int(wmaxes[wi]), 1))
+                wi += 1
+                padded = jrd.render_device(
+                    p["bi"], pp["stype"], pp["sarg"], pp["segcum"],
+                    pp["out_len"], pp["err"], p["kind"], p["start"],
+                    p["end"], (p["len_raw"], p["len_esc"], p["neg0"]),
+                    p["floats"], W)
+                valid_out[pi] = valid_out[pi].at[tgt].set(
+                    ~pp["err"], mode="drop")
+                results[pi].append(
+                    (b.rows[:nv], padded[:nv],
+                     pp["out_len"][:nv].astype(jnp.int32), nv))
 
     return [strings_from_buckets(n, results[pi], valid_out[pi])
             for pi in range(P)]
@@ -1404,29 +1361,27 @@ def _get_json_object_host(col: StringColumn, parts: Sequence[tuple]
     valid_out = [np.zeros((n,), bool) for _ in range(P)]
     n_trunc = 0
     for b in padded_buckets(col):
-        with _phase("tokenize"):
-            ts = jt.tokenize(b.bytes, b.lengths)
-            # one device->host transfer per token array; host paths slice
-            nv = b.n_valid
-            kind = np.asarray(ts.kind).astype(np.int32)[:nv]
-            start = np.asarray(ts.start)[:nv]
-            end = np.asarray(ts.end)[:nv]
-            match = np.asarray(ts.match)[:nv]
-            ntok = np.asarray(ts.n_tokens).astype(np.int64)[:nv]
-            ok = np.asarray(ts.ok)[:nv]
-            rows_np = np.asarray(b.rows)[:nv]
-            bi = _byte_info(b.bytes, b.lengths, n_valid=nv,
-                            str_state=ts.str_state)
+        ts = jt.tokenize(b.bytes, b.lengths)
+        # one device->host transfer per token array; host paths slice
+        nv = b.n_valid
+        kind = np.asarray(ts.kind).astype(np.int32)[:nv]
+        start = np.asarray(ts.start)[:nv]
+        end = np.asarray(ts.end)[:nv]
+        match = np.asarray(ts.match)[:nv]
+        ntok = np.asarray(ts.n_tokens).astype(np.int64)[:nv]
+        ok = np.asarray(ts.ok)[:nv]
+        rows_np = np.asarray(b.rows)[:nv]
+        bi = _byte_info(b.bytes, b.lengths, n_valid=nv,
+                        str_state=ts.str_state)
 
-        with _phase("evaluate"):
-            len_raw, len_esc, has_uni, neg0 = _token_tables(
-                bi, kind, start, end)
-            nm_cache: dict = {}
-            nm_paths = [
-                _name_matches(bi, kind, start, end, names, len_raw, has_uni,
-                              cache=nm_cache)
-                for _pt, _pa, names in parts
-            ]
+        len_raw, len_esc, has_uni, neg0 = _token_tables(
+            bi, kind, start, end)
+        nm_cache: dict = {}
+        nm_paths = [
+            _name_matches(bi, kind, start, end, names, len_raw, has_uni,
+                          cache=nm_cache)
+            for _pt, _pa, names in parts
+        ]
         T = kind.shape[1]
         has_float = bool((kind == jt.VALUE_NUMBER_FLOAT).any())
         used_float = (np.zeros((nv, T), bool) if has_float else None)
@@ -1450,43 +1405,41 @@ def _get_json_object_host(col: StringColumn, parts: Sequence[tuple]
                 n0_s = neg0[sel][:, :Tcap]
             for pi, ((ptypes, pargs, _names), nm) in enumerate(
                     zip(parts, nm_paths)):
-                with _phase("evaluate"):
-                    nm_s = nm if whole else [t[sel][:, :Tcap] for t in nm]
-                    m = _Machine(kind_s, match_s, ntok_s, ok_s, ptypes,
-                                 pargs, nm_s, compact=compact,
-                                 step_margin=margin)
-                    n_trunc += m.run()
-                    stype, sarg = m.segment_tables()
-                    err = (m.err_out | (m.dirty_out <= 0)
-                           | ~in_valid[rows_s])
-                    if has_float:
-                        # note float tokens this path actually emits, so
-                        # the Ryu re-render below runs on just those
-                        ref = (stype == _SEG_RAW_TOK) | \
-                            (stype == _SEG_ESC_TOK)
-                        ri2, si2 = np.nonzero(ref)
-                        ta2 = np.clip(sarg[ri2, si2], 0, Tcap - 1)
-                        fref = kind_s[ri2, ta2] == jt.VALUE_NUMBER_FLOAT
-                        used_float[sel[ri2[fref]], ta2[fref]] = True
+                nm_s = nm if whole else [t[sel][:, :Tcap] for t in nm]
+                m = _Machine(kind_s, match_s, ntok_s, ok_s, ptypes,
+                             pargs, nm_s, compact=compact,
+                             step_margin=margin)
+                n_trunc += m.run()
+                stype, sarg = m.segment_tables()
+                err = (m.err_out | (m.dirty_out <= 0)
+                       | ~in_valid[rows_s])
+                if has_float:
+                    # note float tokens this path actually emits, so
+                    # the Ryu re-render below runs on just those
+                    ref = (stype == _SEG_RAW_TOK) | \
+                        (stype == _SEG_ESC_TOK)
+                    ri2, si2 = np.nonzero(ref)
+                    ta2 = np.clip(sarg[ri2, si2], 0, Tcap - 1)
+                    fref = kind_s[ri2, ta2] == jt.VALUE_NUMBER_FLOAT
+                    used_float[sel[ri2[fref]], ta2[fref]] = True
                 pending.append((pi, sel, Tcap, whole, stype, sarg, err,
                                 bi_s, kind_s, start_s, end_s, lr_s, le_s,
                                 n0_s, rows_s))
 
-        with _phase("render"):
-            ftext, flen, fidx = _float_texts(bi, kind, start, end,
-                                             used=used_float)
-            for (pi, sel, Tcap, whole, stype, sarg, err, bi_s, kind_s,
-                 start_s, end_s, lr_s, le_s, n0_s, rows_s) in pending:
-                fidx_s = fidx if whole else fidx[sel][:, :Tcap]
-                padded, out_len = _render(
-                    bi_s, stype, sarg, err, kind_s, start_s, end_s,
-                    lr_s, le_s, n0_s, ftext, flen, fidx_s)
-                valid_out[pi][rows_s] = ~err
-                out_len = np.where(~err, out_len, 0)
-                results[pi].append(
-                    (jnp.asarray(rows_s), jnp.asarray(padded),
-                     jnp.asarray(out_len.astype(np.int32)),
-                     len(rows_s)))
+        ftext, flen, fidx = _float_texts(bi, kind, start, end,
+                                         used=used_float)
+        for (pi, sel, Tcap, whole, stype, sarg, err, bi_s, kind_s,
+             start_s, end_s, lr_s, le_s, n0_s, rows_s) in pending:
+            fidx_s = fidx if whole else fidx[sel][:, :Tcap]
+            padded, out_len = _render(
+                bi_s, stype, sarg, err, kind_s, start_s, end_s,
+                lr_s, le_s, n0_s, ftext, flen, fidx_s)
+            valid_out[pi][rows_s] = ~err
+            out_len = np.where(~err, out_len, 0)
+            results[pi].append(
+                (jnp.asarray(rows_s), jnp.asarray(padded),
+                 jnp.asarray(out_len.astype(np.int32)),
+                 len(rows_s)))
 
     _note_truncation(n_trunc)
     return [strings_from_buckets(n, results[pi], jnp.asarray(valid_out[pi]))

@@ -18,8 +18,8 @@ u32 limbs *outside* with plain shifts, which the rewrite does support).
 Off-TPU the kernels run in Pallas interpret mode, so correctness is
 CI-testable on the CPU mesh; selection is via the ``hash_backend`` config
 flag ("xla" default, "pallas" to route murmur3 fixed-width contributions
-here).  On hardware the two backends are A/B benchable
-(bench.py ``murmur3_int32`` / ``murmur3_int32_pallas`` stages).
+here).  No benchmark cell measures the two backends yet; an A/B on
+hardware belongs in ``benchmark/``.
 """
 
 from __future__ import annotations

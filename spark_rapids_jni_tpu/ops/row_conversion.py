@@ -61,19 +61,11 @@ from spark_rapids_jni_tpu.columnar.column import (
     strings_from_padded,
 )
 from spark_rapids_jni_tpu.columnar.dtypes import DType, Kind, UINT8
-from spark_rapids_jni_tpu.obs.phases import PhaseTimes
 from spark_rapids_jni_tpu.plans.cache import CompiledPlan, plan_cache
 from spark_rapids_jni_tpu.utils.floatbits import bits_to_f32, f32_to_bits
 
 JCUDF_ROW_ALIGNMENT = 8
 MAX_BATCH_SIZE = (1 << 31) - 1
-
-# Sub-timings across both directions: plan (permutation lookup/build),
-# lanes (byte-lane construction / decode), gather (the fused permutation),
-# emit (batch split + ragged string pass).  Host arm: wall-clock host work;
-# device arm: dispatch time only (XLA is async).
-PHASES = PhaseTimes("plan", "lanes", "gather", "emit")
-
 
 def _round_up(x: int, align: int) -> int:
     return (x + align - 1) // align * align
@@ -365,87 +357,82 @@ def _convert_to_rows_host(
 ) -> List[ListColumn]:
     n = columns[0].size
     dtypes = [c.dtype for c in columns]
-    with PHASES.phase("plan"):
-        plan = _get_row_plan(dtypes, n)
+    plan = _get_row_plan(dtypes, n)
     size_per_row = plan["size_per_row"]
     fixed_row = plan["fixed_row"]
     string_cols = [c for c in columns if c.dtype.kind == Kind.STRING]
 
-    with PHASES.phase("lanes"):
-        lanes_list: List[np.ndarray] = []
-        str_lens: List[np.ndarray] = []
-        str_starts: List[np.ndarray] = []
-        within = (
-            np.full((n,), size_per_row, dtype=np.int64) if string_cols else None
-        )
-        for col in columns:
-            if col.dtype.kind == Kind.STRING:
-                lens = np.asarray(col.lengths()).astype(np.int64)
-                str_lens.append(lens)
-                str_starts.append(within)
-                pair = np.empty((n, 2), np.uint32)
-                pair[:, 0] = within
-                pair[:, 1] = lens
-                lanes_list.append(pair.view(np.uint8))
-                within = within + lens
-            else:
-                lanes_list.append(_np_col_lanes(col))
-        vbytes = np.zeros((n, (len(columns) + 7) // 8), np.uint8)
-        for c, col in enumerate(columns):
-            valid = np.asarray(col.is_valid()).astype(np.uint8)
-            vbytes[:, c // 8] |= valid << np.uint8(c % 8)
-        lanes = np.concatenate(lanes_list + [vbytes], axis=1)
-
-    with PHASES.phase("gather"):
-        fixed = _gather_fixed_np(lanes, plan["perm"], plan["keep"])
-
-    with PHASES.phase("emit"):
-        if string_cols:
-            row_sizes = size_per_row + sum(str_lens)
-            a = JCUDF_ROW_ALIGNMENT
-            row_sizes = (row_sizes + (a - 1)) // a * a
+    lanes_list: List[np.ndarray] = []
+    str_lens: List[np.ndarray] = []
+    str_starts: List[np.ndarray] = []
+    within = (
+        np.full((n,), size_per_row, dtype=np.int64) if string_cols else None
+    )
+    for col in columns:
+        if col.dtype.kind == Kind.STRING:
+            lens = np.asarray(col.lengths()).astype(np.int64)
+            str_lens.append(lens)
+            str_starts.append(within)
+            pair = np.empty((n, 2), np.uint32)
+            pair[:, 0] = within
+            pair[:, 1] = lens
+            lanes_list.append(pair.view(np.uint8))
+            within = within + lens
         else:
-            row_sizes = np.full((n,), fixed_row, dtype=np.int64)
-        bounds = _batch_boundaries(row_sizes, max_batch_bytes)
-        cum_sizes = np.concatenate([[0], np.cumsum(row_sizes)])
-        chars_np = [np.asarray(c.chars) for c in string_cols]
-        soffs_np = [np.asarray(c.offsets) for c in string_cols]
-        out: List[ListColumn] = []
-        for b0, b1 in zip(bounds[:-1], bounds[1:]):
-            offsets_np = (cum_sizes[b0 : b1 + 1] - cum_sizes[b0]).astype(np.int32)
-            total = int(offsets_np[-1])
-            if not string_cols:
-                # uniform fixed_row rows: the permuted matrix IS the batch
-                flat = np.ascontiguousarray(fixed[b0:b1]).reshape(-1)
-            else:
-                row_off = offsets_np[:-1].astype(np.int64)
-                flat = np.zeros((total,), np.uint8)
-                pos = row_off[:, None] + np.arange(size_per_row, dtype=np.int64)
-                flat[pos] = fixed[b0:b1, :size_per_row]
-                for lens, sstart, chars, soffs in zip(
-                    str_lens, str_starts, chars_np, soffs_np
-                ):
-                    lsub = lens[b0:b1]
-                    tot = int(lsub.sum())
-                    if not tot:
-                        continue
-                    idx = _ragged_char_indices(row_off + sstart[b0:b1], lsub)
-                    c0 = int(soffs[b0])
-                    flat[idx] = chars[c0 : c0 + tot]
-            out.append(
-                ListColumn(
-                    jnp.asarray(offsets_np),
-                    Column(jnp.asarray(flat), None, UINT8),
-                    None,
-                )
+            lanes_list.append(_np_col_lanes(col))
+    vbytes = np.zeros((n, (len(columns) + 7) // 8), np.uint8)
+    for c, col in enumerate(columns):
+        valid = np.asarray(col.is_valid()).astype(np.uint8)
+        vbytes[:, c // 8] |= valid << np.uint8(c % 8)
+    lanes = np.concatenate(lanes_list + [vbytes], axis=1)
+
+    fixed = _gather_fixed_np(lanes, plan["perm"], plan["keep"])
+
+    if string_cols:
+        row_sizes = size_per_row + sum(str_lens)
+        a = JCUDF_ROW_ALIGNMENT
+        row_sizes = (row_sizes + (a - 1)) // a * a
+    else:
+        row_sizes = np.full((n,), fixed_row, dtype=np.int64)
+    bounds = _batch_boundaries(row_sizes, max_batch_bytes)
+    cum_sizes = np.concatenate([[0], np.cumsum(row_sizes)])
+    chars_np = [np.asarray(c.chars) for c in string_cols]
+    soffs_np = [np.asarray(c.offsets) for c in string_cols]
+    out: List[ListColumn] = []
+    for b0, b1 in zip(bounds[:-1], bounds[1:]):
+        offsets_np = (cum_sizes[b0 : b1 + 1] - cum_sizes[b0]).astype(np.int32)
+        total = int(offsets_np[-1])
+        if not string_cols:
+            # uniform fixed_row rows: the permuted matrix IS the batch
+            flat = np.ascontiguousarray(fixed[b0:b1]).reshape(-1)
+        else:
+            row_off = offsets_np[:-1].astype(np.int64)
+            flat = np.zeros((total,), np.uint8)
+            pos = row_off[:, None] + np.arange(size_per_row, dtype=np.int64)
+            flat[pos] = fixed[b0:b1, :size_per_row]
+            for lens, sstart, chars, soffs in zip(
+                str_lens, str_starts, chars_np, soffs_np
+            ):
+                lsub = lens[b0:b1]
+                tot = int(lsub.sum())
+                if not tot:
+                    continue
+                idx = _ragged_char_indices(row_off + sstart[b0:b1], lsub)
+                c0 = int(soffs[b0])
+                flat[idx] = chars[c0 : c0 + tot]
+        out.append(
+            ListColumn(
+                jnp.asarray(offsets_np),
+                Column(jnp.asarray(flat), None, UINT8),
+                None,
             )
-        return out
+        )
+    return out
 
 
 def _convert_from_rows_host(rows: ListColumn, dtypes: Sequence[DType]) -> List:
     n = rows.size
-    with PHASES.phase("plan"):
-        plan = _get_row_plan(dtypes, n)
+    plan = _get_row_plan(dtypes, n)
     starts, sizes = plan["starts"], plan["sizes"]
     validity_offset = plan["validity_offset"]
     size_per_row = plan["size_per_row"]
@@ -454,43 +441,41 @@ def _convert_from_rows_host(rows: ListColumn, dtypes: Sequence[DType]) -> List:
     offs = np.asarray(rows.offsets).astype(np.int64)
     row_off = offs[:-1]
 
-    with PHASES.phase("gather"):
-        if flat.size == n * fixed_row and bool(
-            (offs == np.arange(n + 1, dtype=np.int64) * fixed_row).all()
-        ):
-            # uniform rows (fixed-width-only batch): a reshape view, no copy
-            fixed = flat.reshape(n, fixed_row)
-        else:
-            pos = row_off[:, None] + np.arange(size_per_row, dtype=np.int64)
-            fixed = flat[np.minimum(pos, max(flat.size - 1, 0))]
+    if flat.size == n * fixed_row and bool(
+        (offs == np.arange(n + 1, dtype=np.int64) * fixed_row).all()
+    ):
+        # uniform rows (fixed-width-only batch): a reshape view, no copy
+        fixed = flat.reshape(n, fixed_row)
+    else:
+        pos = row_off[:, None] + np.arange(size_per_row, dtype=np.int64)
+        fixed = flat[np.minimum(pos, max(flat.size - 1, 0))]
 
     out: List = []
-    with PHASES.phase("lanes"):
-        for c, (dt, start, size) in enumerate(zip(dtypes, starts, sizes)):
-            vb = fixed[:, validity_offset + c // 8]
-            validity = jnp.asarray(((vb >> np.uint8(c % 8)) & np.uint8(1)) == 1)
-            if dt.kind == Kind.STRING:
-                pr = np.ascontiguousarray(fixed[:, start : start + 8]).view(
-                    np.uint32
-                )
-                soff = pr[:, 0].astype(np.int64)
-                slen = pr[:, 1].astype(np.int64)
-                tot = int(slen.sum())
-                if tot:
-                    idx = _ragged_char_indices(row_off + soff, slen)
-                    chars = flat[np.minimum(idx, flat.size - 1)]
-                else:
-                    chars = np.zeros((0,), np.uint8)
-                soffsets = np.zeros((n + 1,), np.int32)
-                soffsets[1:] = np.cumsum(slen)
-                out.append(
-                    StringColumn(
-                        jnp.asarray(chars), jnp.asarray(soffsets), validity
-                    )
-                )
+    for c, (dt, start, size) in enumerate(zip(dtypes, starts, sizes)):
+        vb = fixed[:, validity_offset + c // 8]
+        validity = jnp.asarray(((vb >> np.uint8(c % 8)) & np.uint8(1)) == 1)
+        if dt.kind == Kind.STRING:
+            pr = np.ascontiguousarray(fixed[:, start : start + 8]).view(
+                np.uint32
+            )
+            soff = pr[:, 0].astype(np.int64)
+            slen = pr[:, 1].astype(np.int64)
+            tot = int(slen.sum())
+            if tot:
+                idx = _ragged_char_indices(row_off + soff, slen)
+                chars = flat[np.minimum(idx, flat.size - 1)]
             else:
-                raw = np.ascontiguousarray(fixed[:, start : start + size])
-                out.append(_np_bytes_to_col(raw, dt, validity))
+                chars = np.zeros((0,), np.uint8)
+            soffsets = np.zeros((n + 1,), np.int32)
+            soffsets[1:] = np.cumsum(slen)
+            out.append(
+                StringColumn(
+                    jnp.asarray(chars), jnp.asarray(soffsets), validity
+                )
+            )
+        else:
+            raw = np.ascontiguousarray(fixed[:, start : start + size])
+            out.append(_np_bytes_to_col(raw, dt, validity))
     return out
 
 
@@ -528,46 +513,43 @@ def _convert_to_rows_device(
     # ---- fixed-width section as a dense [n, size_per_row] matrix ----
     if bool(config.get("rows_plan_cache")):
         # round 20: one fused permutation gather over the lane matrix
-        with PHASES.phase("plan"):
-            plan = _get_row_plan(dtypes, n)
-        with PHASES.phase("lanes"):
-            str_starts = []
-            if string_cols:
-                # exclusive running char offset per string column: spr + the
-                # cumulative lengths of the preceding string columns
-                run = jnp.cumsum(jnp.stack(str_lens, axis=0), axis=0)
-                str_starts = [
-                    run[i] - str_lens[i] + size_per_row
-                    for i in range(len(string_cols))
-                ]
-            lanes_list = []
-            si = 0
-            for col in columns:
-                if col.dtype.kind == Kind.STRING:
-                    pair = jnp.stack(
+        plan = _get_row_plan(dtypes, n)
+        str_starts = []
+        if string_cols:
+            # exclusive running char offset per string column: spr + the
+            # cumulative lengths of the preceding string columns
+            run = jnp.cumsum(jnp.stack(str_lens, axis=0), axis=0)
+            str_starts = [
+                run[i] - str_lens[i] + size_per_row
+                for i in range(len(string_cols))
+            ]
+        lanes_list = []
+        si = 0
+        for col in columns:
+            if col.dtype.kind == Kind.STRING:
+                pair = jnp.stack(
+                    [
+                        str_starts[si].astype(jnp.uint32),
+                        str_lens[si].astype(jnp.uint32),
+                    ],
+                    axis=1,
+                )
+                lanes_list.append(
+                    jnp.stack(
                         [
-                            str_starts[si].astype(jnp.uint32),
-                            str_lens[si].astype(jnp.uint32),
+                            (pair[:, i // 4] >> jnp.uint32(8 * (i % 4))).astype(jnp.uint8)
+                            for i in range(8)
                         ],
                         axis=1,
                     )
-                    lanes_list.append(
-                        jnp.stack(
-                            [
-                                (pair[:, i // 4] >> jnp.uint32(8 * (i % 4))).astype(jnp.uint8)
-                                for i in range(8)
-                            ],
-                            axis=1,
-                        )
-                    )
-                    si += 1
-                else:
-                    lanes_list.append(_col_le_bytes(col))
-            lanes = jnp.concatenate(lanes_list + [_validity_bytes(columns)], axis=1)
-        with PHASES.phase("gather"):
-            fixed = _gather_fixed(
-                lanes, plan["perm_dev"], plan["keep_dev"]
-            )[:, :size_per_row]
+                )
+                si += 1
+            else:
+                lanes_list.append(_col_le_bytes(col))
+        lanes = jnp.concatenate(lanes_list + [_validity_bytes(columns)], axis=1)
+        fixed = _gather_fixed(
+            lanes, plan["perm_dev"], plan["keep_dev"]
+        )[:, :size_per_row]
     else:
         # oracle: per-column scatter chain (pre-round-20 byte path)
         # analyze: ignore[governed-allocation] - same ungoverned row-
@@ -595,40 +577,39 @@ def _convert_to_rows_device(
         fixed = fixed.at[:, validity_offset:size_per_row].set(_validity_bytes(columns))
 
     # ---- emit batches ----
-    with PHASES.phase("emit"):
-        bounds = _batch_boundaries(row_sizes, max_batch_bytes)
-        str_lens_np = [np.asarray(c.lengths()) for c in string_cols]
-        out: List[ListColumn] = []
-        cum_sizes = np.concatenate([[0], np.cumsum(row_sizes)])
-        for b0, b1 in zip(bounds[:-1], bounds[1:]):
-            offsets_np = (cum_sizes[b0 : b1 + 1] - cum_sizes[b0]).astype(np.int32)
-            total = int(offsets_np[-1])
-            row_off = jnp.asarray(offsets_np[:-1].astype(np.int64))
-            # analyze: ignore[governed-allocation] - same row-codec debt
-            flat = jnp.zeros((max(total, 1),), jnp.uint8)
-            # scatter the fixed sections
-            pos = row_off[:, None] + jnp.arange(size_per_row, dtype=jnp.int64)[None, :]
-            flat = flat.at[pos].set(fixed[b0:b1], mode="drop")
-            # scatter string chars (column order); pad per batch so one long
-            # string elsewhere in the table doesn't inflate this batch's tile
-            for scol, lens_np, sstart in zip(string_cols, str_lens_np, str_starts):
-                batch_max = max(int(lens_np[b0:b1].max()) if b1 > b0 else 0, 1)
-                sub = StringColumn(
-                    scol.chars,
-                    scol.offsets[b0 : b1 + 1],
-                    None,
-                )
-                padded, lens = sub.padded(batch_max)
-                lane = jnp.arange(batch_max, dtype=jnp.int64)[None, :]
-                cpos = row_off[:, None] + sstart[b0:b1, None] + lane
-                in_bounds = lane < lens[:, None].astype(jnp.int64)
-                cpos = jnp.where(in_bounds, cpos, jnp.int64(total))
-                flat = flat.at[cpos].set(padded, mode="drop")
-            out.append(
-                ListColumn(
-                    jnp.asarray(offsets_np), Column(flat[:total], None, UINT8), None
-                )
+    bounds = _batch_boundaries(row_sizes, max_batch_bytes)
+    str_lens_np = [np.asarray(c.lengths()) for c in string_cols]
+    out: List[ListColumn] = []
+    cum_sizes = np.concatenate([[0], np.cumsum(row_sizes)])
+    for b0, b1 in zip(bounds[:-1], bounds[1:]):
+        offsets_np = (cum_sizes[b0 : b1 + 1] - cum_sizes[b0]).astype(np.int32)
+        total = int(offsets_np[-1])
+        row_off = jnp.asarray(offsets_np[:-1].astype(np.int64))
+        # analyze: ignore[governed-allocation] - same row-codec debt
+        flat = jnp.zeros((max(total, 1),), jnp.uint8)
+        # scatter the fixed sections
+        pos = row_off[:, None] + jnp.arange(size_per_row, dtype=jnp.int64)[None, :]
+        flat = flat.at[pos].set(fixed[b0:b1], mode="drop")
+        # scatter string chars (column order); pad per batch so one long
+        # string elsewhere in the table doesn't inflate this batch's tile
+        for scol, lens_np, sstart in zip(string_cols, str_lens_np, str_starts):
+            batch_max = max(int(lens_np[b0:b1].max()) if b1 > b0 else 0, 1)
+            sub = StringColumn(
+                scol.chars,
+                scol.offsets[b0 : b1 + 1],
+                None,
             )
+            padded, lens = sub.padded(batch_max)
+            lane = jnp.arange(batch_max, dtype=jnp.int64)[None, :]
+            cpos = row_off[:, None] + sstart[b0:b1, None] + lane
+            in_bounds = lane < lens[:, None].astype(jnp.int64)
+            cpos = jnp.where(in_bounds, cpos, jnp.int64(total))
+            flat = flat.at[cpos].set(padded, mode="drop")
+        out.append(
+            ListColumn(
+                jnp.asarray(offsets_np), Column(flat[:total], None, UINT8), None
+            )
+        )
     return out
 
 
@@ -666,11 +647,9 @@ def _convert_from_rows_device(rows: ListColumn, dtypes: Sequence[DType]) -> List
     # gather per column straight from the flat buffer.
     fixed = None
     if bool(config.get("rows_plan_cache")):
-        with PHASES.phase("plan"):
-            _get_row_plan(dtypes, n)  # warm/validate the cached layout
-        with PHASES.phase("gather"):
-            pos = row_off[:, None] + jnp.arange(size_per_row, dtype=jnp.int64)[None, :]
-            fixed = flat[jnp.clip(pos, 0, max(flat.shape[0] - 1, 0))]
+        _get_row_plan(dtypes, n)  # warm/validate the cached layout
+        pos = row_off[:, None] + jnp.arange(size_per_row, dtype=jnp.int64)[None, :]
+        fixed = flat[jnp.clip(pos, 0, max(flat.shape[0] - 1, 0))]
 
     # validity bits for every column
     nbytes = (len(dtypes) + 7) // 8

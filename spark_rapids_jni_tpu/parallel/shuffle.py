@@ -49,8 +49,8 @@ def partition_of(keys: jnp.ndarray, n_parts: int) -> jnp.ndarray:
     ``murmur3`` (default; Spark's placement hash) or ``mix32``
     (ops/hashing.partition_mix32 — pure u32 lane math, ~1/3 the multiply
     count; placement only needs every participant to agree, which one
-    traced program guarantees).  The A/B lives in bench.py's
-    partition-hash stage; flip the default to the measured winner."""
+    traced program guarantees).  No benchmark cell measures the two yet;
+    flip the default only on a measured win in ``benchmark/``."""
     from spark_rapids_jni_tpu import config
     from spark_rapids_jni_tpu.ops.hashing import (
         murmur3_raw_int64,

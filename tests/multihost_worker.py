@@ -2,10 +2,11 @@
 
 Each process joins the JAX process group through the framework's own
 ``multihost.initialize`` (explicit coordinator args — the CPU-cluster /
-test path), builds the pod mesh, and runs the distributed q97 query step
-over globally-sharded inputs.  Prints one JSON line with the process
-summary and the q97 totals; the parent test asserts both processes agree
-with the local oracle.
+test path), builds the pod mesh, and runs the compiled q97 plan
+(``run_distributed_q97``) over inputs sharded across every process's
+devices.  Prints one JSON line with the process summary and the q97
+totals; the parent test asserts both processes agree with the host-set
+oracle.
 """
 
 import json
@@ -26,45 +27,34 @@ def main() -> int:
                          num_processes=nproc, process_id=pid)
 
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
-    from spark_rapids_jni_tpu.models.q97 import make_distributed_q97, q97_local
+    from spark_rapids_jni_tpu.models.q97 import (
+        q97_host_oracle,
+        run_distributed_q97,
+    )
 
     assert multihost.is_multihost()
     mesh = multihost.make_pod_mesh(mp=1, axis_names=("data", "model"))
     ndev = len(jax.devices())
 
-    # identical global inputs in every process (deterministic seed); each
-    # process donates its local shards via make_array_from_callback
+    # identical global inputs in every process (deterministic seed); the
+    # plan runtime places each process's shards of them on its devices
     rng = np.random.RandomState(11)
     rows = 512
     glb = [rng.randint(1, 50, rows).astype(np.int32) for _ in range(4)]
 
-    spec = jax.sharding.PartitionSpec("data")
-    sharding = jax.sharding.NamedSharding(mesh, spec)
-
-    def to_global(a):
-        return jax.make_array_from_callback(
-            a.shape, sharding, lambda idx: a[idx])
-
-    args = [to_global(a) for a in glb]
-    step = make_distributed_q97(mesh, capacity=rows)
-    out = step(*args)
+    out = run_distributed_q97(mesh, (glb[0], glb[1]), (glb[2], glb[3]),
+                              capacity=rows)
     got = {
         "store_only": int(out.store_only),
         "catalog_only": int(out.catalog_only),
         "both": int(out.both),
         "dropped": int(out.dropped),
     }
-    want_out = q97_local((jnp.asarray(glb[0]), jnp.asarray(glb[1])),
-                         (jnp.asarray(glb[2]), jnp.asarray(glb[3])))
-    want = {
-        "store_only": int(want_out.store_only),
-        "catalog_only": int(want_out.catalog_only),
-        "both": int(want_out.both),
-        "dropped": 0,
-    }
+    want = dict(zip(("store_only", "catalog_only", "both"),
+                    q97_host_oracle((glb[0], glb[1]), (glb[2], glb[3]))),
+                dropped=0)
     print(json.dumps({"proc": pid, "summary": multihost.process_summary(),
                       "got": got, "want": want, "ndev": ndev}), flush=True)
     return 0 if got == want else 1

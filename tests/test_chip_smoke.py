@@ -40,11 +40,6 @@ def test_exits_nonzero_alone_in_a_directory(tmp_path):
     assert r.returncode != 0 and '"ok": true' not in r.stdout
 
 
-def test_bench_exits_nonzero_without_tpu():
-    r = _run_script(os.path.join(REPO, "bench.py"), REPO)
-    assert r.returncode != 0 and "needs a TPU" in r.stderr
-
-
 def test_device_phase_refuses_cpu():
     with pytest.raises(AssertionError, match="not tpu"):
         chip_smoke.phase_device()

@@ -14,13 +14,13 @@ def test_version_and_build_info():
 
 
 def test_flag_env_resolution(monkeypatch):
-    monkeypatch.delenv("BENCH_ITERS", raising=False)
-    assert config.get("bench_iters") == 20
-    monkeypatch.setenv("BENCH_ITERS", "7")
-    assert config.get("bench_iters") == 7
-    monkeypatch.setenv("BENCH_ITERS", "not-a-number")
+    monkeypatch.delenv("SRT_JSON_FUZZ_ROWS", raising=False)
+    assert config.get("json_fuzz_rows") == 300
+    monkeypatch.setenv("SRT_JSON_FUZZ_ROWS", "7")
+    assert config.get("json_fuzz_rows") == 7
+    monkeypatch.setenv("SRT_JSON_FUZZ_ROWS", "not-a-number")
     with pytest.warns(RuntimeWarning):
-        assert config.get("bench_iters") == 20  # unparsable -> default
+        assert config.get("json_fuzz_rows") == 300  # unparsable -> default
 
 
 def test_flag_override_context():

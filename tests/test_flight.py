@@ -503,21 +503,3 @@ def test_flightdump_cli(tmp_path):
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out2.returncode == 0 and "task 1" in out2.stdout
     assert "task 2" not in out2.stdout
-
-
-# ------------------------------------------------- bench --profile helper
-
-
-def test_bench_profile_overhead_helper():
-    sys.path.insert(0, REPO_ROOT)
-    import bench
-
-    Profiler.init(io.BytesIO())
-    try:
-        out = bench._measure_profile_overhead(lambda: sum(range(20000)),
-                                              "unit")
-    finally:
-        Profiler.shutdown()
-    assert set(out) == {"plain_s", "profiled_s", "overhead_frac"}
-    assert out["plain_s"] > 0 and out["profiled_s"] > 0
-    assert out["overhead_frac"] >= 0.0  # noise clamps at zero

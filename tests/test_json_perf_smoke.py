@@ -48,7 +48,7 @@ def test_single_path_throughput_floor():
     assert rate >= _FLOOR_ROWS_PER_S, (
         f"get_json_object fell to {rate:.0f} rows/s "
         f"(floor {_FLOOR_ROWS_PER_S}); the host pipeline has regressed "
-        f"pathologically — check bench.py phases_s for the guilty stage")
+        f"pathologically")
 
 
 def test_multi_path_amortizes_tokenization():

@@ -3,7 +3,7 @@
 This is the closest a single box gets to the multi-host claim: each
 process owns 2 virtual CPU devices, ``multihost.initialize`` joins them
 through a real coordinator, ``make_pod_mesh`` spans all 4 global devices,
-and the SAME shard_map q97 program that runs single-process executes with
+and the SAME compiled q97 plan that runs single-process executes with
 cross-process collectives.  (On a pod, the identical code path rides
 ICI/DCN — SURVEY.md §2.3's planning note.)
 """
@@ -48,7 +48,7 @@ def test_two_process_group_runs_distributed_q97():
 
 def test_four_process_group_runs_distributed_q97():
     """Pod-shape evidence past 2 processes: a 4-process group (8 global
-    devices) runs the same shard_map program with cross-process
+    devices) runs the same compiled plan with cross-process
     collectives (SURVEY §2.3 planning note; VERDICT r4 #9)."""
     _run_group_with_port_retry(4)
 

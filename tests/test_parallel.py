@@ -120,18 +120,19 @@ def test_multihost_single_process_noop_and_pod_mesh():
 
 def test_partition_mix32_placement_backend():
     """The cheap mix32 placement hash (partition_hash config): spreads
-    dense keys, is deterministic, and a distributed q97 traced under it
-    still matches the host oracle — placement choice can never change
-    results, only where rows land."""
+    dense keys, is deterministic, and the q97 plan traced under it still
+    matches the host oracle — placement choice can never change results,
+    only where rows land."""
     import numpy as np
 
     from spark_rapids_jni_tpu import config
     from spark_rapids_jni_tpu.models.q97 import (
-        make_distributed_q97,
         q97_host_oracle,
+        run_distributed_q97,
     )
     from spark_rapids_jni_tpu.ops.hashing import partition_mix32
     from spark_rapids_jni_tpu.parallel.shuffle import partition_of
+    from spark_rapids_jni_tpu.plans.cache import plan_cache
 
     rng = np.random.RandomState(2)
     # dense TPC-DS-ish packed pairs (the worst case for a weak mix)
@@ -151,14 +152,17 @@ def test_partition_mix32_placement_backend():
 
         mesh = make_mesh((8, 1))
         n = 8 * 64
-        s = (jnp.asarray(cust[:n].astype(np.int32)),
-             jnp.asarray(item[:n].astype(np.int32)))
-        c = (jnp.asarray(cust[n:2 * n].astype(np.int32)),
-             jnp.asarray(item[n:2 * n].astype(np.int32)))
-        step = make_distributed_q97(mesh, capacity=2 * n)
-        out = step(*s, *c)  # traced INSIDE the override: mix32 placement
-    want = q97_host_oracle((np.asarray(s[0]), np.asarray(s[1])),
-                           (np.asarray(c[0]), np.asarray(c[1])))
+        s = (cust[:n].astype(np.int32), item[:n].astype(np.int32))
+        c = (cust[n:2 * n].astype(np.int32), item[n:2 * n].astype(np.int32))
+        # the plan cache does not key on partition_hash: empty it so the
+        # plan is traced INSIDE the override (mix32 placement), and again
+        # after, so no later run reuses that program
+        plan_cache.clear()
+        try:
+            out = run_distributed_q97(mesh, s, c, capacity=2 * n)
+        finally:
+            plan_cache.clear()
+    want = q97_host_oracle(s, c)
     assert (int(out.store_only), int(out.catalog_only),
             int(out.both)) == want
     assert int(out.dropped) == 0

@@ -88,10 +88,12 @@ def test_each_split_q97_partial_verifies(q97_files):
     """One file, split two ways: each split's q97 partial (vs the catalog
     file read whole) matches the host set oracle on exactly that split's
     rows, and the two splits together cover the whole file."""
-    import jax.numpy as jnp
+    import jax
 
-    from spark_rapids_jni_tpu.models import q97_local
+    from spark_rapids_jni_tpu.models import run_distributed_q97
+    from spark_rapids_jni_tpu.parallel import make_mesh
 
+    mesh = make_mesh((1, 1), devices=jax.devices()[:1])
     store_path, catalog_path = q97_files
     cat = read_split(catalog_path, *plan_byte_splits(catalog_path, 1)[0],
                      schema=_keys_schema("cs"), as_numpy=True)
@@ -106,8 +108,7 @@ def test_each_split_q97_partial_verifies(q97_files):
         store = (part["ss_customer_sk"][0].astype(np.int32),
                  part["ss_item_sk"][0].astype(np.int32))
         total_rows += len(store[0])
-        out = q97_local(tuple(map(jnp.asarray, store)),
-                        tuple(map(jnp.asarray, catalog)))
+        out = run_distributed_q97(mesh, store, catalog)
         s_set = set(zip(store[0].tolist(), store[1].tolist()))
         want = (len(s_set - c_set), len(c_set - s_set), len(s_set & c_set))
         got = (int(out.store_only), int(out.catalog_only), int(out.both))

@@ -1,6 +1,6 @@
 """Throughput floor smoke tests for the round-20 straggler fast paths.
 
-These are NOT benchmarks — bench.py owns the real numbers.  They are
+These are NOT benchmarks — ``benchmark/`` owns the real numbers.  They are
 regression tripwires: the pre-round-20 pipelines ran at O(100) rows/s per
 stage on the CPU mesh (BENCH_r09), the fast paths run 3-4 orders of
 magnitude above these floors, so a trip means a dispatch regression (the
