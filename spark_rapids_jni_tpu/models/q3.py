@@ -196,35 +196,44 @@ def _facts(data: Q3Data) -> dict:
 def q3_plan(*, n_brands: int, year0: int, n_years: int, date_sk0: int,
             manufact_id: int, moy: int) -> ir.Plan:
     """The whole q3 device pipeline as ONE plan: scan -> item gather ->
-    date gather -> manufact/moy filter -> grouped segment-sum into the
-    dense [n_years * n_brands] grid (``n_brands`` counts the item table's
-    brand codes; the item dim's ``brand`` field holds each item's 1-based
-    code).  Geometry scalars normalize through
-    ``plans.ir.lit`` so equal geometry always builds an EQUAL plan (one
-    cache entry on the process-global plan cache).  Memoized per
-    geometry: the per-request hot path must not rebuild (and re-hash)
-    the plan tree every call."""
+    date gather -> validity filter -> grouped segment-sum into the dense
+    [n_years * n_brands] grid (``n_brands`` counts the item table's brand
+    codes; the item dim's ``brand`` field holds each item's 1-based code).
+
+    Each join gathers ONE int32 evaluated on its dimension table: the
+    item's 0-based brand code where its manufacturer qualifies, the day's
+    clipped year offset where its month qualifies, else -1 — neither kept
+    value is negative, so ``>= 0`` is the dimension filter and the group
+    is ``year_off * n_brands + brand_code``, exactly the per-op body's
+    grid arithmetic.  Geometry scalars normalize through ``plans.ir.lit``
+    so equal geometry always builds an EQUAL plan (one cache entry on the
+    process-global plan cache).  Memoized per geometry: the per-request
+    hot path must not rebuild (and re-hash) the plan tree every call."""
     item = ir.Dim("item", ("brand", "manufact"))
     date = ir.Dim("date_dim", ("year", "moy"))
+
+    def or_miss(pred, value):
+        return Bin("sub", Bin("mul", Cast(pred, "int32"),
+                              Bin("add", value, lit(1))), lit(1))
+
+    brand_code = Bin("sub", Cast(col("brand"), "int32"), lit(1))
+    year_off = Cast(Bin("sub", col("year"), lit(year0)), "int32")
+    clipped = Bin("min", Bin("max", year_off, lit(0)), lit(n_years - 1))
     node: ir.Node = ir.Scan(
         "store_sales", ("ss_item", "ss_item_v", "ss_date", "ss_date_v",
                         "price"))
     node = ir.GatherJoin(node, item, key=col("ss_item"), base=lit(1),
-                         fields=(("brand", "brand"),
-                                 ("manufact", "manufact")))
+                         fields=((or_miss(Bin("eq", col("manufact"),
+                                                  lit(manufact_id)),
+                                              brand_code), "b"),))
     node = ir.GatherJoin(node, date, key=col("ss_date"), base=lit(date_sk0),
-                         fields=(("year", "year"), ("moy", "moy")))
+                         fields=((or_miss(Bin("eq", col("moy"), lit(moy)),
+                                          clipped), "y"),))
     node = ir.Filter(node, band_all(
         col("ss_item_v"), col("ss_date_v"),
-        Bin("eq", col("manufact"), lit(manufact_id)),
-        Bin("eq", col("moy"), lit(moy)),
+        Bin("ge", col("b"), lit(0)), Bin("ge", col("y"), lit(0)),
     ))
-    # group = clip(year - year0, 0, n_years-1) * n_brands + (brand - 1),
-    # exactly the per-op body's grid arithmetic (brand is 1-based)
-    year_off = Cast(Bin("sub", col("year"), lit(year0)), "int32")
-    clipped = Bin("min", Bin("max", year_off, lit(0)), lit(n_years - 1))
-    group = Bin("add", Bin("mul", clipped, lit(n_brands)),
-                Bin("sub", Cast(col("brand"), "int32"), lit(1)))
+    group = Bin("add", Bin("mul", col("y"), lit(n_brands)), col("b"))
     node = ir.Project(node, (("group", group),))
     sink = ir.SegmentAgg(
         node, key=col("group"), num_segments=n_years * n_brands,

@@ -66,7 +66,7 @@ __all__ = [
     "EV_RCACHE_EVICT", "EV_RCACHE_INVALIDATE",
     "EV_PLAN_REWRITE", "EV_ADAPT_EXCHANGE",
     "EV_HEDGE_LAUNCH", "EV_HEDGE_WIN", "EV_HEDGE_LOSE",
-    "EV_ATTRIB", "EV_SEGMENT_AGG",
+    "EV_ATTRIB", "EV_SEGMENT_AGG", "EV_GATHER_JOIN",
     "EVENT_KINDS", "EVENT_PAIRS", "KIND_IDS", "DUMP_SCHEMA",
     "FlightRecorder", "record", "anomaly", "snapshot", "snapshot_since",
     "task_stats", "task_stat", "ring_stats",
@@ -243,6 +243,11 @@ EV_ATTRIB = "attrib"
 # (detail=plan:<name>:path:<sorted|scatter|mixed>:scattered:<rows the
 # aggregation ran over>:kept:<rows their masks kept>, value=kept)
 EV_SEGMENT_AGG = "segment_agg"
+# the plan runtime's join counter (plans/runtime.execute_plan): one event
+# per plan run with GatherJoins, computed from the plan on the host
+# (detail=plan:<name>:gathers:<fact-length columns gathered>:dim_side:
+# <of them, expressions evaluated on the dimension table>, value=gathers)
+EV_GATHER_JOIN = "gather_join"
 
 # Paired kinds: a layer that emits the left side of a pair must also emit
 # the right side (module-granular balance, enforced by the analyze gate's
@@ -290,6 +295,7 @@ EVENT_KINDS = (
     EV_ATTRIB,
     # appended for the same reason
     EV_SEGMENT_AGG,
+    EV_GATHER_JOIN,
 )
 KIND_IDS = {k: i for i, k in enumerate(EVENT_KINDS)}
 

@@ -187,12 +187,24 @@ def _emit_gather_join(node: ir.GatherJoin, ctx: _Ctx) -> _Rows:
     with jax.named_scope("gather_join"):
         key = _eval(node.key, rows.cols)
         base = _eval(node.base, rows.cols)
-        n_dim = dim[node.fields[0][0]].shape[0]
+        n_dim = dim[node.dim.fields[0]].shape[0]
         idx = jnp.clip(key - base, 0, n_dim - 1)
         cols = dict(rows.cols)
-        for dfield, out in node.fields:
-            cols[out] = dim[dfield][idx]
+        for source, out in node.fields:
+            # an expression source runs over the n_dim dimension rows,
+            # then gathers like a column
+            vals = dim[source] if isinstance(source, str) \
+                else _eval(source, dim)
+            cols[out] = vals[idx]
     return _Rows(cols, rows.mask)
+
+
+def gather_fields(plan: ir.Plan) -> Tuple[int, int]:
+    """(fact-length columns the plan's GatherJoins gather, how many of
+    them are dimension-side expressions)."""
+    fields = [source for n in ir.walk(plan) if isinstance(n, ir.GatherJoin)
+              for source, _out in n.fields]
+    return len(fields), sum(not isinstance(s, str) for s in fields)
 
 
 @emitter(ir.SemiJoinWindow)

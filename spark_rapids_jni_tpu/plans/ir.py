@@ -167,16 +167,26 @@ class Project:
 
 @dataclasses.dataclass(frozen=True)
 class GatherJoin:
-    """Dense surrogate-key inner-join: gather ``dim`` fields at
-    ``clip(key - base, 0, len-1)``.  Out-of-range / null keys must be
-    excluded by a Filter on the pipeline mask (the gather itself clips,
-    matching the per-op device bodies bit for bit)."""
+    """Dense surrogate-key inner-join: gather at ``clip(key - base, 0,
+    len-1)``.  Out-of-range / null keys must be excluded by a Filter on
+    the pipeline mask (the gather itself clips, matching the per-op
+    device bodies bit for bit).
+
+    Each field is ``(source, out_name)``.  A ``str`` source names a
+    ``dim`` column and gathers it.  An expression source reads ``dim``'s
+    columns only: it is evaluated once over the dimension table, then its
+    value is gathered — one fact-length column for what would otherwise
+    be several gathers and the same expression per fact row.  Where the
+    expression folds a dimension predicate in, the plan author chooses
+    the value a row that fails it gathers (a miss value the kept values
+    never take, e.g. ``-1`` beside non-negative codes) and filters on it
+    above the join."""
 
     child: "Node"
     dim: Dim
     key: Expr
     base: Expr  # usually lit(1) (1-based sks) or lit(date_sk0)
-    fields: Tuple[Tuple[str, str], ...]  # (dim_field, out_name)
+    fields: Tuple[Tuple[_U[str, Expr], str], ...]  # (source, out_name)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -38,6 +38,7 @@ from spark_rapids_jni_tpu.plans.compiler import (
     VALID_FIELD,
     agg_path,
     cached_compile,
+    gather_fields,
 )
 
 __all__ = ["pad_tables", "plan_working_set_bytes", "execute_plan",
@@ -253,7 +254,10 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
     trace context (``plan_pad``, ``plan_upload``, ``plan_run``,
     ``plan_download``; no-ops without one).  A plan with SegmentAgg sinks
     also records one ``segment_agg`` flight event: the path its sums took,
-    the rows the aggregation ran over and the rows their masks kept.
+    the rows the aggregation ran over and the rows their masks kept.  A
+    plan with GatherJoins records one ``gather_join`` event: the
+    fact-length columns its joins gather and how many of them are
+    expressions evaluated on the dimension table.
 
     Raises :class:`mem.governed.ShuffleCapacityExceeded` when an
     Exchange overflowed (``dropped > 0``) — the caller grows the
@@ -311,6 +315,13 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
                 detail=f"plan:{plan.name}:path:{agg_path(plan)}:scattered:"
                        f"{int(outputs.pop(AGG_ROWS))}:kept:{int(kept)}",
                 value=int(kept))
+        gathers, dim_side = gather_fields(plan)
+        if gathers:
+            _flight.record(
+                _flight.EV_GATHER_JOIN,
+                detail=f"plan:{plan.name}:gathers:{gathers}:"
+                       f"dim_side:{dim_side}",
+                value=gathers)
         if int(outputs.get("dropped", 0)) > 0:
             raise ShuffleCapacityExceeded(
                 f"{int(outputs['dropped'])} rows overflowed the plan's "

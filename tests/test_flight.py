@@ -167,6 +167,8 @@ def test_event_kind_vocabulary_is_stable():
     assert flight.EVENT_KINDS[47:48] == ("attrib",)
     # the plan runtime's aggregate counter is strictly appended after
     assert flight.EVENT_KINDS[48:49] == ("segment_agg",)
+    # the plan runtime's join counter is strictly appended after
+    assert flight.EVENT_KINDS[49:50] == ("gather_join",)
     assert len(set(flight.EVENT_KINDS)) == len(flight.EVENT_KINDS)
 
 

@@ -212,6 +212,71 @@ def test_filter_reading_non_wire_column_stays_above_exchange():
     assert "filter_below_exchange" not in [r for r, _ in applied]
 
 
+def _expr_join_plan(pred, a_first=True, key_b="kb"):
+    """Two joins whose fields are expressions over their dims' columns:
+    ``wa`` = 2 w, and ``vb`` = v where v > 3, else -1."""
+    node = ir.Scan("facts", ("ka", "kb", "qty"))
+    gated = ir.Bin("sub", ir.Bin("mul", ir.Cast(ir.Bin(
+        "gt", ir.col("v"), ir.lit(3)), "int64"), ir.Bin(
+        "add", ir.col("v"), ir.lit(1))), ir.lit(1))
+    ja = (ir.Dim("dim_a", ("w",)), ir.col("ka"),
+          ((ir.Bin("mul", ir.col("w"), ir.lit(2)), "wa"),))
+    jb = (ir.Dim("dim_b", ("v",)), ir.col(key_b), ((gated, "vb"),))
+    for dim, key, fields in ([ja, jb] if a_first else [jb, ja]):
+        node = ir.GatherJoin(node, dim, key, ir.lit(0), fields)
+    sink = ir.SegmentAgg(
+        ir.Filter(node, pred), ir.col("ka"), 4,
+        (("s", ir.Bin("mul", ir.col("wa"), ir.col("vb")), "int64"),
+         ("c", ir.lit(1), "int64")))
+    return ir.Plan("expr", (sink,))
+
+
+def test_filter_below_expression_gather_reads_its_out_names():
+    """A filter slides below a join whose fields are expressions exactly
+    when it reads none of the join's out_names: the dim columns the
+    expressions read are not row columns."""
+    below_both = _expr_join_plan(ir.Bin("gt", ir.col("qty"), ir.lit(2)))
+    out, applied = rewrite_plan(below_both, {})
+    assert [r for r, _ in applied] == ["filter_below_gather"] * 2
+    assert isinstance(out.sinks[0].child.child.child, ir.Filter)
+    _assert_same_outputs(below_both, out, _facts())
+
+    on_wa = _expr_join_plan(ir.Bin("gt", ir.col("wa"), ir.lit(6)))
+    out, applied = rewrite_plan(on_wa, {})
+    assert [r for r, _ in applied] == ["filter_below_gather"]
+    upper = out.sinks[0].child
+    assert upper.dim.table == "dim_b"
+    assert isinstance(upper.child, ir.Filter)
+    assert upper.child.child.dim.table == "dim_a"
+    _assert_same_outputs(on_wa, out, _facts())
+
+    on_vb = _expr_join_plan(ir.Bin("ge", ir.col("vb"), ir.lit(0)))
+    assert rewrite_plan(on_vb, {}) == (on_vb, ())
+
+
+def _joins_top_down(plan):
+    return [n.dim.table for n in ir.walk(plan)
+            if isinstance(n, ir.GatherJoin)]
+
+
+def test_join_reorder_moves_expression_gathers_by_their_out_names():
+    stats = {"dim_a": 1000, "dim_b": 3}
+    pred = ir.Bin("ge", ir.col("vb"), ir.lit(0))
+    plan = _expr_join_plan(pred)
+    out, applied = rewrite_plan(plan, stats)
+    assert "join_reorder" in [r for r, _ in applied]
+    assert _joins_top_down(out) == ["dim_a", "dim_b"]
+    assert out == rewrite_plan(_expr_join_plan(pred, a_first=False),
+                               stats)[0]
+    _assert_same_outputs(plan, out, _facts())
+    # dim_b's key reads dim_a's expression field: the order is forced
+    keyed = _expr_join_plan(pred, key_b="wa")
+    out, applied = rewrite_plan(keyed, stats)
+    assert "join_reorder" not in [r for r, _ in applied]
+    assert _joins_top_down(out) == ["dim_b", "dim_a"]
+    _assert_same_outputs(keyed, out, _facts())
+
+
 # ------------------------------------------------------- fixed point + fuzz
 
 

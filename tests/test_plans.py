@@ -436,3 +436,135 @@ def test_split_and_retry_halves_join_to_unfused_oracle(gov):
     stats = plan_cache.stats()
     assert stats["execute_calls"] >= 2
     assert stats["traces"] <= stats["execute_calls"]
+
+
+# ---------------------------------------------------- GatherJoin fields
+
+_DIM_BASE = 5
+
+
+def _gather_tables(n=300, n_dim=40, seed=0):
+    """Fact keys over the dimension and two rows past each end (the join
+    clips them), an int32, an int64 past 32 bits and a flag per dim row."""
+    rng = np.random.default_rng(seed)
+    return {
+        "f": {"rid": np.arange(n, dtype=np.int32),
+              "k": rng.integers(_DIM_BASE - 2, _DIM_BASE + n_dim + 2, n,
+                                dtype=np.int32)},
+        "d": {"a": rng.integers(-1000, 1000, n_dim, dtype=np.int32),
+              "w": rng.integers(-2**40, 2**40, n_dim, dtype=np.int64),
+              "flag": rng.integers(0, 3, n_dim, dtype=np.int32)},
+    }
+
+
+#: (out_name, expression over the dim's columns): int32, int64, and an
+#: int32 gated by a predicate, -1 where it fails
+_DIM_EXPRS = (
+    ("x", ir.Bin("add", ir.Bin("mul", ir.col("a"), ir.lit(3)), ir.lit(-7))),
+    ("y", ir.Bin("sub", ir.col("w"), ir.Cast(ir.col("a"), "int64"))),
+    ("z", ir.Bin("sub", ir.Bin("mul", ir.Cast(ir.Bin(
+        "eq", ir.col("flag"), ir.lit(1)), "int32"), ir.Bin(
+        "add", ir.col("a"), ir.lit(1))), ir.lit(1))),
+)
+
+
+def _rows_plan(node, name):
+    return ir.Plan(name, (ir.Sort(node, keys=((ir.col("rid"), True),),
+                                  fields=("rid", "x", "y", "z")),))
+
+
+def test_expression_field_equals_the_expression_after_a_plain_gather():
+    """A GatherJoin field that is an expression over the dim's columns
+    gathers, row for row and bit for bit, what a plain gather of those
+    columns followed by the same expression on the fact side gives."""
+    scan = ir.Scan("f", ("rid", "k"))
+    dim = ir.Dim("d", ("a", "w", "flag"))
+    dim_side = ir.GatherJoin(scan, dim, ir.col("k"), ir.lit(_DIM_BASE),
+                             tuple((e, out) for out, e in _DIM_EXPRS))
+    fact_side = ir.Project(
+        ir.GatherJoin(scan, dim, ir.col("k"), ir.lit(_DIM_BASE),
+                      tuple((f, f) for f in dim.fields)), _DIM_EXPRS)
+    tables = _gather_tables()
+    got = execute_plan(None, _rows_plan(dim_side, "dim_side"), tables)
+    want = execute_plan(None, _rows_plan(fact_side, "fact_side"), tables)
+    assert [got[f].dtype for f in ("x", "y", "z")] == [
+        np.int32, np.int64, np.int32]
+    for f in ("rid", "x", "y", "z", "rows"):
+        np.testing.assert_array_equal(got[f], want[f])
+    assert np.any(got["z"] == -1) and np.any(got["z"] != -1)
+    assert np.any(np.abs(got["y"]) > 2**32)
+
+
+def _program_text(text: str) -> str:
+    """A compiled program's text without its source locations."""
+    import re
+
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return "\n".join(
+        line for line in text.split("\n\n", 1)[-1].splitlines()
+        if not re.match(r"^(FileNames|FunctionNames|FileLocations|"
+                        r"StackFrames|\d+ )", line))
+
+
+def _q64_map_plan():
+    """q64's map side, its two name-field GatherJoins, projection and
+    filter, under a local Sort sink."""
+    from spark_rapids_jni_tpu.models.q64 import q64_plan
+
+    (ex,) = ir.range_exchange_nodes(q64_plan(3, 30, 25, 2))
+    return ir.Plan("q64_map", (ir.Sort(ex.child, keys=ex.keys,
+                                       fields=ex.fields),))
+
+
+#: sha256 of the q64 map plan compiled locally at 1,000 rows, source
+#: locations left out: name fields gather as they did before a GatherJoin
+#: field could be an expression
+Q64_MAP_PROGRAM = ("a81f2cc448a2815d628a2c6ff46a3435"
+                   "50c2b11e362e3d3f972b5781372a84fd")
+
+
+def test_name_field_joins_compile_to_the_same_program():
+    import hashlib
+
+    from spark_rapids_jni_tpu.models.q64 import make_q64_tables
+    from spark_rapids_jni_tpu.plans.compiler import compile_plan
+    from spark_rapids_jni_tpu.plans.runtime import input_signature_raw
+
+    plan = _q64_map_plan()
+    tables = make_q64_tables(1000, 30, 25, seed=1)
+    cp = compile_plan(plan, None, input_signature_raw(plan, tables, 1))
+    text = _program_text(cp.fn.as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == Q64_MAP_PROGRAM
+
+
+def _run_q3():
+    q3_local(generate_q3_data(sf=0.01, seed=3))
+
+
+def _run_q64_map():
+    from spark_rapids_jni_tpu.models.q64 import make_q64_tables
+
+    execute_plan(None, _q64_map_plan(), make_q64_tables(500, 30, 25, seed=2))
+
+
+def _run_toy():
+    execute_plan(None, _toy_plan(), _toy_tables(100))
+
+
+@pytest.mark.parametrize("run, details", [
+    (_run_q3, ["plan:q3:gathers:2:dim_side:2"]),
+    (_run_q64_map, ["plan:q64_map:gathers:3:dim_side:0"]),
+    (_run_toy, [])], ids=["q3", "q64_map", "no_joins"])
+def test_gather_join_counter_counts_the_gathered_columns(run, details):
+    """One ``gather_join`` flight event per run of a plan with
+    GatherJoins: its fact-length gathered columns, and how many of them
+    are expressions evaluated on the dimension table."""
+    from spark_rapids_jni_tpu.obs import flight
+
+    seq = max((e["seq"] for e in flight.snapshot()), default=0)
+    run()
+    events = [e for e in flight.snapshot()
+              if e["seq"] > seq and e["kind"] == flight.EV_GATHER_JOIN]
+    assert [e["detail"] for e in events] == details
+    assert [e["value"] for e in events] == [
+        int(d.split(":")[3]) for d in details]

@@ -228,15 +228,15 @@ def test_q3_plan_scopes_name_the_join_filter_and_aggregate_ops():
         assert any(scope in n.split("/") for n in names), scope
 
 
-def _scope_opcodes(hlo: str, scope: str) -> set:
-    """The HLO opcodes of the instructions whose ``op_name`` holds
+def _scope_opcodes(hlo: str, scope: str) -> list:
+    """The HLO opcode of each instruction whose ``op_name`` holds
     ``scope`` as a path component."""
-    ops = set()
+    ops = []
     for line in hlo.splitlines():
         name = re.search(r'op_name="([^"]*)"', line)
         op = re.search(r"=\s*(?:\([^)]*\)|\S+)\s+([a-z][a-z0-9-]*)\(", line)
         if name and op and scope in name.group(1).split("/"):
-            ops.add(op.group(1))
+            ops.append(op.group(1))
     return ops
 
 
@@ -271,3 +271,98 @@ def test_q3_plan_sums_integers_by_sort_and_floats_by_scatter(sums):
         assert agg_path(plan) == "sorted" and "scatter" not in ops
     else:
         assert agg_path(plan) == "mixed" and "scatter" in ops
+
+
+# ------------------------------------------- one gather per dimension join --
+
+def test_q3_plan_gathers_one_column_per_dimension_join():
+    """The manufacturer and month tests, the brand code and the year
+    offset are evaluated on the dimension tables: the program gathers one
+    fact-length column per join, two in all (four when each dimension
+    field was gathered)."""
+    from spark_rapids_jni_tpu.models.q3 import (
+        _dims,
+        _facts,
+        _geometry,
+        _q3_tables,
+        q3_plan,
+    )
+    from spark_rapids_jni_tpu.plans.compiler import compile_plan
+    from spark_rapids_jni_tpu.plans.runtime import input_signature_raw
+
+    data = spec_data(3)
+    plan = q3_plan(**_geometry(data))
+    tables = _q3_tables(_facts(data), _dims(data))
+    cp = compile_plan(plan, _one_device_mesh(),
+                      input_signature_raw(plan, tables, 1))
+    ops = _scope_opcodes(cp.fn.as_text(), "gather_join")
+    assert ops.count("gather") == 2
+
+
+def _no_manufacturer(data):
+    data.manufact_id = int(data.item_manufact_id.max()) + 1
+
+
+def _no_day_of_the_month(data):
+    data.date_moy[data.date_moy == data.moy] = 12
+
+
+def _all_keys_null(data):
+    data.ss_item_sk_valid[:] = False
+    data.ss_sold_date_sk_valid[:] = False
+
+
+def _keys_at_the_dims_edges(data):
+    """Every key the first or the last row of its dimension, every one of
+    those rows qualifying: the kept rows fill the grid's first and last
+    year and the first and last item's codes."""
+    rng = np.random.default_rng(17)
+    n = len(data.ss_item_sk)
+    data.ss_item_sk[:] = rng.choice([1, len(data.item_sk)], n)
+    data.ss_sold_date_sk[:] = rng.choice([DATE_SK0, DATE_SK0 + DATE_ROWS - 1],
+                                         n)
+    data.item_manufact_id[[0, -1]] = data.manufact_id
+    data.date_moy[[0, -1]] = data.moy
+
+
+@pytest.mark.parametrize("case", [
+    _no_manufacturer, _no_day_of_the_month, _all_keys_null,
+    _keys_at_the_dims_edges], ids=lambda f: f.__name__.lstrip("_"))
+def test_dimension_side_fields_equal_the_per_op_grid(case):
+    """The plan's grid, kept where the gathered brand code and year offset
+    are not -1, equals the per-op body's, which gathers every dimension
+    field and filters per fact row: sums and counts, slot for slot."""
+    import jax.numpy as jnp
+
+    from spark_rapids_jni_tpu.models.q3 import (
+        _brand_codes,
+        _dims,
+        _facts,
+        _geometry,
+        _partials,
+        _q3_tables,
+        q3_plan,
+    )
+    from spark_rapids_jni_tpu.plans import execute_plan
+
+    data = spec_data(21)
+    case(data)
+    codes = _brand_codes(data)
+    geo = _geometry(data, codes)
+    dims = _dims(data, codes)
+    got = execute_plan(None, q3_plan(**geo),
+                       _q3_tables(_facts(data), dims))
+    want = _partials(*(jnp.asarray(v) for v in _facts(data).values()),
+                     **{k: jnp.asarray(v) for k, v in dims.items()}, **geo)
+    np.testing.assert_array_equal(got["sums"], np.asarray(want.sums))
+    np.testing.assert_array_equal(got["counts"], np.asarray(want.counts))
+    assert [tuple(r) for r in q3_local(data)] == numpy_q3(data) == [
+        tuple(r) for r in q3_local_unfused(data)]
+    counts = got["counts"].reshape(geo["n_years"], geo["n_brands"])
+    if case is _keys_at_the_dims_edges:
+        first, last = codes.item[[0, -1]] - 1
+        assert counts[0, first] > 0 and counts[-1, last] > 0
+        assert counts.sum() == len(data.ss_item_sk) - np.count_nonzero(
+            ~data.ss_item_sk_valid | ~data.ss_sold_date_sk_valid)
+    else:
+        assert counts.sum() == 0
