@@ -449,15 +449,8 @@ def phase_nds(sf_big: float = 10.0, sf_small: float = 1.0,
         q5_local,
     )
     from spark_rapids_jni_tpu.models.q3 import q3_columns_host_oracle
-    from spark_rapids_jni_tpu.models.q5 import (
-        _facts_of,
-        q5_host_channel_partials,
-        q5_rollup,
-    )
-    from spark_rapids_jni_tpu.models.tpcds import (
-        CHANNELS,
-        generate_q97_tables,
-    )
+    from spark_rapids_jni_tpu.models.q5 import q5_host_oracle
+    from spark_rapids_jni_tpu.models.tpcds import generate_q97_tables
     from spark_rapids_jni_tpu.plans import plan_cache
 
     def split(report):
@@ -481,12 +474,7 @@ def phase_nds(sf_big: float = 10.0, sf_small: float = 1.0,
         small["queries"]
 
     d5 = generate_q5_data(sf=sf_small, seed=SEED)
-    want5 = q5_rollup(
-        {c: q5_host_channel_partials(
-            _facts_of(d5.channels[c]), len(d5.channels[c].dim_sk),
-            d5.date_sk, d5.date_days, d5.sales_date_lo, d5.sales_date_hi)
-         for c in CHANNELS},
-        {c: d5.channels[c].dim_id for c in CHANNELS})
+    want5 = q5_host_oracle(d5)
     assert small["queries"]["q5"]["result_rows"] == len(want5)
     assert q5_local(d5) == want5, "q5 != host oracle"
     d3 = generate_q3_data(sf=sf_small, seed=SEED)
@@ -627,21 +615,10 @@ def mesh_q5_q3(mesh, sf: float = 1.0):
         run_distributed_q5,
     )
     from spark_rapids_jni_tpu.models.q3 import q3_columns_host_oracle
-    from spark_rapids_jni_tpu.models.q5 import (
-        _facts_of,
-        q5_host_channel_partials,
-        q5_rollup,
-    )
-    from spark_rapids_jni_tpu.models.tpcds import CHANNELS
-
+    from spark_rapids_jni_tpu.models.q5 import q5_host_oracle
     d5 = generate_q5_data(sf=sf, seed=SEED)
     d3 = generate_q3_data(sf=sf, seed=SEED)
-    want5 = q5_rollup(
-        {c: q5_host_channel_partials(
-            _facts_of(d5.channels[c]), len(d5.channels[c].dim_sk),
-            d5.date_sk, d5.date_days, d5.sales_date_lo, d5.sales_date_hi)
-         for c in CHANNELS},
-        {c: d5.channels[c].dim_id for c in CHANNELS})
+    want5 = q5_host_oracle(d5)
     want3 = [tuple(r) for r in q3_columns_host_oracle(d3)]
     q5 = run_distributed_q5(mesh, d5)
     q3 = run_distributed_q3(mesh, d3)

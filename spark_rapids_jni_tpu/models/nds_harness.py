@@ -211,7 +211,7 @@ def run(argv=None) -> dict:
         else:
             data = generate_q5_data(sf=args.sf, seed=args.seed)
             q5_rows_total = sum(
-                len(ch.sales_sk) + len(ch.ret_sk)
+                len(ch.sales_date) + len(ch.ret_date)
                 for ch in data.channels.values())
             t0 = time.perf_counter()
             q5_rows = run_distributed_q5(mesh, data, budget=budget, task_id=1)

@@ -88,75 +88,126 @@ def generate_q97_chunks(sf: float, seed: int, chunk_rows: int
 
 
 # ------------------------------------------------------------ streamed q5 --
-# q5's aggregates are per-(channel, dim_sk) segment sums — additive over any
-# disjoint row partition — so the grace hash needs no join co-location; it
-# routes by the GROUP key (dim sk) per channel anyway, which makes every
-# (channel, sk) group bucket-local and the per-bucket oracle exact without a
-# global materialize.  Facts spill as full JCUDF tables (nullable keys +
-# int64 money) through one ExternalTableShuffle with six sides:
-# "{channel}.{sales|ret}".
+# q5's aggregates are per-(channel, id code) sums — additive over any
+# disjoint partition of the probe rows — with one join that needs
+# co-location: a web return meets its sale by (item, order).  So store and
+# catalog facts route by (dimension key, date), which has values enough to
+# split a bucket again and again, and web sales and returns by order
+# number, each through its own ExternalTableShuffle with the same
+# bucket count: bucket b of the stream is bucket b of both, and a split
+# refines both alike (the modulus doubles per bucket), so every return
+# stays in its sale's bucket.  Facts spill as full JCUDF tables (nullable
+# keys + int64 money); sides are "{channel}.{sales|ret}".
 
 
 def generate_q5_chunks(sf: float, seed: int, chunk_rows: int,
                        null_pct: float = 0.04):
     """Stream the q5 fact tables as ``(channel, kind, arrays)`` chunks.
 
-    Same totals as tpcds.generate_q5_data (n_sales = 40k*sf scaled down by
-    channel, returns = sales/8) with per-chunk seeded rngs, so any prefix
-    is reproducible without materializing a table.  ``kind`` is "sales"
-    (m1=price, m2=profit) or "ret" (m1=amt, m2=loss).
+    The row counts of tpcds.generate_q5_data (section 3's SF 10 counts
+    scaled to ``sf``) from per-chunk seeded rngs, so any prefix is
+    reproducible without materializing a table.  ``kind`` is "sales"
+    (m1=price, m2=profit) or "ret" (m1=amt, m2=loss); every chunk has
+    ``date`` and ``date_valid``; store and catalog chunks ``sk`` and
+    ``sk_valid``; web sales ``sk``, ``sk_valid``, ``item`` and ``order``,
+    web returns ``item`` and ``order`` only.  Each web sales chunk is
+    followed by the returns of its lines, its share of web_returns.
     """
-    from spark_rapids_jni_tpu.models.tpcds import CHANNELS, q5_dims
+    from spark_rapids_jni_tpu.models.tpcds import (
+        CHANNELS,
+        ITEM_ROWS,
+        ORPHAN_SHARE,
+        q5_dims,
+        q5_returns,
+        q5_rows,
+        q5_sales,
+        web_orders,
+        web_returns_of,
+    )
 
     dims = q5_dims()
-    d0 = int(dims.date_sk[0])
-    n_dates = len(dims.date_sk)
+
+    def chunk(sales, kind):
+        money = ("price", "profit") if kind == "sales" else ("amt", "loss")
+        out = {k: v for k, v in sales.items() if k not in money}
+        out["m1"], out["m2"] = sales[money[0]], sales[money[1]]
+        return out
+
     for ci, name in enumerate(CHANNELS):
         n_dim = dims.channel_size(name)
-        n_sales = max(8, int(40_000 * sf) // (ci + 1))
-        for ki, (kind, total, m2_lo, m2_hi) in enumerate(
-                (("sales", n_sales, -100_00, 200_00),
-                 ("ret", max(4, n_sales // 8), 0, 80_00))):
-            done = 0
-            chunk = 0
-            while done < total:
-                m = min(chunk_rows, total - done)
-                rng = np.random.RandomState(
-                    (seed + 7_000_003 * ci + 500_009 * ki + chunk)
-                    % (2**31 - 1))
-                sk = rng.randint(1, n_dim + 1, m).astype(np.int32)
-                sk_valid = rng.rand(m) >= null_pct
-                date = rng.randint(d0, d0 + n_dates, m).astype(np.int32)
-                date_valid = rng.rand(m) >= null_pct
-                yield (name, kind, {
-                    "sk": np.where(sk_valid, sk, 0).astype(np.int32),
-                    "sk_valid": sk_valid,
-                    "date": np.where(date_valid, date, 0).astype(np.int32),
-                    "date_valid": date_valid,
-                    "m1": rng.randint(0, 500_00, m).astype(np.int64),
-                    "m2": rng.randint(m2_lo, m2_hi, m).astype(np.int64),
-                })
-                done += m
-                chunk += 1
+        n_s = q5_rows(f"{name}_sales", sf)
+        n_r = q5_rows(f"{name}_returns", sf)
+
+        def rng_of(ki, c):
+            return np.random.RandomState(
+                (seed + 7_000_003 * ci + 500_009 * ki + c) % (2**31 - 1))
+
+        if name != "web":
+            for ki, (kind, total) in enumerate((("sales", n_s),
+                                               ("ret", n_r))):
+                for c, at in enumerate(range(0, total, chunk_rows)):
+                    m = min(chunk_rows, total - at)
+                    rng = rng_of(ki, c)
+                    rows = (q5_sales(rng, m, n_dim, null_pct)
+                            if kind == "sales"
+                            else q5_returns(rng, m, n_dim, null_pct))
+                    yield name, kind, chunk(rows, kind)
+            continue
+        first_order = 1
+        orphans = 0
+        for c, at in enumerate(range(0, n_s, chunk_rows)):
+            m = min(chunk_rows, n_s - at)
+            rng = rng_of(0, c)
+            sales = q5_sales(rng, m, n_dim, null_pct)
+            sales["item"], sales["order"] = web_orders(
+                rng, m, ITEM_ROWS, first_order)
+            first_order = int(sales["order"][-1]) + 1
+            yield name, "sales", chunk(sales, "sales")
+            r = n_r * (at + m) // n_s - n_r * at // n_s
+            if r:
+                # orphans take order numbers above any line's
+                ret = web_returns_of(rng, sales, r, null_pct,
+                                     n_s + 1 + orphans)
+                orphans += int(round(r * ORPHAN_SHARE))
+                yield name, "ret", chunk(ret, "ret")
 
 
-def _q5_side_facts(shuffle: ExternalTableShuffle, channel: str, bucket: int):
+def _columns(ch, names, dtypes):
+    """JCUDF columns of a chunk: ``names`` in order, a key's validity from
+    ``<name>_valid`` where the chunk has one."""
+    from spark_rapids_jni_tpu.columnar.column import Column
+
+    return [Column(ch[n], ch.get(f"{n}_valid"), d)
+            for n, d in zip(names, dtypes)]
+
+
+#: the spill columns of each shuffle: store/catalog route by their
+#: dimension key and date (columns 0 and 1), web by order number (column
+#: 0); web returns spill an all-null site column
+_BY_SK = ("sk", "date", "m1", "m2")
+_BY_ORDER = ("order", "item", "sk", "date", "m1", "m2")
+
+
+def _q5_side_facts(shuffles, channel: str, bucket: int):
     """Decode one channel's (sales, ret) spill sides of one bucket into the
     q5 fact-array dict the partials step consumes."""
+    web = channel == "web"
+    names = _BY_ORDER if web else _BY_SK
     out = {}
-    for kind, names in (("sales", ("sales_sk", "sales_date",
-                                   "sales_price", "sales_profit")),
-                        ("ret", ("ret_sk", "ret_date",
-                                 "ret_amt", "ret_loss"))):
-        cols = shuffle.read(f"{channel}.{kind}", bucket)
-        n = len(np.asarray(cols[0].data))
-        for col, cname in zip(cols, names):
-            out[cname] = np.asarray(col.data)
-        for key_col, cname in ((cols[0], f"{kind}_sk"),
-                               (cols[1], f"{kind}_date")):
-            out[f"{cname}_valid"] = (
-                np.ones(n, bool) if key_col.validity is None
-                else np.asarray(key_col.validity))
+    for kind, money in (("sales", ("price", "profit")),
+                        ("ret", ("amt", "loss"))):
+        cols = dict(zip(names, shuffles[web].read(f"{channel}.{kind}",
+                                                  bucket)))
+        for name, col in cols.items():
+            field = {"m1": money[0], "m2": money[1]}.get(name, name)
+            if web and kind == "ret" and name == "sk":
+                continue
+            out[f"{kind}_{field}"] = np.asarray(col.data)
+            if name in ("sk", "date"):
+                n = len(out[f"{kind}_{field}"])
+                out[f"{kind}_{field}_valid"] = (
+                    np.ones(n, bool) if col.validity is None
+                    else np.asarray(col.validity))
     return out
 
 
@@ -174,20 +225,21 @@ def run_streaming_q5(
 ):
     """Out-of-core governed distributed q5 over streamed fact chunks.
 
-    Returns ``(rows, verified, stats)`` where ``rows`` is the full
-    ROLLUP(channel, id) result.  Each bucket runs through ONE cached
-    compiled partials step (geometry is the dim side, bucket-independent);
-    per-bucket partial vectors sum into the global answer because every
-    aggregate is additive over the disjoint bucket rows.  ``verify``
-    checks each bucket against the numpy oracle
+    Returns ``(rows, verified, stats)`` where ``rows`` is the query's
+    result (the rollup, its order and its limit).  Each bucket runs
+    through ONE cached compiled partials step (geometry is the dimension
+    side, bucket-independent); per-bucket partial vectors sum into the
+    global answer because every aggregate is additive over the disjoint
+    bucket rows, and each web return is in its sale's bucket.  ``verify``
+    checks each bucket against the numpy reference
     (models.q5.q5_host_channel_partials) — bucket-local, bounded memory.
 
     Host staging is governed like streamed q97: the bucket's ACTUAL
     spill-file bytes are reserved on the arbiter's CPU path; an
-    over-budget bucket recursively splits on disk (partials stay additive
-    under ANY row partition, so key-space splits are trivially exact).
+    over-budget bucket recursively splits on disk, in both shuffles alike.
     """
-    from spark_rapids_jni_tpu.columnar.column import Column
+    import os
+
     from spark_rapids_jni_tpu.columnar.dtypes import INT32, INT64
     from spark_rapids_jni_tpu.mem.governed import (
         default_device_budget,
@@ -197,6 +249,7 @@ def run_streaming_q5(
     from spark_rapids_jni_tpu.models.q5 import (
         ChannelPartials,
         add_partials,
+        id_codes,
         q5_host_channel_partials,
         q5_rollup,
         run_q5_partials,
@@ -211,61 +264,62 @@ def run_streaming_q5(
     if budget is None:
         budget = default_device_budget()
     dims = q5_dims()
-    schema = [INT32, INT32, INT64, INT64]  # sk, date, m1, m2
-    shuffle = ExternalTableShuffle(tmpdir, n_buckets, schema,
-                                   key_indices=(0,))
+    codes = {n: id_codes(dims.dim_id[n]) for n in CHANNELS}
+    dtypes = {False: [INT32, INT32, INT64, INT64],
+              True: [INT32, INT32, INT32, INT32, INT64, INT64]}
+    shuffles = {web: ExternalTableShuffle(
+        os.path.join(tmpdir, "by_order" if web else "by_sk"), n_buckets,
+        dtypes[web], key_indices=(0,) if web else (0, 1))
+        for web in (False, True)}
     rows_in = 0
     try:
         for channel, kind, ch in chunks:
-            rows_in += len(ch["sk"])
-            cols = [
-                Column(ch["sk"], ch["sk_valid"], INT32),
-                Column(ch["date"], ch["date_valid"], INT32),
-                Column(ch["m1"], None, INT64),
-                Column(ch["m2"], None, INT64),
-            ]
+            rows_in += len(ch["date"])
+            web = channel == "web"
+            if web and "sk" not in ch:
+                ch = dict(ch, sk=np.zeros(len(ch["date"]), np.int32),
+                          sk_valid=np.zeros(len(ch["date"]), bool))
+            shuffle = shuffles[web]
+            cols = _columns(ch, _BY_ORDER if web else _BY_SK, dtypes[web])
             hashes = shuffle.row_hashes(cols)
             if bucket_owner is not None:
                 ids = (hashes % np.uint64(n_buckets)).astype(np.int64)
                 mine = (ids % bucket_owner[1]) == bucket_owner[0]
                 if not mine.any():
                     continue
-                cols = [Column(np.asarray(col.data)[mine],
-                               None if col.validity is None
-                               else np.asarray(col.validity)[mine],
-                               col.dtype) for col in cols]
+                cols = [type(col)(np.asarray(col.data)[mine],
+                                  None if col.validity is None
+                                  else np.asarray(col.validity)[mine],
+                                  col.dtype) for col in cols]
                 hashes = hashes[mine]
             shuffle.append(f"{channel}.{kind}", cols, hashes=hashes)
 
         verified: Optional[bool] = True if verify else None
 
         def run_bucket(b: int):
-            batch = {name: _q5_side_facts(shuffle, name, b)
+            batch = {name: _q5_side_facts(shuffles, name, b)
                      for name in CHANNELS}
-            per = run_q5_partials(
-                mesh, batch,
-                date_sk=dims.date_sk, date_days=dims.date_days,
-                n_dims=dims.n_dims,
-                lo=dims.sales_date_lo, hi=dims.sales_date_hi,
-                budget=budget, task_id=task_id, manage_task=False)
+            per = run_q5_partials(mesh, batch, dims, budget=budget,
+                                  task_id=task_id, manage_task=False)
             oracle_ok = True
             if verify:
-                for name, n_dim in zip(CHANNELS, dims.n_dims):
+                for name in CHANNELS:
                     want = q5_host_channel_partials(
-                        batch[name], n_dim, dims.date_sk, dims.date_days,
-                        dims.sales_date_lo, dims.sales_date_hi)
-                    got = per[name]
+                        batch[name], codes[name][0], dims.date_sk,
+                        dims.date_days, dims.sales_date_lo,
+                        dims.sales_date_hi)
                     oracle_ok = oracle_ok and all(
                         np.array_equal(np.asarray(g, np.int64),
                                        np.asarray(w, np.int64))
-                        for g, w in zip(got, want))
+                        for g, w in zip(per[name], want))
             return per, oracle_ok
 
         n_splits = [0]
 
         def split_piece(b: int):
             n_splits[0] += 1
-            return shuffle.split_bucket(b)
+            pieces = [sh.split_bucket(b) for sh in shuffles.values()]
+            return pieces[0]
 
         def combine_pieces(rs):
             acc = rs[0][0]
@@ -273,18 +327,21 @@ def run_streaming_q5(
                 acc = add_partials(acc, per)
             return acc, all(ok for _p, ok in rs)
 
+        def bucket_nbytes(b: int) -> int:
+            return sum(sh.bucket_nbytes(b) for sh in shuffles.values())
+
         totals = None
         with task_context(budget.gov, task_id):
             for b in range(n_buckets):
                 if bucket_owner is not None and \
                         b % bucket_owner[1] != bucket_owner[0]:
                     continue
-                if shuffle.bucket_rows(b) == 0:
+                if not any(sh.bucket_rows(b) for sh in shuffles.values()):
                     continue
                 if host_budget is not None:
                     per, oracle_ok = run_with_split_retry(
                         host_budget, b,
-                        nbytes_of=shuffle.bucket_nbytes,
+                        nbytes_of=bucket_nbytes,
                         run=run_bucket,
                         split=split_piece,
                         combine=combine_pieces,
@@ -296,21 +353,26 @@ def run_streaming_q5(
                 totals = per if totals is None else add_partials(totals, per)
         if totals is None:  # no owned rows at all
             totals = {name: ChannelPartials(
-                np.zeros(nd, np.int64), np.zeros(nd, np.int64),
-                np.zeros(nd, np.int64), np.zeros(nd, np.int32))
-                for name, nd in zip(CHANNELS, dims.n_dims)}
-        rows = q5_rollup(totals, dims.dim_id)
+                np.zeros(n, np.int64), np.zeros(n, np.int64),
+                np.zeros(n, np.int64), np.zeros(n, np.int32))
+                for name, n in ((c, len(codes[c][1])) for c in CHANNELS)}
+        rows = q5_rollup(totals, {n: codes[n][1] for n in CHANNELS})
+        per_bucket: Dict[int, int] = {}
+        for sh in shuffles.values():
+            for (_side, b), n in sh.rows.items():
+                per_bucket[b] = per_bucket.get(b, 0) + n
         stats = {
             "rows_in": rows_in,
             "n_buckets": n_buckets,
-            "max_bucket_rows": shuffle.max_bucket_rows(),
+            "max_bucket_rows": max(per_bucket.values(), default=0),
         }
         if host_budget is not None:
             stats["host_peak_reserved"] = host_budget.peak
             stats["bucket_splits"] = n_splits[0]
         return rows, verified, stats
     finally:
-        shuffle.close()
+        for sh in shuffles.values():
+            sh.close()
 
 
 def run_streaming_q97(

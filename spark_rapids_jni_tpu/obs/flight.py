@@ -248,6 +248,12 @@ EV_SEGMENT_AGG = "segment_agg"
 # (detail=plan:<name>:gathers:<fact-length columns gathered>:dim_side:
 # <of them, expressions evaluated on the dimension table>, value=gathers)
 EV_GATHER_JOIN = "gather_join"
+# the plan runtime's key-join counter (plans/runtime.execute_plan): one
+# event per plan run with KeyJoins, recorded in its plan_download
+# (detail=plan:<name>:probe:<probe rows the joins ran over>:kept:<rows
+# their masks kept>:matched:<of them, rows that found a build row>,
+# value=kept)
+EV_KEY_JOIN = "key_join"
 
 # Paired kinds: a layer that emits the left side of a pair must also emit
 # the right side (module-granular balance, enforced by the analyze gate's
@@ -296,6 +302,7 @@ EVENT_KINDS = (
     # appended for the same reason
     EV_SEGMENT_AGG,
     EV_GATHER_JOIN,
+    EV_KEY_JOIN,
 )
 KIND_IDS = {k: i for i, k in enumerate(EVENT_KINDS)}
 

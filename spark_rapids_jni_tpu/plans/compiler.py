@@ -58,6 +58,14 @@ VALID_FIELD = "__valid__"
 #: over the sinks and the chips)
 AGG_KEPT = "__agg_kept__"
 AGG_ROWS = "__agg_rows__"
+#: the implicit outputs of a plan with KeyJoins: the probe rows their
+#: masks kept, those of them that found a build row, and the probe rows
+#: the joins ran over (int32, each summed over the joins and the chips)
+JOIN_KEPT = "__join_kept__"
+JOIN_MATCHED = "__join_matched__"
+JOIN_ROWS = "__join_rows__"
+#: ... and the kept build rows whose key repeats the row's before it
+JOIN_REPEATS = "__join_repeats__"
 
 
 # ---------------------------------------------------------------- expressions
@@ -129,6 +137,10 @@ class _Ctx:
         self.dropped: List[object] = []
         self.agg_kept: List[object] = []  # rows each SegmentAgg kept
         self.agg_rows = 0  # static rows the aggregation ran over
+        self.join_kept: List[object] = []  # probe rows each KeyJoin kept
+        self.join_matched: List[object] = []  # ... that found a build row
+        self.join_rows = 0  # static probe rows the joins ran over
+        self.join_repeats: List[object] = []  # build keys seen twice
 
 
 class _Rows:
@@ -207,21 +219,6 @@ def gather_fields(plan: ir.Plan) -> Tuple[int, int]:
     return len(fields), sum(not isinstance(s, str) for s in fields)
 
 
-@emitter(ir.SemiJoinWindow)
-def _emit_semi_join_window(node: ir.SemiJoinWindow, ctx: _Ctx) -> _Rows:
-    rows = _emit(node.child, ctx)
-    dim_sk = ctx.inputs[node.dim.table][node.sk_field]
-    dim_days = ctx.inputs[node.dim.table][node.days_field]
-    date = _eval(node.key, rows.cols)
-    valid = _eval(node.key_valid, rows.cols)
-    lo = _eval(node.lo, rows.cols)
-    hi = _eval(node.hi, rows.cols)
-    idx = jnp.clip(jnp.searchsorted(dim_sk, date), 0, dim_sk.shape[0] - 1)
-    hit = dim_sk[idx] == date
-    in_win = (dim_days[idx] >= lo) & (dim_days[idx] < hi)
-    return _Rows(rows.cols, rows.mask & valid & hit & in_win)
-
-
 def _rowwise(x, mask):
     """``x`` (a row column or a scalar) as one value per row of ``mask``."""
     return jnp.broadcast_to(jnp.asarray(x), mask.shape)
@@ -242,25 +239,95 @@ def agg_path(plan: ir.Plan) -> str:
     return paths.pop() if len(paths) == 1 else "mixed"
 
 
+def _lex_below(keys, queries):
+    """``keys < queries`` for equally long tuples of columns compared
+    lexicographically, the first column most significant."""
+    below = keys[-1] < queries[-1]
+    for k, q in zip(keys[-2::-1], queries[-2::-1]):
+        below = (k < q) | ((k == q) & below)
+    return below
+
+
 def _lower_bounds(sorted_keys, queries, hi):
     """``searchsorted(sorted_keys, queries)`` (side left) for queries whose
     answers are at most ``hi``: a branch-free binary search over
     ``[0, hi]``, one gather of every query a step and ``bit_length(hi)``
-    steps, so few where few rows are kept.  Its loop body is this
-    function's own ops: no jitted helper's trace, shared with other
-    programs, carries this caller's frames."""
-    size = sorted_keys.shape[0]
+    steps, so few where few rows are kept.  Keys and queries are equally
+    long tuples of columns, sorted and compared lexicographically.  Its
+    loop body is this function's own ops: no jitted helper's trace,
+    shared with other programs, carries this caller's frames."""
+    size = sorted_keys[0].shape[0]
     steps = 32 - jax.lax.clz(hi)
     top = jax.lax.shift_left(jnp.int32(1), jnp.maximum(steps - 1, 0))
 
     def step(i, pos):
         # at least cand keys lie below q iff key[cand - 1] < q
         cand = pos + jax.lax.shift_right_logical(top, i)
-        below = sorted_keys[jnp.minimum(cand, size) - 1] < queries
+        at = jnp.minimum(cand, size) - 1
+        below = _lex_below(tuple(k[at] for k in sorted_keys), queries)
         return jax.lax.select((cand <= hi) & below, cand, pos)
 
     return jax.lax.fori_loop(0, steps, step,
-                             jnp.zeros(queries.shape, jnp.int32))
+                             jnp.zeros(queries[0].shape, jnp.int32))
+
+
+@emitter(ir.KeyJoin)
+def _emit_key_join(node: ir.KeyJoin, ctx: _Ctx) -> _Rows:
+    """One payload sort of the build rows by (not kept, key...), so the
+    kept rows come first in key order; a binary search of each probe key
+    over them; one gather of each field at the found row."""
+    probe = _emit(node.probe, ctx)
+    build = _emit(node.build, ctx)
+    with jax.named_scope("key_join"):
+        keys = [_rowwise(_eval(k, build.cols), build.mask)
+                for k in node.build_key]
+        payload = []
+        for source, valid, _out in node.fields:
+            payload.append(build.cols[source])
+            if valid is not None:
+                payload.append(build.cols[valid])
+        kept = build.mask
+        if ctx.mesh is not None:
+            from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
+
+            if ctx.mesh.shape[DATA_AXIS] > 1:
+                # a broadcast join: every chip searches all build rows
+                def everyone(x):
+                    return jax.lax.all_gather(x, DATA_AXIS, tiled=True)
+
+                keys = [everyone(k) for k in keys]
+                payload = [everyone(v) for v in payload]
+                kept = everyone(kept)
+        dropped = jax.lax.convert_element_type(~kept, jnp.int8)
+        flag, *ordered = jax.lax.sort((dropped, *keys, *payload),
+                                      num_keys=1 + len(keys),
+                                      is_stable=False)
+        sorted_keys = tuple(ordered[:len(keys)])
+        # the build key must be unique: count the kept rows that repeat
+        # their predecessor's key, for the runtime to refuse
+        repeat = (flag[1:] == 0) & (flag[:-1] == 0)
+        for k in sorted_keys:
+            repeat = repeat & (k[1:] == k[:-1])
+        ctx.join_repeats.append(jnp.sum(repeat, dtype=jnp.int32))
+        sorted_vals = iter(ordered[len(keys):])
+        queries = tuple(_rowwise(_eval(k, probe.cols), probe.mask)
+                        for k in node.probe_key)
+        n_kept = jnp.sum(kept, dtype=jnp.int32)
+        pos = _lower_bounds(sorted_keys, queries, n_kept)
+        at = jnp.minimum(pos, sorted_keys[0].shape[0] - 1)
+        found = pos < n_kept
+        for k, q in zip(sorted_keys, queries):
+            found = found & (k[at] == q)
+        cols = dict(probe.cols)
+        for _source, valid, out in node.fields:
+            value = next(sorted_vals)[at]
+            ok = found if valid is None else found & next(sorted_vals)[at]
+            cols[out] = jax.lax.select(ok, value, jnp.zeros_like(value))
+            cols[f"{out}_valid"] = ok
+        ctx.join_kept.append(jnp.sum(probe.mask, dtype=jnp.int32))
+        ctx.join_matched.append(jnp.sum(probe.mask & found, dtype=jnp.int32))
+        ctx.join_rows += int(probe.mask.shape[0])
+    return _Rows(cols, probe.mask)
 
 
 def _sorted_segment_sums(bucket, n: int, aggs, cols) -> Dict[str, object]:
@@ -282,7 +349,7 @@ def _sorted_segment_sums(bucket, n: int, aggs, cols) -> Dict[str, object]:
         (bucket, *payload.values()), num_keys=1, is_stable=False)
     # every bound lies at or below the count of keys below n
     bounds = _lower_bounds(
-        sorted_bucket, jnp.arange(n + 1, dtype=sorted_bucket.dtype),
+        (sorted_bucket,), (jnp.arange(n + 1, dtype=sorted_bucket.dtype),),
         jnp.sum(bucket < n, dtype=jnp.int32))
     out = {}
     for name, v in zip(payload, sorted_vals):
@@ -535,6 +602,8 @@ def compile_plan(plan: ir.Plan, mesh, signature: Tuple) -> CompiledPlan:
     out_names = output_names(plan)
     if any(isinstance(s, ir.SegmentAgg) for s in plan.sinks):
         out_names += (AGG_KEPT, AGG_ROWS)
+    if any(isinstance(n, ir.KeyJoin) for n in ir.walk(plan)):
+        out_names += (JOIN_KEPT, JOIN_MATCHED, JOIN_ROWS, JOIN_REPEATS)
     local = mesh is None
     if local and ir.has_exchange(plan):
         raise ValueError(
@@ -568,6 +637,13 @@ def compile_plan(plan: ir.Plan, mesh, signature: Tuple) -> CompiledPlan:
         if ctx.agg_kept:
             outputs[AGG_KEPT] = sum(ctx.agg_kept[1:], ctx.agg_kept[0])
             outputs[AGG_ROWS] = jnp.int32(ctx.agg_rows)
+        if ctx.join_kept:
+            outputs[JOIN_KEPT] = sum(ctx.join_kept[1:], ctx.join_kept[0])
+            outputs[JOIN_MATCHED] = sum(ctx.join_matched[1:],
+                                        ctx.join_matched[0])
+            outputs[JOIN_ROWS] = jnp.int32(ctx.join_rows)
+            outputs[JOIN_REPEATS] = sum(ctx.join_repeats[1:],
+                                        ctx.join_repeats[0])
         if not local:
             from spark_rapids_jni_tpu.parallel.mesh import DATA_AXIS
 

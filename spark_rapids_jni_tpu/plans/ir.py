@@ -20,9 +20,10 @@ Two layers:
   by plans/compiler.py onto the existing ops/ and columnar/ primitives:
   :class:`Scan` (sharded fact input), :class:`Dim` (replicated dimension
   input), :class:`Filter`, :class:`Project`, :class:`GatherJoin` (dense
-  surrogate-key join = replicated-table gather), :class:`SemiJoinWindow`
-  (date-dim membership via searchsorted — q5's broadcast-join analog),
-  :class:`SegmentAgg` (masked segment sums into a dense group space),
+  surrogate-key join = replicated-table gather), :class:`KeyJoin` (left
+  outer join on a unique composite key against a fact-sized build
+  pipeline, by one sort and a binary search), :class:`SegmentAgg`
+  (masked segment sums into a dense group space),
   :class:`Union` (tagged row concat), :class:`Exchange` (the all_to_all
   hash shuffle), :class:`PresenceCount` (q97's sort-merge presence
   counting) — and the order-sensitive tier: :class:`RangeExchange` (the
@@ -48,11 +49,12 @@ from typing import Tuple, Union as _U
 
 __all__ = [
     "Expr", "Col", "Lit", "Bin", "Unary", "Cast",
-    "Node", "Scan", "Dim", "Filter", "Project", "GatherJoin",
-    "SemiJoinWindow", "SegmentAgg", "Union", "Exchange", "PresenceCount",
+    "Node", "Scan", "Dim", "Filter", "Project", "GatherJoin", "KeyJoin",
+    "SegmentAgg", "Union", "Exchange", "PresenceCount",
     "RangeExchange", "WinFunc", "Window", "Sort", "TopK",
     "Plan", "col", "lit", "band_all", "plan_signature",
     "order_sink", "range_exchange_nodes", "has_any_exchange",
+    "build_tables",
 ]
 
 
@@ -190,18 +192,32 @@ class GatherJoin:
 
 
 @dataclasses.dataclass(frozen=True)
-class SemiJoinWindow:
-    """q5's date-dim membership: mask &= (key found in dim.sk_field via
-    searchsorted) AND (dim.days_field in [lo, hi)) AND key_valid."""
+class KeyJoin:
+    """Left outer join of the ``probe`` pipeline against the ``build``
+    pipeline on a key of one or more int columns: every probe row stays,
+    and each field of the build row whose key equals its own rides along.
 
-    child: "Node"
-    dim: Dim
-    key: Expr
-    key_valid: Expr
-    sk_field: str
-    days_field: str
-    lo: Expr
-    hi: Expr
+    ``probe_key`` and ``build_key`` are equally long tuples of
+    expressions, compared column by column.  The build key must be
+    unique among the build's kept rows (a primary key, like web_sales'
+    (item, order)): the program counts the kept rows that repeat a key,
+    and the runtime refuses a result with any.  Key columns are not
+    null.
+
+    Each field is ``(build column, its validity column or None, out
+    name)``: the join adds ``out`` and ``out + "_valid"`` to the probe's
+    row environment.  ``_valid`` is False where no kept build row
+    matches or where the build value is null, and ``out`` is 0 there.
+
+    The build's tables may not be scanned anywhere else in the plan: a
+    split halves the probe side and hands each half the whole build, and
+    on a mesh each chip gathers every chip's build rows."""
+
+    probe: "Node"
+    build: "Node"
+    probe_key: Tuple[Expr, ...]
+    build_key: Tuple[Expr, ...]
+    fields: Tuple[Tuple[str, _U[str, None], str], ...]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -348,13 +364,13 @@ class PresenceCount:
     names: Tuple[str, str, str] = ("store_only", "catalog_only", "both")
 
 
-Node = _U[Scan, Dim, Filter, Project, GatherJoin, SemiJoinWindow,
+Node = _U[Scan, Dim, Filter, Project, GatherJoin, KeyJoin,
           SegmentAgg, Union, Exchange, PresenceCount,
           RangeExchange, Window, Sort, TopK]
 
 #: the concrete node classes _walk recurses into (single source of truth
 #: — a node type missing here is invisible to scan/dim/exchange discovery)
-NODE_TYPES = (Scan, Dim, Filter, Project, GatherJoin, SemiJoinWindow,
+NODE_TYPES = (Scan, Dim, Filter, Project, GatherJoin, KeyJoin,
               SegmentAgg, Union, Exchange, PresenceCount,
               RangeExchange, Window, Sort, TopK)
 
@@ -418,12 +434,39 @@ def dim_tables(plan: Plan) -> Tuple[Dim, ...]:
     """Distinct Dim nodes, ordered by table name.  Cached (hot path)."""
     seen = {}
     for n in walk(plan):
-        if isinstance(n, (GatherJoin, SemiJoinWindow)):
+        if isinstance(n, GatherJoin):
             prev = seen.setdefault(n.dim.table, n.dim)
             if prev != n.dim:
                 raise ValueError(
                     f"conflicting Dim field sets for table {n.dim.table!r}")
     return tuple(seen[t] for t in sorted(seen))
+
+
+@functools.lru_cache(maxsize=256)
+def build_tables(plan: Plan) -> frozenset:
+    """The tables scanned under a :class:`KeyJoin`'s build side.  Cached
+    (hot path: every governed run's split).  A table scanned both there
+    and elsewhere would be counted twice once a split replicates it, so
+    that is a structural error."""
+    built, elsewhere = set(), set()
+
+    def visit(node, under_build):
+        if isinstance(node, Scan):
+            (built if under_build else elsewhere).add(node.table)
+        for f in dataclasses.fields(node):
+            v = getattr(node, f.name)
+            for item in (v if isinstance(v, tuple) else (v,)):
+                if isinstance(item, NODE_TYPES):
+                    visit(item, under_build or (
+                        isinstance(node, KeyJoin) and f.name == "build"))
+
+    for sink in plan.sinks:
+        visit(sink, False)
+    if built & elsewhere:
+        raise ValueError(
+            f"plan {plan.name!r} scans {sorted(built & elsewhere)} both as "
+            f"a KeyJoin build and elsewhere")
+    return frozenset(built)
 
 
 @functools.lru_cache(maxsize=256)

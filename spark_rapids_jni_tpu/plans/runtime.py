@@ -35,6 +35,10 @@ from spark_rapids_jni_tpu.plans.cache import plan_cache
 from spark_rapids_jni_tpu.plans.compiler import (
     AGG_KEPT,
     AGG_ROWS,
+    JOIN_KEPT,
+    JOIN_MATCHED,
+    JOIN_REPEATS,
+    JOIN_ROWS,
     VALID_FIELD,
     agg_path,
     cached_compile,
@@ -257,7 +261,10 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
     the rows the aggregation ran over and the rows their masks kept.  A
     plan with GatherJoins records one ``gather_join`` event: the
     fact-length columns its joins gather and how many of them are
-    expressions evaluated on the dimension table.
+    expressions evaluated on the dimension table.  A plan with KeyJoins
+    records one ``key_join`` event: the probe rows the joins ran over,
+    the rows their masks kept and how many of those found a build row;
+    it raises ``ValueError`` where a build key repeats.
 
     Raises :class:`mem.governed.ShuffleCapacityExceeded` when an
     Exchange overflowed (``dropped > 0``) — the caller grows the
@@ -315,6 +322,20 @@ def execute_plan(mesh, plan: ir.Plan, tables: Tables) -> Dict[str, np.ndarray]:
                 detail=f"plan:{plan.name}:path:{agg_path(plan)}:scattered:"
                        f"{int(outputs.pop(AGG_ROWS))}:kept:{int(kept)}",
                 value=int(kept))
+        join_kept = outputs.pop(JOIN_KEPT, None)
+        if join_kept is not None:
+            repeats = int(outputs.pop(JOIN_REPEATS))
+            if repeats:
+                raise ValueError(
+                    f"plan {plan.name!r}: {repeats} KeyJoin build rows "
+                    f"repeat a key that must be unique")
+            _flight.record(
+                _flight.EV_KEY_JOIN,
+                detail=f"plan:{plan.name}:probe:"
+                       f"{int(outputs.pop(JOIN_ROWS))}:kept:"
+                       f"{int(join_kept)}:matched:"
+                       f"{int(outputs.pop(JOIN_MATCHED))}",
+                value=int(join_kept))
         gathers, dim_side = gather_fields(plan)
         if gathers:
             _flight.record(
@@ -365,9 +386,10 @@ def _upload_dims(plan: ir.Plan, tables: Tables, mesh) -> Tables:
 
 
 def split_scan_tables(tables: Tables, scans) -> List[Tables]:
-    """Halve every scan table's rows (dims replicated into both halves).
-    Exact for plans whose sinks are additive aggregates — every fused
-    NDS plan here."""
+    """Halve the rows of every table in ``scans`` (every other table, a
+    dim or a KeyJoin's build, replicated into both halves).  Exact for
+    plans whose sinks are additive aggregates — every fused NDS plan
+    here."""
     halves: List[Tables] = [{}, {}]
     scan_names = {s.table for s in scans}
     for table, fields in tables.items():
@@ -456,7 +478,9 @@ def run_governed_plan(
             with _trace.maybe_span(_trace.SPAN_CACHE,
                                    extra=f"plan:{plan.name}"):
                 return hit
-    scans = ir.scan_tables(plan)
+    # a KeyJoin's build side stays whole: each half probes all of it
+    builds = ir.build_tables(plan)
+    scans = [s for s in ir.scan_tables(plan) if s.table not in builds]
     tables = _upload_dims(plan, tables, mesh)
     if ir.order_sink(plan) is not None and split is None and combine is None:
         # ordered row vectors do not combine by addition, and a row-

@@ -2,8 +2,8 @@
 
 BASELINE config 5's second half (q97 lives in test_q97*.py).  The oracle
 recomputes the whole query with pandas joins/groupbys from the same
-generated tables — null FK drops, date-window dim join, per-id sums,
-ROLLUP(channel, id).
+generated tables — null FK drops, date-window dim join, the web returns'
+key join to web_sales, per-id sums, ROLLUP(channel, id).
 """
 
 import numpy as np
@@ -23,44 +23,63 @@ import pytest
 NDEV = 8
 
 
+LABELS = {"store": ("store channel", "store"),
+          "catalog": ("catalog channel", "catalog_page"),
+          "web": ("web channel", "web_site")}
+
+
 def _oracle(data):
-    """pandas re-implementation of the q5 pipeline."""
+    """pandas re-implementation of the q5 query: the window (both ends
+    in), the web returns' left outer join to web_sales, the inner joins
+    to the business dimension, sums per business id, then ROLLUP(channel,
+    id) ordered with nulls first and LIMIT 100."""
     dates = pd.DataFrame({"sk": data.date_sk, "days": data.date_days})
     window = dates[(dates.days >= data.sales_date_lo)
-                   & (dates.days < data.sales_date_hi)]["sk"]
-    rows = []
-    g = np.zeros(3, np.int64)
+                   & (dates.days <= data.sales_date_hi)]["sk"]
+    groups = []
     for name in CHANNELS:
         ch = data.channels[name]
+        dim = pd.DataFrame({"sk": ch.dim_sk, "id": ch.dim_id})
         sales = pd.DataFrame({
             "sk": np.where(ch.sales_sk_valid, ch.sales_sk, -1),
             "dt": np.where(ch.sales_date_valid, ch.sales_date, -1),
-            "price": ch.sales_price, "profit": ch.sales_profit,
+            "sales": ch.sales_price, "profit": ch.sales_profit,
+            "returns": 0, "loss": 0,
         })
-        sales = sales[sales.sk.isin(ch.dim_sk) & sales.dt.isin(window)]
         rets = pd.DataFrame({
-            "sk": np.where(ch.ret_sk_valid, ch.ret_sk, -1),
             "dt": np.where(ch.ret_date_valid, ch.ret_date, -1),
-            "amt": ch.ret_amt, "loss": ch.ret_loss,
+            "sales": 0, "profit": 0, "returns": ch.ret_amt,
+            "loss": ch.ret_loss,
         })
-        rets = rets[rets.sk.isin(ch.dim_sk) & rets.dt.isin(window)]
-
-        s_agg = sales.groupby("sk")[["price", "profit"]].sum()
-        r_agg = rets.groupby("sk")[["amt", "loss"]].sum()
-        merged = s_agg.join(r_agg, how="outer").fillna(0)
-        c = np.zeros(3, np.int64)
-        leaf = []
-        for sk, row in merged.iterrows():
-            ident = ch.dim_id[int(sk) - 1]
-            s, r = int(row.get("price", 0)), int(row.get("amt", 0))
-            p = int(row.get("profit", 0)) - int(row.get("loss", 0))
-            leaf.append((name, ident, s, r, p))
-            c += (s, r, p)
-        rows.extend(sorted(leaf, key=lambda q: q[1]))
-        rows.append((name, None, int(c[0]), int(c[1]), int(c[2])))
-        g += c
-    rows.append((None, None, int(g[0]), int(g[1]), int(g[2])))
-    return rows
+        if ch.ret_item is None:
+            rets["sk"] = np.where(ch.ret_sk_valid, ch.ret_sk, -1)
+        else:
+            keyed = pd.DataFrame({
+                "item": ch.ret_item, "order": ch.ret_order}).merge(
+                pd.DataFrame({
+                    "item": ch.sales_item, "order": ch.sales_order,
+                    "sk": np.where(ch.sales_sk_valid, ch.sales_sk, -1)}),
+                on=["item", "order"], how="left")
+            rets["sk"] = keyed["sk"].fillna(-1).to_numpy()
+        both = pd.concat([sales, rets])
+        both = both[both.dt.isin(window)].merge(dim, on="sk")
+        agg = both.groupby("id")[["sales", "returns", "profit",
+                                  "loss"]].sum()
+        label, prefix = LABELS[name]
+        for ident, row in agg.iterrows():
+            groups.append((label, prefix + ident, int(row.sales),
+                           int(row.returns), int(row.profit - row.loss)))
+    rows = list(groups)
+    for label in {g[0] for g in groups}:
+        mine = [g for g in groups if g[0] == label]
+        rows.append((label, None) + tuple(
+            sum(g[i] for g in mine) for i in (2, 3, 4)))
+    if groups:
+        rows.append((None, None) + tuple(
+            sum(g[i] for g in groups) for i in (2, 3, 4)))
+    rows.sort(key=lambda r: (r[0] is not None, r[0] or "",
+                             r[1] is not None, r[1] or ""))
+    return rows[:100]
 
 
 @pytest.mark.slow

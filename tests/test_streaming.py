@@ -296,6 +296,7 @@ def _assemble_q5(chunks):
 
     channels = {}
     for name in CHANNELS:
+        web = name == "web"
         channels[name] = ChannelTables(
             sales_sk=cat(name, "sales", "sk"),
             sales_sk_valid=cat(name, "sales", "sk_valid"),
@@ -303,14 +304,18 @@ def _assemble_q5(chunks):
             sales_date_valid=cat(name, "sales", "date_valid"),
             sales_price=cat(name, "sales", "m1"),
             sales_profit=cat(name, "sales", "m2"),
-            ret_sk=cat(name, "ret", "sk"),
-            ret_sk_valid=cat(name, "ret", "sk_valid"),
+            ret_sk=None if web else cat(name, "ret", "sk"),
+            ret_sk_valid=None if web else cat(name, "ret", "sk_valid"),
             ret_date=cat(name, "ret", "date"),
             ret_date_valid=cat(name, "ret", "date_valid"),
             ret_amt=cat(name, "ret", "m1"),
             ret_loss=cat(name, "ret", "m2"),
             dim_sk=dims.dim_sk[name],
             dim_id=dims.dim_id[name],
+            sales_item=cat(name, "sales", "item") if web else None,
+            sales_order=cat(name, "sales", "order") if web else None,
+            ret_item=cat(name, "ret", "item") if web else None,
+            ret_order=cat(name, "ret", "order") if web else None,
         )
     return Q5Data(channels, dims.date_sk, dims.date_days,
                   dims.sales_date_lo, dims.sales_date_hi)
@@ -345,7 +350,7 @@ def test_streaming_q5_matches_global_oracle(tmp_path):
         MemoryGovernor.shutdown()
     assert verified is True
     assert rows == want
-    assert stats["rows_in"] == sum(len(c[2]["sk"]) for c in chunks)
+    assert stats["rows_in"] == sum(len(c[2]["date"]) for c in chunks)
     assert stats["max_bucket_rows"] < stats["rows_in"]
     assert stats["host_peak_reserved"] > 0
     assert host_budget.used == 0
@@ -367,13 +372,13 @@ def test_streaming_q5_oversized_bucket_splits(tmp_path):
     from spark_rapids_jni_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh((len(jax.devices()), 1))
-    chunks = list(generate_q5_chunks(sf=0.5, seed=8, chunk_rows=3000))
+    chunks = list(generate_q5_chunks(sf=0.02, seed=8, chunk_rows=3000))
     want = q5_local(_assemble_q5(chunks))
 
     gov = MemoryGovernor(watchdog_period_s=0.02)
     dev_budget = BudgetedResource(gov, 1 << 30)
-    # sf=0.5 -> ~36k rows over 2 buckets at 32 B/row JCUDF -> ~580 KB per
-    # bucket; a 192 KB host budget forces recursive disk splits
+    # sf=0.02 -> ~111k rows over 2 buckets at 32-40 B/row JCUDF -> ~2 MB
+    # per bucket; a 192 KB host budget forces recursive disk splits
     host_budget = BudgetedResource(gov, 192 << 10, is_cpu=True)
     try:
         rows, verified, stats = run_streaming_q5(
